@@ -50,11 +50,13 @@ def main() -> None:
     import horovod_tpu as hvd
     from horovod_tpu.models import ResNet18, ResNet50
 
-    from horovod_tpu.utils.backend_probe import guarded_init
+    from horovod_tpu.utils.platform import place_compile_cache, require_tpu
 
-    # Outage-proof acquisition (see utils/backend_probe.py).
-    guarded_init("resnet_adasum_images_per_sec_per_chip", "images/sec/chip",
-                 skip=args.preset == "tiny")
+    hvd.init()
+    if args.preset == "full":
+        # A full-preset number is a device number: no TPU, no run.
+        require_tpu()
+        place_compile_cache()
     n_chips = hvd.size()
 
     if args.preset == "tiny":

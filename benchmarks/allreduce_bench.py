@@ -144,8 +144,7 @@ def main() -> None:
                         help="also write the full sweep as a JSON artifact "
                              "(BUSBW_r*.json trend line for the judge)")
     args = parser.parse_args()
-    # Pure usage errors exit HERE — before guarded_init spends its probe
-    # budget and mislabels a bad invocation as a measured outage.
+    # Pure usage errors exit HERE, before any backend is touched.
     if args.compression != "none" and args.collective != "allreduce":
         parser.error("--compression applies to the allreduce sweep only")
     if args.two_phase and args.collective != "allreduce":
@@ -211,11 +210,13 @@ def main() -> None:
 
     import horovod_tpu as hvd
     from horovod_tpu.ops import collectives as C
-    from horovod_tpu.utils.backend_probe import guarded_init
+    from horovod_tpu.utils.platform import place_compile_cache, require_tpu
 
-    # Outage-proof acquisition (round-3 postmortem — see
-    # horovod_tpu/utils/backend_probe.py).
-    guarded_init(metric, "GB/s", skip=args.cpu_mesh)
+    hvd.init()
+    if not args.cpu_mesh:
+        # Without --cpu-mesh the sweep is a device measurement.
+        require_tpu()
+        place_compile_cache()
     n = hvd.size()
     dtype = jnp.bfloat16 if args.dtype == "bfloat16" else jnp.float32
     bytes_per = 2 if args.dtype == "bfloat16" else 4

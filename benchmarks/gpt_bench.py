@@ -88,11 +88,17 @@ def main() -> None:
     from horovod_tpu.models.transformer import lm_loss_fn
     from horovod_tpu.parallel.train import shard_batch
 
-    from horovod_tpu.utils.backend_probe import guarded_init
+    from horovod_tpu.utils.mfu import (aot_compile_with_flops,
+                                       estimate_compute_us, peak_tflops)
+    from horovod_tpu.utils.platform import (device_record,
+                                            place_compile_cache, require_tpu)
 
-    # Outage-proof acquisition (see utils/backend_probe.py).
-    guarded_init("gpt_train_tokens_per_sec_per_chip", "tokens/sec/chip",
-                 skip=args.preset == "tiny")
+    hvd.init()
+    peak = None
+    if args.preset == "full":
+        # A full-preset number is a device number: no TPU, no run.
+        peak = peak_tflops(require_tpu())
+        place_compile_cache()
     gm = hvd.global_mesh()
     n_chips = hvd.size()
 
@@ -217,10 +223,7 @@ def main() -> None:
                                            (inputs, targets))
         return params, opt_state, loss
 
-    from horovod_tpu.utils.mfu import aot_compile_with_flops, peak_tflops_info
-
     run_chunk, chunk_flops = aot_compile_with_flops(chunk, params, opt_state)
-    peak, peak_source = peak_tflops_info(jax.devices()[0])
 
     for _ in range(args.warmup):
         params, opt_state, loss = run_chunk(params, opt_state)
@@ -250,6 +253,7 @@ def main() -> None:
         else hvd.config().overlap_reduce,
         "compressor": args.compressor,
     }
+    out["device"] = device_record()
     if mb > 1 and not out["overlap"]:
         # Nothing is scheduled under the backward: the honest estimate
         # of hidden communication is zero.
@@ -258,20 +262,19 @@ def main() -> None:
     elif mb > 1:
         # Estimated hidden-communication fraction of the overlap
         # schedule (ops/fusion.py cost model): per-microbatch backward
-        # time from the chip's advertised peak when known, else from the
-        # measured wall clock (CPU runs — the basis field records which).
+        # time from the chip's published peak on the TPU preset, else
+        # from the measured wall clock (the tiny CPU preset — the basis
+        # field records which).
         from horovod_tpu.ops.fusion import estimate_overlap_hidden_fraction
-        from horovod_tpu.utils.mfu import estimate_compute_us
 
         sizes = [leaf.size * leaf.dtype.itemsize
                  for leaf in jax.tree.leaves(params)]
-        step_flops = (chunk_flops / args.steps_per_call
-                      if chunk_flops else None)
-        bwd_us = estimate_compute_us(
-            (2.0 / 3.0) * step_flops / mb if step_flops else None,
-            jax.devices()[0])
-        basis = "modeled_peak"
-        if bwd_us is None:
+        if peak and chunk_flops:
+            basis = "modeled_peak"
+            bwd_us = estimate_compute_us(
+                (2.0 / 3.0) * chunk_flops / args.steps_per_call / mb,
+                jax.devices()[0])
+        else:
             basis = "measured_wall"
             bwd_us = (dt / (args.iters * args.steps_per_call * mb)) \
                 * (2.0 / 3.0) * 1e6
@@ -294,9 +297,6 @@ def main() -> None:
         if peak:
             out["mfu_pct"] = round(
                 100.0 * per_chip_flops_s / (peak * 1e12), 2)
-        # Unconditional: the provenance of mfu_pct — or of its absence
-        # (unknown device kind) — must be explicit in the artifact.
-        out["peak_tflops_source"] = peak_source
     # Final telemetry snapshot (diagnostic block — bench_regress skips
     # it): wire bytes per tier, step-time distribution, microbatch plan.
     from horovod_tpu.obs import export as obs_export

@@ -2,11 +2,17 @@
 
 Runs ``bench.py`` across batch sizes / steps-per-call and reports each
 config's images/sec + MFU so the best can be promoted to the bench
-defaults with a measured justification (VERDICT r2 task #3: perf wins
-must be measured and explained, not guessed).
+defaults with a measured justification (perf wins must be measured and
+explained, not guessed).
 
     python benchmarks/resnet_sweep.py                 # on the TPU chip
     python benchmarks/resnet_sweep.py --preset tiny   # CPU smoke
+
+A chip belongs to one process at a time.  This parent imports no JAX
+(only the standard library), so it never holds the chip: each
+``bench.py`` child is the one process on it, and they run one after
+another.  Keep it that way — a parent that touches JAX would make every
+child fail or hang.
 """
 
 from __future__ import annotations

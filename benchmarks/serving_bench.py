@@ -12,9 +12,10 @@ ONE trailing summary line:
      "occupancy_mean": ..., ...}
 
 TTFT is measured from *submission* (queueing included — the number a
-user feels), TPOT as the post-first-token cadence.  Runnable on CPU
-(default tiny model; ``--cpu-mesh`` forces the virtual CPU mesh) —
-a functional datapoint there, a perf datapoint on TPU.
+user feels), TPOT as the post-first-token cadence.  Without
+``--cpu-mesh`` the run needs a TPU and fails where JAX finds none;
+``--cpu-mesh`` asks for the virtual CPU mesh by name (default tiny
+model) — a functional datapoint, never a device number.
 
 **Prefix-heavy workload** (``--prefix-shared N``): every request
 carries the same N-token system prompt plus a short unique tail — the
@@ -39,7 +40,7 @@ at 4× capacity).
 
 Usage::
 
-    python benchmarks/serving_bench.py                     # tiny, CPU-safe
+    python benchmarks/serving_bench.py --cpu-mesh          # tiny, functional
     python benchmarks/serving_bench.py --requests 128 --slots 16
     python benchmarks/serving_bench.py --prefix-shared 48 --spec-k 4
     python benchmarks/serving_bench.py \\
@@ -192,9 +193,14 @@ def main() -> None:
     from horovod_tpu.serve import (ContinuousBatcher, InferenceEngine,
                                    QueueFullError, SamplingParams,
                                    ServingStats)
-    from horovod_tpu.utils.backend_probe import guarded_init
+    import horovod_tpu as hvd
+    from horovod_tpu.utils.platform import place_compile_cache, require_tpu
 
-    guarded_init(METRIC, "tok/s", skip=args.cpu_mesh)
+    hvd.init()
+    if not args.cpu_mesh:
+        # Without --cpu-mesh the rows are device numbers: no TPU, no run.
+        require_tpu()
+        place_compile_cache()
 
     buckets = tuple(int(b) for b in args.buckets.split(",") if b.strip())
     cfg = GPTConfig(

@@ -1,89 +1,46 @@
-"""Version compatibility shims for the evolving JAX API surface."""
+"""The few JAX spellings the tree routes through one place.
+
+Written for the one installed JAX (0.9.0, pinned in ``pyproject.toml``):
+no version branches.  What is left is house defaults (``shard_map``
+without replication checking), a multi-axis ``axis_size``, and two
+small helpers whose home would otherwise be arbitrary.
+"""
 
 from __future__ import annotations
 
-try:  # jax >= 0.8: jax.shard_map with check_vma
-    from jax import shard_map as _shard_map
+import jax
+import numpy as np
+from jax import lax
 
-    def shard_map(f, *, mesh, in_specs, out_specs, check: bool = False):
-        return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_vma=check)
-
-except ImportError:  # older jax: experimental module with check_rep
-    from jax.experimental.shard_map import shard_map as _shard_map  # type: ignore
-
-    def shard_map(f, *, mesh, in_specs, out_specs, check: bool = False):
-        return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_rep=check)
+enable_x64 = jax.enable_x64
 
 
-def enable_x64(new_val: bool = True):
-    """64-bit-mode context manager: ``jax.enable_x64`` on jax versions
-    that export it, else ``jax.experimental.enable_x64`` (same
-    semantics)."""
-    import jax
-
-    if hasattr(jax, "enable_x64"):
-        return jax.enable_x64(new_val)
-    from jax.experimental import enable_x64 as _enable_x64
-
-    return _enable_x64(new_val)
+def shard_map(f, *, mesh, in_specs, out_specs, check: bool = False):
+    """``jax.shard_map`` with the tree's default: varying-manual-axes
+    checking off (the collective bodies mix replicated and per-slot
+    values on purpose)."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check)
 
 
 def axis_size(axis) -> int:
     """Static width of a named mesh axis inside an SPMD region.  A
     tuple of names (a multi-axis MeshPlan's reduce wire) is the product
-    of the per-name widths.
-
-    ``jax.lax.axis_size`` only exists on newer jax; older versions
-    resolve the width from the abstract mesh (shard_map regions) or, as
-    a last resort, the constant-psum folding trick (``psum(1, axis)``
-    is evaluated statically)."""
-    import jax
-    from jax import lax
-
+    of the per-name widths."""
     if isinstance(axis, (tuple, list)):
         n = 1
         for a in axis:
-            n *= axis_size(a)
+            n *= lax.axis_size(a)
         return n
-    if hasattr(lax, "axis_size"):
-        return lax.axis_size(axis)
-    try:
-        mesh = jax.sharding.get_abstract_mesh()
-        shape = getattr(mesh, "shape", None) or {}
-        if axis in shape:
-            return int(shape[axis])
-    except Exception:
-        pass
-    return lax.psum(1, axis)
-
-
-def ffi_module():
-    """The jax typed-FFI namespace: ``jax.ffi`` on jax >= 0.5, its
-    previous home ``jax.extend.ffi`` on 0.4.x (same surface:
-    ``ffi_call``, ``register_ffi_target``, ``pycapsule``,
-    ``include_dir``).  ``register_ffi_target_as_batch_partitionable``
-    only exists in the new home — callers must getattr-guard it."""
-    try:
-        import jax.ffi as m
-
-        return m
-    except ImportError:
-        import jax.extend.ffi as m  # type: ignore
-
-        return m
+    return lax.axis_size(axis)
 
 
 def sanitize_checkpoint_tree(tree):
-    """Normalize a pytree for orbax's ``StandardSave``: newer orbax
-    (0.7+) accepts only ``int``/``float``/``np.ndarray``/``jax.Array``
-    leaves, so numpy *scalars* (``np.int64(7)`` — the idiomatic step
-    counter) fail the type check.  Wrap them as 0-d ndarrays, which
-    round-trip with dtype intact; everything else passes through."""
-    import jax
-    import numpy as np
-
+    """Normalize a pytree for orbax's ``StandardSave``, which accepts
+    only ``int``/``float``/``np.ndarray``/``jax.Array`` leaves: numpy
+    *scalars* (``np.int64(7)`` — the idiomatic step counter) fail its
+    type check.  Wrap them as 0-d ndarrays, which round-trip with dtype
+    intact; everything else passes through."""
     def fix(leaf):
         if isinstance(leaf, np.generic):
             return np.asarray(leaf)
@@ -92,36 +49,6 @@ def sanitize_checkpoint_tree(tree):
     return jax.tree.map(fix, tree)
 
 
-def _resolve_tracer():
-    """jax.core.Tracer's home keeps moving (jax.core is deprecated as a
-    public namespace); resolve it once, falling back through the known
-    locations so a jax upgrade can't break isinstance checks at call
-    time."""
-    import jax
-
-    for path in ("core", "_src.core"):
-        obj = jax
-        try:
-            for part in path.split("."):
-                obj = getattr(obj, part)
-            return obj.Tracer
-        except AttributeError:
-            continue
-    return None
-
-
-Tracer = _resolve_tracer()
-
-
 def is_tracer(x) -> bool:
-    """True when ``x`` is a JAX tracer (i.e. we are inside a trace).
-
-    The fallback must POSITIVELY identify tracers: tracers are
-    registered ``jax.Array`` instances, so "is it a concrete type?"
-    misclassifies every tracer as concrete — exactly the failure the
-    check exists to prevent.  Tracers (and only tracers) carry the
-    ``_trace`` link to their owning trace; concrete ``ArrayImpl`` does
-    not."""
-    if Tracer is not None:
-        return isinstance(x, Tracer)
-    return hasattr(x, "_trace") and hasattr(x, "aval")
+    """True when ``x`` is a JAX tracer (i.e. we are inside a trace)."""
+    return isinstance(x, jax.core.Tracer)

@@ -43,9 +43,9 @@ FAULT_SITES = ("collective", "fusion", "accumulate", "discovery", "rpc",
 # --- pre-init knob registry --------------------------------------------------
 # Knobs legitimately read via raw ``os.environ`` outside this module:
 # launcher/platform wiring consumed before ``init()`` builds the Config,
-# import-time gates (FFI registration), logging that must work during
-# init itself, and benchmark-subprocess sentinels.  Together with
-# ``Config.from_env`` this tuple IS the knob namespace —
+# import-time gates (FFI registration), and logging that must work
+# during init itself.  Together with ``Config.from_env`` this tuple IS
+# the knob namespace —
 # ``hvdlint``'s knob checker (horovod_tpu/analysis/knobs.py) rejects any
 # env name outside it and any raw read of a knob not listed here, so a
 # new knob must either land a Config field or be registered (and
@@ -64,10 +64,6 @@ PRE_INIT_KNOBS = (
     "SANITIZE", "SANITIZE_REPORT",
     # import-time gate for the native FFI tier
     "USE_NATIVE_FFI",
-    # benchmark outage defense (runs pre-init, often in subprocesses)
-    "PEAK_TFLOPS", "COMPILE_CACHE", "PROBE_ATTEMPTS", "PROBE_RETRIES",
-    "PROBE_BACKOFF_S", "PROBE_BACKOFF", "PROBE_TIMEOUT_S",
-    "BENCH_EXEC_ATTEMPT",
 )
 
 _FAULT_MODES = {

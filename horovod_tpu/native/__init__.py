@@ -19,9 +19,10 @@ Inventory (``src/``):
 * ``c_api.cc`` — plain-C ABI (:mod:`.bindings`)
 
 Components build lazily with the in-image toolchain (``g++``) on first
-use and cache the shared object next to the sources; every native entry
-point has a pure-python fallback, so a missing compiler only costs
-speed, never correctness (``horovodtpurun --check-build`` reports which
+use and cache the shared object beside the package, named after a
+digest of its sources (``build.py``; no ``.so`` is committed); every
+native entry point has a pure-python fallback, so a missing compiler
+only costs speed, never correctness (``horovodtpurun --check-build`` reports which
 path is active).
 """
 

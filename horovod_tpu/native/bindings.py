@@ -99,11 +99,12 @@ def load() -> Optional[ctypes.CDLL]:
             return _lib
         if _load_failed:
             return None
-        if _build.needs_build() and _build.build() is None:
+        path = _build.build()
+        if path is None:
             _load_failed = True
             return None
         try:
-            lib = ctypes.CDLL(_build.SO_PATH)
+            lib = ctypes.CDLL(path)
             for name, (restype, argtypes) in _SIGNATURES.items():
                 fn = getattr(lib, name)
                 fn.restype = restype
@@ -116,8 +117,8 @@ def load() -> Optional[ctypes.CDLL]:
             _lib = lib
             return _lib
         except (OSError, AttributeError) as e:
-            logger.info("Native library load failed (%s); python fallbacks "
-                        "active", e)
+            logger.warning("Native library load failed (%s); python "
+                           "fallbacks active", e)
             _load_failed = True
             return None
 

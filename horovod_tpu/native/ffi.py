@@ -21,27 +21,24 @@ custom call would force operand all-gathers; measured in
 HLO path in its manual-mode home — hlo 3334.5ms vs ffi 859.6ms, CPU
 controller tier).
 
-Registration uses ``jax.ffi.register_ffi_target`` (via the
-``_compat.ffi_module`` shim — ``jax.extend.ffi`` on jax 0.4.x) with
-PyCapsules minted from ``dlsym`` addresses via ctypes — no pybind11
-(not in this image).
+Registration uses ``jax.ffi.register_ffi_target`` with PyCapsules minted
+from ``dlsym`` addresses via ctypes — no pybind11 (not in this image).
 """
 
 from __future__ import annotations
 
 import ctypes
 import os
-import subprocess
 import threading
 from typing import List, Optional, Sequence
 
 from ..utils.logging import get_logger
+from .build import build_shared
 
 logger = get_logger(__name__)
 
 _HERE = os.path.dirname(os.path.abspath(__file__))
 SRC = os.path.join(_HERE, "src", "ffi_ops.cc")
-SO_PATH = os.path.join(_HERE, "libhvdtpu_ffi.so")
 
 _TARGETS = ("hvd_bucket_pack", "hvd_bucket_unpack", "hvd_adasum_combine")
 
@@ -50,28 +47,20 @@ _registered = False   # guarded-by: _lock
 _failed = False       # guarded-by: _lock
 
 
-def _needs_build() -> bool:
-    return (not os.path.exists(SO_PATH)
-            or os.path.getmtime(SRC) > os.path.getmtime(SO_PATH))
+def build() -> Optional[str]:
+    """The FFI library's path, compiled against the jaxlib headers when
+    its source (or jaxlib) changed; None on failure."""
+    import jax.ffi
+    import jaxlib
 
-
-def build(verbose: bool = False) -> Optional[str]:
-    """Compile the FFI library against the jaxlib headers (mtime-cached)."""
-    from .._compat import ffi_module
-
-    jffi = ffi_module()
-    cmd = ["g++", "-O3", "-std=c++17", "-shared", "-fPIC",
-           f"-I{jffi.include_dir()}", SRC, "-o", SO_PATH]
     try:
-        proc = subprocess.run(cmd, check=True, capture_output=True,
-                              timeout=300)
-        if verbose and proc.stderr:
-            logger.info("ffi build stderr: %s", proc.stderr.decode())
-        return SO_PATH
-    except (subprocess.SubprocessError, FileNotFoundError, OSError) as e:
-        err = getattr(e, "stderr", b"") or b""
-        logger.info("FFI build failed (%s) %s; HLO fallbacks active",
-                    e, err.decode(errors="replace")[:800])
+        return build_shared(
+            "hvdtpu_ffi", [SRC],
+            lambda out: ["g++", "-O3", "-std=c++17", "-shared", "-fPIC",
+                         f"-I{jax.ffi.include_dir()}", SRC, "-o", out],
+            key=jaxlib.__version__)
+    except RuntimeError as e:
+        logger.warning("%s; HLO fallbacks active", e)
         return None
 
 
@@ -82,39 +71,31 @@ def ensure_registered() -> bool:
     with _lock:
         if _registered:
             return True
-        if _failed and not _needs_build():
+        if _failed:
             return False
-        if _needs_build() and build() is None:
+        path = build()
+        if path is None:
             _failed = True
             return False
         try:
-            from .._compat import ffi_module
+            import jax.ffi
 
-            jffi = ffi_module()
-            lib = ctypes.cdll.LoadLibrary(SO_PATH)
+            lib = ctypes.cdll.LoadLibrary(path)
             for name in _TARGETS:
                 fn = getattr(lib, name)
-                jffi.register_ffi_target(
-                    name, jffi.pycapsule(fn), platform="cpu")
+                jax.ffi.register_ffi_target(
+                    name, jax.ffi.pycapsule(fn), platform="cpu")
             # pack/unpack treat each leading-dim row independently, so the
             # SPMD partitioner may keep dim-0 (slot) sharding and run the
             # handler per-shard — without this, slot-sharded operands get
             # all-gathered before the custom call.  (adasum_combine is NOT
             # partitionable: its dot products are global.)
-            # Only in the new (jax.ffi) home; on 0.4.x the partitioner
-            # falls back to gathering operands — correct, just slower,
-            # and _native_ffi_ok's manual-region gate keeps it off the
-            # auto-partitioned path anyway.
-            reg_bp = getattr(jffi,
-                             "register_ffi_target_as_batch_partitionable",
-                             None)
-            if reg_bp is not None:
-                for name in ("hvd_bucket_pack", "hvd_bucket_unpack"):
-                    reg_bp(name)
+            for name in ("hvd_bucket_pack", "hvd_bucket_unpack"):
+                jax.ffi.register_ffi_target_as_batch_partitionable(name)
             _registered = True
             return True
         except Exception as e:  # registration must never break the core
-            logger.info("FFI registration failed: %s", e)
+            logger.warning("FFI registration failed: %s", e)
             _failed = True
             return False
 
@@ -135,24 +116,20 @@ def bucket_pack(leaves: Sequence) -> "jax.Array":
     import jax
     import jax.numpy as jnp
 
-    from .._compat import ffi_module
-
     leaves = [jnp.asarray(x) for x in leaves]
     rows = leaves[0].shape[0]
     total = sum(int(x.shape[1]) for x in leaves)
     out_t = jax.ShapeDtypeStruct((rows, total), leaves[0].dtype)
-    return ffi_module().ffi_call("hvd_bucket_pack", out_t)(*leaves)
+    return jax.ffi.ffi_call("hvd_bucket_pack", out_t)(*leaves)
 
 
 def bucket_unpack(flat, cols: Sequence[int]) -> List:
     """Split one ``[L, sum(cols)]`` buffer back into ``[L, c]`` pieces."""
     import jax
 
-    from .._compat import ffi_module
-
     rows = flat.shape[0]
     outs = [jax.ShapeDtypeStruct((rows, int(c)), flat.dtype) for c in cols]
-    res = ffi_module().ffi_call("hvd_bucket_unpack", outs)(flat)
+    res = jax.ffi.ffi_call("hvd_bucket_unpack", outs)(flat)
     return list(res)
 
 
@@ -161,7 +138,5 @@ def adasum_combine(a, b):
     scaled-add kernels fused into one pass); f32/f64."""
     import jax
 
-    from .._compat import ffi_module
-
     out_t = jax.ShapeDtypeStruct(a.shape, a.dtype)
-    return ffi_module().ffi_call("hvd_adasum_combine", out_t)(a, b)
+    return jax.ffi.ffi_call("hvd_adasum_combine", out_t)(a, b)

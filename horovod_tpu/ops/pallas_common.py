@@ -32,11 +32,13 @@ _SUBLANES = 8
 
 
 def resolve_interpret(interpret: Optional[bool]) -> bool:
-    """The tree-wide default for the ``interpret=`` escape hatch: None
-    resolves to "interpret off-TPU" so the same kernel runs under the
-    CPU test mesh without callers passing a flag, while an explicit
-    True/False is honored as given (forcing the interpreter on TPU is a
-    legitimate numerics-debug move)."""
+    """The tree-wide default for the ``interpret=`` escape hatch.  None
+    means "compile for the TPU when the platform is the TPU, interpret
+    anywhere else", so the same kernel runs under the CPU test mesh
+    without callers passing a flag.  On the TPU the interpreter is
+    therefore never chosen unless the caller passed True (a numerics-
+    debug move); ``chip_smoke.py`` checks the lowered program for the
+    kernel rather than trust this flag."""
     if interpret is None:
         return jax.default_backend() != "tpu"
     return bool(interpret)

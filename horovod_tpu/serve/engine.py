@@ -109,6 +109,19 @@ def _sample(logits, rng, temps, topks):
     return jnp.where(temps <= 0.0, greedy, sampled).astype(jnp.int32)
 
 
+def _beside(params, tree):
+    """``tree`` (fresh KV state) committed to where ``params`` live.
+    Left uncommitted, it comes back from the first compiled program
+    committed to the weights' sharding, every later call then misses
+    that program's cache entry, and the first prefill bucket compiles
+    twice — the second time inside some request's TTFT."""
+    leaves = jax.tree.leaves(params)
+    sharding = getattr(leaves[0], "sharding", None) if leaves else None
+    if sharding is None or not sharding.is_fully_replicated:
+        return tree
+    return jax.device_put(tree, sharding)
+
+
 class InferenceEngine:
     """Slot-based prefill/decode engine; the batcher owns scheduling.
 
@@ -245,10 +258,10 @@ class InferenceEngine:
                     # Head-sharded pool: each shard device holds only
                     # its H/tp heads of every block; the block table
                     # stays whole-pool host state.
-                    z = jax.device_put(z, NamedSharding(
+                    return jax.device_put(z, NamedSharding(
                         self._tp_mesh,
                         PartitionSpec(None, None, "tensor", None)))
-                return z
+                return _beside(params, z)
 
             self._pools = [{"k": _pool_zeros(), "v": _pool_zeros()}
                            for _ in range(n_layer)]
@@ -282,8 +295,8 @@ class InferenceEngine:
             self.kv_block = 0
             self.kv_blocks = 0
             self._kv = None
-            self._caches = init_kv_cache(model.config, self.max_slots,
-                                         self.max_seq_len)
+            self._caches = _beside(params, init_kv_cache(
+                model.config, self.max_slots, self.max_seq_len))
             self._decode_fn = jax.jit(self._decode_impl,
                                       donate_argnums=self._donate)
             self._prefill_fns = {L: self._make_prefill(L)
@@ -307,8 +320,8 @@ class InferenceEngine:
                     f"serving cache ({self.max_seq_len})")
             self._drafter = dmodel
             self._drafter_params = dparams
-            self._drafter_caches = init_kv_cache(
-                dmodel.config, self.max_slots, self.max_seq_len)
+            self._drafter_caches = _beside(dparams, init_kv_cache(
+                dmodel.config, self.max_slots, self.max_seq_len))
             self._draft_prefill_fns = {L: self._make_draft_prefill(L)
                                        for L in self.prefill_buckets}
             self._spec_draft_fn = jax.jit(

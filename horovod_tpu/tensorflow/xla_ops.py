@@ -16,7 +16,8 @@ run the SAME host-binding closure the py_function bridge would, keyed
 through a trace-time closure table; the opaque payload carries only
 ``(key, dtype, dims)``.
 
-Build is lazy and mtime-cached like the rest of the native tier; any
+Build is lazy and content-cached like the rest of the native tier
+(``native/build.py``); any
 failure (no g++, header drift) degrades to ``available() == False``
 and the py_function bridge keeps working — only jit_compile support is
 lost, with the pinned error naming this module.
@@ -27,7 +28,6 @@ from __future__ import annotations
 import ctypes
 import itertools
 import os
-import subprocess
 import threading
 from typing import Callable, Dict, Optional, Tuple
 
@@ -39,7 +39,6 @@ logger = get_logger(__name__)
 
 _HERE = os.path.dirname(os.path.abspath(__file__))
 _SRC = os.path.join(os.path.dirname(_HERE), "native", "src", "tf_xla_ops.cc")
-_SO = os.path.join(os.path.dirname(_HERE), "native", "libhvdtpu_tf_xla.so")
 
 _lock = threading.Lock()
 _lib = None          # guarded-by: _lock (tf.load_op_library module)
@@ -87,35 +86,27 @@ def _trampoline(key: int, dtype_enum: int, dims: Tuple[int, ...],
     out_buf[:] = out.tobytes()
 
 
-def _build() -> Optional[str]:
+def _build() -> str:
     import tensorflow as tf
 
-    if (os.path.exists(_SO)
-            and os.path.getmtime(_SO) >= os.path.getmtime(_SRC)):
-        return _SO
+    from ..native.build import build_shared
+
     py_inc = __import__("sysconfig").get_paths()["include"]
     tf_inc = tf.sysconfig.get_include()
-    cmd = (["g++", "-O2", "-shared", "-fPIC", _SRC, "-o", _SO,
-            f"-I{py_inc}",
-            # Bazel-vendored third-party headers referenced by TF's own
-            # public headers resolve under include/external/*.
-            f"-I{os.path.join(tf_inc, 'external', 'highwayhash')}",
-            f"-I{os.path.join(tf_inc, 'external', 'com_google_highway')}",
-            f"-I{os.path.join(tf_inc, 'external', 'farmhash_archive', 'src')}"]
-           + tf.sysconfig.get_compile_flags()
-           + tf.sysconfig.get_link_flags()
-           + ["-l:libtensorflow_cc.so.2"])
-    # Build to a per-process temp name and rename into place: N worker
-    # processes import this module simultaneously on one host, and a
-    # half-written .so would fail (or corrupt) tf.load_op_library.
-    tmp = f"{_SO}.{os.getpid()}.tmp"
-    cmd[cmd.index(_SO)] = tmp
-    proc = subprocess.run(cmd, capture_output=True, timeout=600)
-    if proc.returncode != 0:
-        raise RuntimeError(
-            f"tf_xla_ops build failed: {proc.stderr.decode()[-800:]}")
-    os.replace(tmp, _SO)
-    return _SO
+    flags = ([f"-I{py_inc}",
+              # Bazel-vendored third-party headers referenced by TF's own
+              # public headers resolve under include/external/*.
+              f"-I{os.path.join(tf_inc, 'external', 'highwayhash')}",
+              f"-I{os.path.join(tf_inc, 'external', 'com_google_highway')}",
+              f"-I{os.path.join(tf_inc, 'external', 'farmhash_archive', 'src')}"]
+             + tf.sysconfig.get_compile_flags()
+             + tf.sysconfig.get_link_flags()
+             + ["-l:libtensorflow_cc.so.2"])
+    return build_shared(
+        "hvdtpu_tf_xla", [_SRC],
+        lambda out: ["g++", "-O2", "-shared", "-fPIC", _SRC, "-o", out,
+                     *flags],
+        key=tf.__version__, timeout=600)
 
 
 def _ensure_loaded():

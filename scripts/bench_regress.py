@@ -32,7 +32,7 @@ metrics (a diff that compares nothing must be loud, not green) — pass
 
 Usage::
 
-    python scripts/bench_regress.py BENCH_r05.json BENCH_r06.json
+    python scripts/bench_regress.py BEFORE.json AFTER.json
     python scripts/bench_regress.py old.json new.json --threshold 0.05
 """
 

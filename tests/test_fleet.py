@@ -19,6 +19,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from greedy_oracle import greedy_reference as _greedy_reference
 from horovod_tpu import faults
 from horovod_tpu.config import parse_fault_spec
 from horovod_tpu.models.transformer import GPT, GPTConfig
@@ -62,18 +63,6 @@ def _engine(model_and_params, **kw):
     kw.setdefault("max_seq_len", 32)
     kw.setdefault("kv_block", 4)
     return InferenceEngine(model, params, **kw)
-
-
-def _greedy_reference(model, params, prompt, n_tokens):
-    seq = list(prompt)
-    out = []
-    for _ in range(n_tokens):
-        logits = model.apply({"params": params},
-                             jnp.asarray([seq], jnp.int32))
-        tok = int(jnp.argmax(logits[0, -1]))
-        out.append(tok)
-        seq.append(tok)
-    return out
 
 
 def _drive(engine, slot, n):

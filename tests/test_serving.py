@@ -19,6 +19,7 @@ import numpy as np
 import pytest
 
 import horovod_tpu as hvd
+from greedy_oracle import greedy_reference as _greedy_reference
 from horovod_tpu import faults
 from horovod_tpu.config import parse_fault_spec
 from horovod_tpu.models.transformer import GPT, GPTConfig
@@ -61,19 +62,6 @@ def _engine(model_and_params, **kw):
     return InferenceEngine(model, params, **kw)
 
 
-def _greedy_reference(model, params, prompt, n_tokens):
-    """Naive full-forward argmax loop — the decode-correctness oracle."""
-    seq = list(prompt)
-    out = []
-    for _ in range(n_tokens):
-        logits = model.apply({"params": params},
-                             jnp.asarray([seq], jnp.int32))
-        tok = int(jnp.argmax(logits[0, -1]))
-        out.append(tok)
-        seq.append(tok)
-    return out
-
-
 def _run_engine_greedy(engine, slot, prompt, n_tokens):
     toks = [engine.start(slot, prompt, SamplingParams(
         max_new_tokens=n_tokens))]
@@ -104,6 +92,21 @@ class TestEngineDecode:
         _run_engine_greedy(engine, 0, [1, 2, 3], 3)        # bucket 8
         _run_engine_greedy(engine, 0, [4, 5, 6, 7, 8], 3)  # bucket 8 again
         _run_engine_greedy(engine, 1, list(range(12)), 3)  # bucket 16
+        assert engine.trace_counts == {"prefill_8": 1, "prefill_16": 1,
+                                       "decode": 1}, engine.trace_counts
+
+    def test_mesh_placed_weights_compile_each_program_once(
+            self, model_and_params):
+        """Weights that come from a trainer carry the mesh's sharding.
+        Fresh KV state must start beside them: left uncommitted it
+        returned from the first program with another sharding, and the
+        first bucket compiled twice (seen on the chip as a 16 s TTFT)."""
+        model, params = model_and_params
+        params = jax.device_put(params, hvd.global_mesh().replicated())
+        engine = _engine((model, params))
+        _run_engine_greedy(engine, 0, [1, 2, 3], 3)        # bucket 8
+        _run_engine_greedy(engine, 1, list(range(12)), 3)  # bucket 16
+        _run_engine_greedy(engine, 0, [4, 5, 6], 3)        # bucket 8 again
         assert engine.trace_counts == {"prefill_8": 1, "prefill_16": 1,
                                        "decode": 1}, engine.trace_counts
 
