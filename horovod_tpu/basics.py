@@ -206,7 +206,9 @@ def init(config: Optional[Config] = None) -> None:
         # Tracing + flight recorder: pin the lazy env gates to the
         # resolved Config; like the metrics registry, the span/event
         # rings are NOT cleared across elastic re-inits.
-        _obs_trace.configure(enabled=cfg.trace, ring=cfg.trace_ring)
+        _obs_trace.configure(enabled=cfg.trace, ring=cfg.trace_ring,
+                             rank=jax.process_index(),
+                             timeline=_state.timeline)
         _obs_flight.configure(enabled=cfg.flight,
                               directory=cfg.flight_dir,
                               ring=cfg.flight_ring)
@@ -711,6 +713,9 @@ def shutdown() -> None:
         _state.timeline = None
         _state.stall_inspector = None
         _state.parameter_manager = None
+        from .obs import trace as _obs_trace
+
+        _obs_trace.configure(rank=None, timeline=None)
 
 
 atexit.register(shutdown)
@@ -908,6 +913,14 @@ def stall_inspector():
     return _require("stall_inspector")
 
 
+def _retarget_span_mirror(timeline) -> None:
+    """Finished spans are mirrored into the Timeline that obs/trace.py
+    was last told of (it looks nothing up per span)."""
+    from .obs import trace as _obs_trace
+
+    _obs_trace.configure(timeline=timeline)
+
+
 def start_timeline(path: str, mark_cycles: bool = False) -> None:
     """Reference: ``hvd.start_timeline()`` (dynamic timeline activation)."""
     from .utils.timeline import Timeline
@@ -918,6 +931,7 @@ def start_timeline(path: str, mark_cycles: bool = False) -> None:
             st.timeline.close()
         st.timeline = Timeline(_per_process_path(path),
                                mark_cycles=mark_cycles)
+        _retarget_span_mirror(st.timeline)
 
 
 def stop_timeline() -> None:
@@ -929,3 +943,4 @@ def stop_timeline() -> None:
         if st.timeline is not None:
             st.timeline.close()
         st.timeline = Timeline(None)
+        _retarget_span_mirror(st.timeline)

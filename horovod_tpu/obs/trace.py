@@ -23,13 +23,29 @@ recorder ``obs/flight.py`` dumps it postmortem) and, when a framework
 client/server spans additionally emit flow (``"s"``/``"f"``) events
 keyed by the client span id, so Perfetto draws the cross-process arrow.
 
-Timestamps are **unix microseconds** (``time.time_ns``): each process
-stamps with its own wall clock, and :func:`estimate_clock_offset`
-(Cristian's algorithm over ``PingRequest`` RTTs — the minimum-RTT
-sample bounds the error by RTT/2) corrects residual skew when
-``scripts/trace_merge.py`` merges per-process span sets into ONE
-Perfetto file.  :func:`critical_path` then reports which hop/phase
-dominated a trace's wall time (TTFT or step time).
+**One clock.**  Every span is timed on ``time.monotonic_ns`` — the
+clock the serve batcher stamps requests with — and the ring's
+``start_us`` is that reading plus ONE wall-clock anchor taken when the
+module is imported, so ``start_us`` still reads as unix microseconds
+(:func:`mono_us` converts a ``time.monotonic()`` stamp; nothing
+re-anchors span by span).  Across processes,
+:func:`estimate_clock_offset` (Cristian's algorithm over
+``PingRequest`` RTTs — the minimum-RTT sample bounds the error by
+RTT/2) corrects residual skew when ``scripts/trace_merge.py`` merges
+per-process span sets into ONE Perfetto file, and
+:func:`critical_path` reports which hop/phase dominated a trace's wall
+time (TTFT or step time).
+
+**The profiler bridge.**  :func:`span` also enters a
+``jax.profiler.TraceAnnotation`` of the same name for its duration.
+With no profiler session live that is one flag test; with one live the
+span lands on the ``/host:CPU`` plane of the same ``.xplane.pb`` as the
+device's ``XLA Ops``, so the program's spans and the device's
+operations share the profiler's clock by construction.
+:func:`record_span` (after-the-fact spans) stays ring-only.
+:func:`scope` is the same idea inside a compiled program: a
+``jax.named_scope`` that names a region of the step in every
+operation's metadata (docs/tracing.md).
 
 Hot-path contract (the ``faults``/``metrics`` convention): one
 :func:`enabled` check per call site; ``HVD_TPU_TRACE=0`` turns every
@@ -40,6 +56,8 @@ from __future__ import annotations
 
 import contextlib
 import os
+import random
+import sys
 import threading
 import time
 from collections import deque
@@ -47,8 +65,8 @@ from typing import Any, Dict, Iterable, List, Optional, Sequence, Tuple
 
 __all__ = [
     "enabled", "configure", "span", "record_span", "instant", "current",
-    "new_context", "use_context", "process_rank",
-    "now_us", "inject", "extract", "snapshot", "clear",
+    "new_context", "use_context", "process_rank", "scope",
+    "now_us", "mono_us", "inject", "extract", "snapshot", "clear",
     "estimate_clock_offset", "merge_traces", "unresolved_parents",
     "critical_path", "trace_ids", "dump_merged",
 ]
@@ -59,6 +77,15 @@ _lock = threading.Lock()
 _enabled: Optional[bool] = None          # guarded-by: _lock (lazy env gate)
 _ring: "deque" = deque(maxlen=2048)      # guarded-by: _lock
 _tls = threading.local()                 # .ctx = (trace_id, span_id) or None
+_UNSET = object()
+# Pinned by configure() (hvd.init / start_timeline / shutdown) so that a
+# finished span looks neither up: this process's rank in the live world,
+# and the framework Timeline that spans are mirrored into.  Written
+# under _lock; a reader takes the one reference as it stands.
+_rank: Optional[int] = None
+_timeline = None
+# The one wall-clock anchor: unix microseconds at monotonic zero.
+_ANCHOR_US = (time.time_ns() - time.monotonic_ns()) / 1e3
 
 
 def enabled() -> bool:
@@ -78,42 +105,68 @@ def enabled() -> bool:
 
 
 def configure(enabled: Optional[bool] = None,
-              ring: Optional[int] = None) -> None:
+              ring: Optional[int] = None, *,
+              rank: Any = _UNSET, timeline: Any = _UNSET) -> None:
     """Pin the gate / resize the span ring from the resolved Config
     (``hvd.init``).  Resizing keeps the newest spans — never clears
-    recorded history across elastic re-inits."""
-    global _enabled, _ring
+    recorded history across elastic re-inits.  ``rank`` is this
+    process's rank in the live world (None after shutdown: the launch
+    env answers again) and ``timeline`` the framework Timeline to
+    mirror finished spans into (None: no mirror)."""
+    global _enabled, _ring, _rank, _timeline
     with _lock:
         if enabled is not None:
             _enabled = bool(enabled)
         if ring is not None and int(ring) != _ring.maxlen:
             _ring = deque(_ring, maxlen=max(1, int(ring)))
+        if rank is not _UNSET:
+            _rank = None if rank is None else int(rank)
+        if timeline is not _UNSET:
+            _timeline = timeline
 
 
 def now_us() -> float:
-    """Unix wall-clock microseconds — the cross-process span clock (the
-    merge step corrects per-process skew; see module docstring)."""
-    return time.time_ns() / 1e3
+    """The span clock *now*: monotonic time on the process's wall-clock
+    anchor, in unix microseconds (the merge step corrects per-process
+    skew; see module docstring)."""
+    return _ANCHOR_US + time.monotonic_ns() / 1e3
+
+
+def mono_us(t_mono: float) -> float:
+    """A ``time.monotonic()`` stamp (seconds) on the span clock."""
+    return _ANCHOR_US + t_mono * 1e6
+
+
+# Span ids have to be unique, not secret: a generator seeded once from
+# the OS, and the pid read once — a span on the hot path (three a
+# serving step) makes no system call.  A forked child reseeds and
+# re-reads, or it would repeat its parent's ids under its parent's pid.
+_ids = random.Random(os.urandom(16))
+_pid = os.getpid()
+
+
+def _after_fork_in_child() -> None:
+    global _pid
+    _ids.seed(os.urandom(16))
+    _pid = os.getpid()
+
+
+os.register_at_fork(after_in_child=_after_fork_in_child)
 
 
 def _new_id(nbytes: int) -> str:
-    return os.urandom(nbytes).hex()
+    return "%0*x" % (2 * nbytes, _ids.getrandbits(8 * nbytes))
 
 
 def process_rank() -> Optional[int]:
-    """This process's rank for span/scrape tagging: the live world when
-    initialized, else the launch env (``HVD_TPU_PROCESS_ID`` — launcher
-    and agent RPC is traced too), else None.  The one lookup every
-    tagging site (spans, ``TraceRequest``, flight dumps) shares."""
-    try:
-        from .. import basics
-
-        if basics.is_initialized():
-            import jax
-
-            return int(jax.process_index())
-    except Exception:
-        pass
+    """This process's rank for span/scrape tagging: the live world's
+    (pinned by ``hvd.init`` through :func:`configure`), else the launch
+    env (``HVD_TPU_PROCESS_ID`` — launcher and agent RPC is traced
+    too), else None.  The one lookup every tagging site (spans,
+    ``TraceRequest``, flight dumps) shares."""
+    rank = _rank
+    if rank is not None:
+        return rank
     raw = os.environ.get("HVD_TPU_PROCESS_ID")
     try:
         return int(raw) if raw is not None else None
@@ -154,31 +207,63 @@ def _append(rec: Dict[str, Any]) -> None:
         _ring.append(rec)
 
 
+def _scalars(args: Optional[Dict[str, Any]]) -> Dict[str, Any]:
+    """The args a Timeline slice or a profiler event can carry."""
+    return {k: v for k, v in (args or {}).items()
+            if isinstance(v, (bool, int, float, str))}
+
+
 def _emit_timeline(rec: Dict[str, Any]) -> None:
     """Mirror one finished span onto the live framework Timeline (slice
-    + flow endpoints for RPC spans).  Timeline timestamps are relative
-    to ITS clock, so the slice is anchored by how long ago the span
-    *ended* on the wall clock — a reconstructed span (``record_span``
-    with historical timing, e.g. the batcher's queued window recorded
-    after prefill) lands where it happened, not ending at "now"."""
+    + flow endpoints for RPC spans) — the one mirror.  Timeline
+    timestamps are relative to ITS clock, so the slice is anchored by
+    how long ago the span *ended* on the span clock — a reconstructed
+    span (``record_span`` with historical timing, e.g. the batcher's
+    queued window recorded after prefill) lands where it happened, not
+    ending at "now"."""
+    tl = _timeline
+    if tl is None:
+        return
     try:
-        from .. import basics
-
-        tl = basics.peek("timeline")   # fail-soft: None pre-init
-        if tl is None or not tl.enabled:
+        if not tl.enabled:
             return
         lag = max(0.0, now_us() - (rec["start_us"] + rec["dur_us"]))
         end = tl._now_us() - lag
         start = max(0.0, end - rec["dur_us"])
         tl.record(rec["trace_id"][:8], rec["name"], start, rec["dur_us"],
                   {"trace_id": rec["trace_id"], "span_id": rec["span_id"],
-                   "parent_id": rec["parent_id"]})
+                   "parent_id": rec["parent_id"], **_scalars(rec["args"])})
         if rec["kind"] == "client":
             tl.flow(rec["name"], rec["span_id"], "s", ts_us=start)
         elif rec["kind"] == "server" and rec["parent_id"]:
             tl.flow(rec["name"], rec["parent_id"], "f", ts_us=start)
     except Exception:
         pass   # observability never takes down the path being observed
+
+
+def _annotation(name: str, args: Optional[Dict[str, Any]]):
+    """The profiler's view of a span: a ``TraceAnnotation`` carrying the
+    span's scalar args — or nothing, in a process that never imported
+    JAX (a launcher's RPC client: no profiler session can be live
+    there, and importing JAX for it would cost a second)."""
+    jax = sys.modules.get("jax")
+    if jax is None:
+        return contextlib.nullcontext()
+    try:
+        return jax.profiler.TraceAnnotation(name, **_scalars(args))
+    except Exception:
+        return contextlib.nullcontext()
+
+
+def scope(name: str):
+    """A named region INSIDE a compiled program: ``jax.named_scope``,
+    so every operation traced in the block carries ``name`` in its
+    metadata (``op_name``) — what xprof groups by and what a reader of
+    the device trace attributes device time with.  Metadata only: no
+    operation, shape or fusion changes."""
+    import jax
+
+    return jax.named_scope(name)
 
 
 def record_span(name: str, *, parent: Optional[Tuple[str, str]],
@@ -210,7 +295,7 @@ def record_span(name: str, *, parent: Optional[Tuple[str, str]],
         "start_us": float(start_us),
         "dur_us": max(0.0, float(dur_us)),
         "rank": process_rank(),
-        "pid": os.getpid(),
+        "pid": _pid,
         "args": dict(args) if args else {},
     }
     _append(rec)
@@ -240,7 +325,9 @@ def span(name: str, *, root: bool = False,
     ``root=True`` forces a fresh trace (the step loop / router
     admission); ``parent`` grafts onto an explicit remote context (the
     server side of an RPC).  An escaping exception is recorded in the
-    span's args as ``error`` and re-raised."""
+    span's args as ``error`` and re-raised.  The same interval is
+    entered as a ``jax.profiler.TraceAnnotation`` (module docstring:
+    the profiler bridge)."""
     if not enabled():
         yield None
         return
@@ -257,15 +344,25 @@ def span(name: str, *, root: bool = False,
     ctx = (trace_id, _new_id(8))
     prev = current()
     _tls.ctx = ctx
+    error = None
+    annotation = _annotation(name, args)
+    annotation.__enter__()
     start = now_us()
-    span_args = dict(args) if args else {}
     try:
         yield ctx
     except BaseException as e:
-        span_args["error"] = type(e).__name__
+        error = type(e).__name__
         raise
     finally:
+        dur = max(0.0, now_us() - start)
+        annotation.__exit__(None, None, None)
         _tls.ctx = prev
+        # ``args`` is read here, not at entry: a caller that learns a
+        # span's counts while it runs (the batcher's step) fills the
+        # dict it passed in.
+        span_args = dict(args) if args else {}
+        if error is not None:
+            span_args["error"] = error
         rec = {
             "name": name,
             "trace_id": trace_id,
@@ -273,9 +370,9 @@ def span(name: str, *, root: bool = False,
             "parent_id": parent_id,
             "kind": kind,
             "start_us": start,
-            "dur_us": max(0.0, now_us() - start),
+            "dur_us": dur,
             "rank": process_rank(),
-            "pid": os.getpid(),
+            "pid": _pid,
             "args": span_args,
         }
         _append(rec)

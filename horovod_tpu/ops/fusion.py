@@ -35,6 +35,7 @@ from static sizes, so every rank agrees without negotiation.
 from __future__ import annotations
 
 import dataclasses
+import itertools
 from typing import Any, Callable, List, Optional, Sequence, Tuple
 
 import jax
@@ -43,6 +44,7 @@ import numpy as np
 
 from ..config import DEFAULT_COST_ALPHA_US, DEFAULT_COST_BETA_GBPS
 from ..obs import instrument as _obs
+from ..obs import trace as _trace
 
 
 def wire_ratio(compression, data_itemsize: int) -> float:
@@ -386,42 +388,48 @@ def fused_apply(
     if use_ffi:
         from ..native import ffi as native_ffi
 
+    bucket_ids = itertools.count()
     for dtype, idxs in by_dtype.items():
         sizes = [int(np.prod(leaves[i].shape[lead_ndim:])) * dtype.itemsize
                  for i in idxs]
         for bucket in plan_buckets(sizes, threshold):
             members = [idxs[j] for j in bucket]
-            flats = [leaves[i].reshape(leaves[i].shape[:lead_ndim] + (-1,))
-                     for i in members]
-            if len(flats) > 1 and use_ffi:
-                # [rows, n_i] normal form (rows=1 when there is no slot
-                # axis); the handler does one row-strided memcpy pass.
-                rows2 = [f.reshape((-1, f.shape[-1])) for f in flats]
-                fused = native_ffi.bucket_pack(rows2).reshape(
-                    flats[0].shape[:-1] + (-1,))
-            elif len(flats) > 1:
-                fused = jnp.concatenate(flats, axis=lead_ndim)
-            else:
-                fused = flats[0]
-            reduced = collective_1d(fused)
+            with _trace.scope("hvd_tpu_wire_pack"):
+                flats = [leaves[i].reshape(
+                    leaves[i].shape[:lead_ndim] + (-1,)) for i in members]
+                if len(flats) > 1 and use_ffi:
+                    # [rows, n_i] normal form (rows=1 when there is no
+                    # slot axis); the handler does one row-strided
+                    # memcpy pass.
+                    rows2 = [f.reshape((-1, f.shape[-1])) for f in flats]
+                    fused = native_ffi.bucket_pack(rows2).reshape(
+                        flats[0].shape[:-1] + (-1,))
+                elif len(flats) > 1:
+                    fused = jnp.concatenate(flats, axis=lead_ndim)
+                else:
+                    fused = flats[0]
+            with _trace.scope(f"hvd_tpu_wire_bucket_{next(bucket_ids)}"):
+                reduced = collective_1d(fused)
             cols = [int(np.prod(leaves[i].shape[lead_ndim:]))
                     if leaves[i].shape[lead_ndim:] else 1
                     for i in members]
-            if len(members) > 1 and use_ffi:
-                pieces = native_ffi.bucket_unpack(
-                    reduced.reshape((-1, reduced.shape[-1])), cols)
-                for i, piece in zip(members, pieces):
-                    out[i] = piece.reshape(
-                        reduced.shape[:-1] + leaves[i].shape[lead_ndim:])
-                continue
-            offset = 0
-            for i, n in zip(members, cols):
-                tail_shape = leaves[i].shape[lead_ndim:]
-                piece = jax.lax.dynamic_slice_in_dim(
-                    reduced, offset, n, axis=reduced.ndim - 1
-                )
-                out[i] = piece.reshape(reduced.shape[:-1] + tail_shape)
-                offset += n
+            with _trace.scope("hvd_tpu_wire_unpack"):
+                if len(members) > 1 and use_ffi:
+                    pieces = native_ffi.bucket_unpack(
+                        reduced.reshape((-1, reduced.shape[-1])), cols)
+                    for i, piece in zip(members, pieces):
+                        out[i] = piece.reshape(
+                            reduced.shape[:-1]
+                            + leaves[i].shape[lead_ndim:])
+                    continue
+                offset = 0
+                for i, n in zip(members, cols):
+                    tail_shape = leaves[i].shape[lead_ndim:]
+                    piece = jax.lax.dynamic_slice_in_dim(
+                        reduced, offset, n, axis=reduced.ndim - 1
+                    )
+                    out[i] = piece.reshape(reduced.shape[:-1] + tail_shape)
+                    offset += n
     return out
 
 
@@ -493,10 +501,12 @@ def fused_two_phase_apply(
                  for i in idxs]
         for bucket in plan_buckets(sizes, threshold):
             members = [idxs[j] for j in bucket]
-            flats = [leaves[i].reshape(-1) for i in members]
-            fused = (jnp.concatenate(flats) if len(flats) > 1 else flats[0])
-            if prescale_factor != 1.0:
-                fused = fused * prescale_factor
+            with _trace.scope("hvd_tpu_wire_pack"):
+                flats = [leaves[i].reshape(-1) for i in members]
+                fused = (jnp.concatenate(flats) if len(flats) > 1
+                         else flats[0])
+                if prescale_factor != 1.0:
+                    fused = fused * prescale_factor
             packed.append({
                 "members": members,
                 "fused": fused,
@@ -549,38 +559,41 @@ def fused_two_phase_apply(
     reduced: dict = {}
     for kind, bi in order:
         b = packed[bi]
-        if kind == "ar":
-            sched = scheds.get(bi)
-            if sched is not None:
-                from ..topo import schedule as _topo_sched
+        with _trace.scope(f"hvd_tpu_wire_bucket_{bi}"):
+            if kind == "ar":
+                sched = scheds.get(bi)
+                if sched is not None:
+                    from ..topo import schedule as _topo_sched
 
-                reduced[bi] = _topo_sched.execute_schedule(
-                    b["fused"], sched, axis=axis, op=op,
-                    compression=compression)
-            else:
-                reduced[bi] = compression.spmd_allreduce(
-                    b["fused"], op=op, axis=axis, groups=groups)
-        elif kind == "rs":
-            x = b["fused"]
-            pad = (-x.size) % n
-            if pad:
-                x = jnp.concatenate([x, jnp.zeros((pad,), x.dtype)])
-            shards[bi] = compression.spmd_reducescatter(
-                x, op=op, axis=axis, groups=groups)
-        else:  # "ag"
-            full = compression.spmd_allgather(shards.pop(bi), axis=axis,
-                                              groups=groups)
-            reduced[bi] = full[: b["fused"].size]
+                    reduced[bi] = _topo_sched.execute_schedule(
+                        b["fused"], sched, axis=axis, op=op,
+                        compression=compression)
+                else:
+                    reduced[bi] = compression.spmd_allreduce(
+                        b["fused"], op=op, axis=axis, groups=groups)
+            elif kind == "rs":
+                x = b["fused"]
+                pad = (-x.size) % n
+                if pad:
+                    x = jnp.concatenate([x, jnp.zeros((pad,), x.dtype)])
+                shards[bi] = compression.spmd_reducescatter(
+                    x, op=op, axis=axis, groups=groups)
+            else:  # "ag"
+                full = compression.spmd_allgather(
+                    shards.pop(bi), axis=axis, groups=groups)
+                reduced[bi] = full[: b["fused"].size]
 
-    for bi, b in enumerate(packed):
-        r = reduced[bi]
-        if postscale_factor != 1.0:
-            r = r * postscale_factor
-        offset = 0
-        for i, ncols in zip(b["members"], b["cols"]):
-            piece = jax.lax.dynamic_slice_in_dim(r, offset, ncols, axis=0)
-            out[i] = piece.reshape(leaves[i].shape)
-            offset += ncols
+    with _trace.scope("hvd_tpu_wire_unpack"):
+        for bi, b in enumerate(packed):
+            r = reduced[bi]
+            if postscale_factor != 1.0:
+                r = r * postscale_factor
+            offset = 0
+            for i, ncols in zip(b["members"], b["cols"]):
+                piece = jax.lax.dynamic_slice_in_dim(r, offset, ncols,
+                                                     axis=0)
+                out[i] = piece.reshape(leaves[i].shape)
+                offset += ncols
     return out
 
 
@@ -691,20 +704,23 @@ def overlap_reduce_scatter(leaves: Sequence[jax.Array],
     inverts the permutation — flat-equivalent end to end."""
     shards: List[jax.Array] = [None] * len(plan.members)  # type: ignore
     for bi in plan.order:
-        flats = [leaves[i].reshape(-1) for i in plan.members[bi]]
-        fused = jnp.concatenate(flats) if len(flats) > 1 else flats[0]
-        if plan.pad[bi]:
-            fused = jnp.concatenate(
-                [fused, jnp.zeros((plan.pad[bi],), fused.dtype)])
+        with _trace.scope("hvd_tpu_wire_pack"):
+            flats = [leaves[i].reshape(-1) for i in plan.members[bi]]
+            fused = jnp.concatenate(flats) if len(flats) > 1 else flats[0]
+            if plan.pad[bi]:
+                fused = jnp.concatenate(
+                    [fused, jnp.zeros((plan.pad[bi],), fused.dtype)])
         sched = _overlap_bucket_schedule(plan, bi, topo)
-        if sched is not None:
-            from ..topo import schedule as _topo_sched_mod
+        with _trace.scope(f"hvd_tpu_wire_bucket_{bi}"):
+            if sched is not None:
+                from ..topo import schedule as _topo_sched_mod
 
-            shards[bi] = _topo_sched_mod.hierarchical_reduce_scatter(
-                fused, sched, axis=axis, op=op, compression=compression)
-        else:
-            shards[bi] = compression.spmd_reducescatter(
-                fused, op=op, axis=axis, groups=groups)
+                shards[bi] = _topo_sched_mod.hierarchical_reduce_scatter(
+                    fused, sched, axis=axis, op=op,
+                    compression=compression)
+            else:
+                shards[bi] = compression.spmd_reducescatter(
+                    fused, op=op, axis=axis, groups=groups)
     return tuple(shards)
 
 
@@ -722,21 +738,24 @@ def overlap_all_gather(shards: Sequence[jax.Array],
     out: List[jax.Array] = [None] * len(leaves_like)  # type: ignore
     for bi, shard in enumerate(shards):
         sched = _overlap_bucket_schedule(plan, bi, topo)
-        if sched is not None:
-            from ..topo import schedule as _topo_sched_mod
+        with _trace.scope(f"hvd_tpu_wire_bucket_{bi}"):
+            if sched is not None:
+                from ..topo import schedule as _topo_sched_mod
 
-            full = _topo_sched_mod.hierarchical_all_gather(
-                shard, sched, axis=axis, compression=compression)
-        else:
-            full = compression.spmd_allgather(shard, axis=axis,
-                                              groups=groups)
-        full = full[: plan.payload[bi]]
-        offset = 0
-        for i, ncols in zip(plan.members[bi], plan.cols[bi]):
-            piece = jax.lax.dynamic_slice_in_dim(full, offset, ncols, axis=0)
-            out[i] = piece.reshape(leaves_like[i].shape).astype(
-                leaves_like[i].dtype)
-            offset += ncols
+                full = _topo_sched_mod.hierarchical_all_gather(
+                    shard, sched, axis=axis, compression=compression)
+            else:
+                full = compression.spmd_allgather(shard, axis=axis,
+                                                  groups=groups)
+        with _trace.scope("hvd_tpu_wire_unpack"):
+            full = full[: plan.payload[bi]]
+            offset = 0
+            for i, ncols in zip(plan.members[bi], plan.cols[bi]):
+                piece = jax.lax.dynamic_slice_in_dim(full, offset, ncols,
+                                                     axis=0)
+                out[i] = piece.reshape(leaves_like[i].shape).astype(
+                    leaves_like[i].dtype)
+                offset += ncols
     return out
 
 

@@ -137,6 +137,7 @@ def quantize_blocks(blocks, *, interpret: Optional[bool] = None):
             jax.ShapeDtypeStruct((rows_p, 1), jnp.float32),
         ],
         interpret=interpret,
+        name="hvd_tpu_quantize_blocks",
     )(xp)
     return q[:rows], s[:rows, 0]
 
@@ -160,6 +161,7 @@ def dequantize_blocks(q, scales, *, interpret: Optional[bool] = None):
         out_specs=pl.BlockSpec((rt, b), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((rows_p, b), jnp.float32),
         interpret=interpret,
+        name="hvd_tpu_dequantize_blocks",
     )(qp, sp)
     return out[:rows]
 
@@ -245,6 +247,7 @@ def fused_quantize_reducescatter(x, *, op: str = "sum", axis: str = "hvd",
         out_specs=pl.BlockSpec((mt, b), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((m_p, b), jnp.float32),
         interpret=resolve_interpret(interpret),
+        name="hvd_tpu_dequant_accumulate",
     )(qp, sp)
     partial = partial[:m].reshape(-1)
     if pad:
@@ -367,9 +370,10 @@ def _unblocked(rows2d, n, k, pad, dtype):
     return out.reshape(-1).astype(dtype)
 
 
-def _apply_gridded(kernel, inputs, out_shapes, rows, b, interpret):
+def _apply_gridded(kernel, inputs, out_shapes, rows, b, interpret, name):
     """Run a leaf-update kernel over ``[rows, b]`` block rows: pads the
-    row axis to the tile, grids, slices the pad back off."""
+    row axis to the tile, grids, slices the pad back off.  ``name`` is
+    the kernel's stable name in a device trace."""
     interpret = resolve_interpret(interpret)
     rows_p, rt = _row_grid(rows, interpret)
     padded = []
@@ -387,6 +391,7 @@ def _apply_gridded(kernel, inputs, out_shapes, rows, b, interpret):
         out_shape=[jax.ShapeDtypeStruct((rows_p, b), dt)
                    for dt in out_shapes],
         interpret=resolve_interpret(interpret),
+        name=name,
     )(*padded)
     if not isinstance(outs, (list, tuple)):
         outs = (outs,)
@@ -419,7 +424,7 @@ def fused_allgather_sgd_apply(param, grad_shard, *, lr: float,
     (new_p,) = _apply_gridded(
         functools.partial(_sgd_kernel, lr=float(lr)),
         [gathered.reshape(rows, b), s_all.reshape(rows, 1), p_rows],
-        [jnp.float32], rows, b, interpret)
+        [jnp.float32], rows, b, interpret, "hvd_tpu_fused_sgd_apply")
     return _unblocked(new_p, n, k, pad, param.dtype).reshape(param.shape)
 
 
@@ -462,7 +467,8 @@ def fused_allgather_adam_apply(param, mu, nu, grad_shard, *, lr: float,
                           b2=float(b2), eps=float(eps), bc1=bc1, bc2=bc2),
         [gathered.reshape(rows, b), s_all.reshape(rows, 1),
          p_rows, m_rows, v_rows],
-        [jnp.float32, jnp.float32, jnp.float32], rows, b, interpret)
+        [jnp.float32, jnp.float32, jnp.float32], rows, b, interpret,
+        "hvd_tpu_fused_adam_apply")
     return (_unblocked(new_p, n, k, pad, param.dtype).reshape(param.shape),
             _unblocked(new_m, n, k, pad, mu.dtype).reshape(mu.shape),
             _unblocked(new_v, n, k, pad, nu.dtype).reshape(nu.shape))
@@ -528,6 +534,7 @@ def fused_matmul_allgather(x, w_shard, *, axis: str = "hvd", groups=None,
         out_shape=jax.ShapeDtypeStruct((mp, np_), x.dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         interpret=resolve_interpret(interpret),
+        name="hvd_tpu_matmul_allgather",
     )(xp, wp)[:mm, :nl]
     n = _group_size(axis, groups)
     if n == 1:

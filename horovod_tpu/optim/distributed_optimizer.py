@@ -49,6 +49,7 @@ from ..ops.adasum import adasum_pytree
 from ..ops.compression import Compression
 from ..ops.fusion import fused_allreduce_pytree
 from ..obs import instrument as _obs
+from ..obs import trace as _trace
 from ..utils.logging import get_logger
 
 logger = get_logger(__name__)
@@ -658,7 +659,14 @@ def make_train_step(
                         "optimizer in DistributedOptimizer("
                         "error_feedback=True) to carry the residual on "
                         "long runs")
-            grad_fn = jax.value_and_grad(loss_fn, has_aux=has_aux)
+            value_and_grad = jax.value_and_grad(loss_fn, has_aux=has_aux)
+
+            def grad_fn(p, b):
+                # Every forward/backward of the step, the microbatch
+                # scan's included, under one scope of the program.
+                with _trace.scope("hvd_tpu_fwd_bwd"):
+                    return value_and_grad(p, b)
+
             mb = _resolve_microbatches(microbatches, batch)
             reduced = False
             if mb > 1:
@@ -683,8 +691,12 @@ def make_train_step(
                     compression=comp, threshold=_threshold(),
                     two_phase=two_phase, pipeline_depth=pipeline_depth,
                 )
-            updates, opt_state = optimizer.update(grads, opt_state, params)
-            params = optax.apply_updates(params, updates)
+            # A DistributedOptimizer's own wire scopes nest inside this
+            # one; a reader charges an operation to its innermost scope.
+            with _trace.scope("hvd_tpu_optimizer"):
+                updates, opt_state = optimizer.update(grads, opt_state,
+                                                      params)
+                params = optax.apply_updates(params, updates)
             loss = spmd.allreduce(loss, op="average", axis=axis,
                                   groups=groups)
             if has_aux:

@@ -7,7 +7,7 @@ training framework's existing layers:
 * :mod:`~horovod_tpu.serve.engine` — jitted, length-bucketed prefill +
   slot-batched single-token decode over ``models.transformer.GPT``
   (preallocated KV cache, greedy/temperature/top-k sampling,
-  Timeline phases ``SERVE_PREFILL``/``SERVE_DECODE``)
+  spans ``hvd_tpu_engine_prefill``/``hvd_tpu_engine_decode``)
 * :mod:`~horovod_tpu.serve.batcher` — continuous-batching scheduler
   (bounded admission queue, per-request deadlines, reject-when-full
   backpressure)

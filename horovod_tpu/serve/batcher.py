@@ -55,6 +55,17 @@ logger = get_logger(__name__)
 _ids = itertools.count()
 
 
+def _request_context():
+    """The trace a request's phases are recorded under: the caller's
+    (an RPC handler's span), or a fresh root when the caller has none —
+    an in-process ``submit`` is traced like one from the wire.  Returns
+    ``(context, minted here)``; ``(None, False)`` with tracing off."""
+    ctx = trace_mod.current()
+    if ctx is not None or not trace_mod.enabled():
+        return ctx, False
+    return trace_mod.new_context(), True
+
+
 class QueueFullError(RuntimeError):
     """Admission queue at capacity — reject-when-full backpressure."""
 
@@ -79,10 +90,17 @@ class ServeRequest:
     prompt: List[int]
     sampling: SamplingParams
     deadline: Optional[float] = None       # absolute time.monotonic()
+    # time.monotonic() stamps of the request's life, in order:
+    # submitted_at <= admitted_at (popped from the queue into a slot)
+    # <= first_token_at == token_times[0] <= ... <= finished_at.  Queue
+    # wait is admitted_at - submitted_at; token_times holds one stamp
+    # per emitted token (len(token_times) == len(tokens)).
     submitted_at: float = 0.0
+    admitted_at: Optional[float] = None
     first_token_at: Optional[float] = None
     finished_at: Optional[float] = None
     tokens: List[int] = dataclasses.field(default_factory=list)
+    token_times: List[float] = dataclasses.field(default_factory=list)
     error: Optional[str] = None
     # Resident-prefix tokens (admission-time probe, refined to the
     # actual binding at prefill) — the cache-hit/miss signal the bench
@@ -90,10 +108,14 @@ class ServeRequest:
     prefix_hit_tokens: int = 0
     done: threading.Event = dataclasses.field(
         default_factory=threading.Event)
-    # Trace context captured at submit (the server handler's span): the
-    # batcher thread reconstructs queued/prefill/decode phase spans
-    # against it, so the request's trace crosses the thread handoff.
+    # Trace context captured at submit (the server handler's span), or
+    # minted there when the caller has none (``trace_root``: the
+    # request then records its own ``hvd_tpu_serve_request`` root when
+    # it finishes).  The batcher thread reconstructs queued/prefill/
+    # decode phase spans against it, so the request's trace crosses the
+    # thread handoff.
     trace_ctx: Optional[tuple] = None
+    trace_root: bool = False
     # Disaggregated fleet (serve/fleet/): the decode target the router
     # asked this (prefill) replica to migrate to, the wire-received KV
     # payload on the adopting (decode) side, and the migration outcome
@@ -124,6 +146,16 @@ class ServeRequest:
             return
         self.error = error
         self.finished_at = time.monotonic()
+        if self.trace_root:
+            # BEFORE ``done`` fires, like the stats: a caller that sees
+            # the request finished finds its whole trace in the ring.
+            trace_mod.record_span(
+                "hvd_tpu_serve_request", parent=None, ctx=self.trace_ctx,
+                start_us=trace_mod.mono_us(self.submitted_at),
+                dur_us=(self.finished_at - self.submitted_at) * 1e6,
+                args={"request_id": self.request_id,
+                      "tokens": len(self.tokens),
+                      **({"error": error} if error else {})})
         self.done.set()
 
 
@@ -190,6 +222,7 @@ class ContinuousBatcher:
         # admission, lets in-flight generations run dry, then runs at
         # the step boundary — no request ever sees mixed weights.
         self._pending_flip: Optional[tuple] = None   # guarded-by: _lock
+        self._admitted = 0     # requests brought into a slot (step thread)
         self._thread: Optional[threading.Thread] = None
         self._stop = threading.Event()
         self._wake = threading.Event()
@@ -365,6 +398,7 @@ class ContinuousBatcher:
         hit = self.engine.prefix_probe(prompt)
         limit = (deadline_s if deadline_s is not None
                  else self.default_deadline_s)
+        trace_ctx, trace_root = _request_context()
         req = ServeRequest(
             request_id=request_id or f"req-{next(_ids)}",
             prompt=list(prompt), sampling=sampling,
@@ -372,7 +406,7 @@ class ContinuousBatcher:
             else None,
             submitted_at=time.monotonic(),
             prefix_hit_tokens=hit,
-            trace_ctx=trace_mod.current(),
+            trace_ctx=trace_ctx, trace_root=trace_root,
             migrate_to=migrate_to,
             tenant=(tenant or "default"), qos_class=qos_class)
         self._admit(req)
@@ -414,12 +448,13 @@ class ContinuousBatcher:
                              "tokens — nothing to continue from")
         limit = manifest.get("deadline_s")
         now = time.monotonic()
+        trace_ctx, trace_root = _request_context()
         req = ServeRequest(
             request_id=manifest["request_id"], prompt=prompt,
             sampling=sampling,
             deadline=(now + limit) if limit and limit > 0 else None,
             submitted_at=now,
-            trace_ctx=trace_mod.current(),
+            trace_ctx=trace_ctx, trace_root=trace_root,
             kv_import=(manifest, k_blocks, v_blocks),
             tenant=manifest.get("tenant", "default"),
             qos_class=validate_class(manifest.get("qos_class")))
@@ -525,14 +560,12 @@ class ContinuousBatcher:
                       start_mono: float, end_mono: float, **args) -> None:
         """One reconstructed phase span on the request's trace (the
         batcher thread has no ambient context — phases are parented to
-        the context captured at submit, with monotonic timestamps
-        re-anchored onto the span clock)."""
-        if req.trace_ctx is None or not trace_mod.enabled():
+        the context captured at submit; the request's monotonic stamps
+        are already on the span clock)."""
+        if req.trace_ctx is None:
             return
-        now_us, now_mono = trace_mod.now_us(), time.monotonic()
-        start_us = now_us - (now_mono - start_mono) * 1e6
         trace_mod.record_span(name, parent=req.trace_ctx,
-                              start_us=start_us,
+                              start_us=trace_mod.mono_us(start_mono),
                               dur_us=(end_mono - start_mono) * 1e6,
                               args=args or None)
 
@@ -559,11 +592,15 @@ class ContinuousBatcher:
                                req.first_token_at, end,
                                tokens=len(req.tokens))
         self._settle_budget(req)
+        times = req.token_times
         self.stats.record_request(
             ttft_s=(req.first_token_at or end) - req.submitted_at,
             n_tokens=len(req.tokens),
             total_s=end - req.submitted_at,
-            qos_class=req.qos_class, tenant=req.tenant)
+            qos_class=req.qos_class, tenant=req.tenant,
+            queue_wait_s=(None if req.admitted_at is None
+                          else req.admitted_at - req.submitted_at),
+            itl_s=[b - a for a, b in zip(times, times[1:])])
         req.finish()
 
     def _emit(self, slot: int, req: ServeRequest, token: int,
@@ -573,6 +610,7 @@ class ContinuousBatcher:
         if req.first_token_at is None:
             req.first_token_at = now
         req.tokens.append(token)
+        req.token_times.append(now)
         stop = req.sampling.stop_token
         # ``check_full`` is False for all but the last token of a
         # speculative burst: the engine advanced the slot position past
@@ -589,7 +627,10 @@ class ContinuousBatcher:
         returns the tokens emitted.  The caller already placed ``req``
         in ``self._slots[slot]``."""
         emitted = 0
+        self._admitted += 1
         prefill_t0 = time.monotonic()
+        if req.admitted_at is None:     # a resumed request keeps its first
+            req.admitted_at = prefill_t0
         imported = req.kv_import is not None
         resumed = req.resume_state is not None
         if resumed and req.weights_version is not None and \
@@ -605,6 +646,8 @@ class ContinuousBatcher:
             # reused either way).
             req.resume_state = None
             req.tokens.clear()
+            req.token_times.clear()
+            req.first_token_at = None
             resumed = False
         if self._lockstep is not None and not imported and not resumed:
             # TP lockstep: followers prefill the same slot before the
@@ -757,7 +800,25 @@ class ContinuousBatcher:
 
     def step(self) -> int:
         """One scheduling iteration; returns the number of tokens
-        emitted (0 = idle)."""
+        emitted (0 = idle).  Runs under an ``hvd_tpu_serve_step`` span
+        (args ``active``/``queued``/``admitted``/``emitted``) whose
+        children are the engine's prefill and decode spans — except a
+        step that finds the queue and the slots empty, which records
+        nothing: the daemon loop polls every 5 ms when idle and would
+        wash the span ring out."""
+        counts: Dict[str, int] = {}
+        if not trace_mod.enabled():
+            return self._step(counts)
+        with self._lock:
+            idle = (not self._slots and not len(self._queue)
+                    and self._pending_flip is None)
+        if idle:
+            return self._step(counts)
+        with trace_mod.span("hvd_tpu_serve_step", args=counts):
+            return self._step(counts)
+
+    def _step(self, counts: Dict[str, int]) -> int:
+        """:meth:`step`'s body; fills ``counts`` for the step's span."""
         with self._lock:
             if self._killed is not None:
                 raise ReplicaKilledError(self._killed)
@@ -765,6 +826,7 @@ class ContinuousBatcher:
         now = time.monotonic()
         self._expire(now)
         emitted = 0
+        admitted_before = self._admitted
         if flip is not None:
             # Swap barrier: admission holds (queued requests WAIT — a
             # swap never drops work), in-flight generations keep
@@ -835,9 +897,13 @@ class ContinuousBatcher:
                     if req.done.is_set():
                         break
         with self._lock:
+            queued = len(self._queue)
             self.stats.record_step(active=len(self._slots),
                                    slots=self.engine.max_slots,
-                                   queued=len(self._queue))
+                                   queued=queued)
+        counts.update(active=len(active), queued=queued,
+                      admitted=self._admitted - admitted_before,
+                      emitted=emitted)
         return emitted
 
     def _handoff(self, slot: int, req: ServeRequest) -> None:

@@ -60,6 +60,7 @@ import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec
 
 from ..models.transformer import GPT, init_kv_cache
+from ..obs import trace as trace_mod
 from ..utils.logging import get_logger
 from .kv import BlockPool, TRASH_BLOCK
 
@@ -129,8 +130,10 @@ class InferenceEngine:
     and returns its first token; ``step()`` decodes for every active
     slot and returns ``{slot: [tokens]}`` — one token per slot on the
     plain path, up to ``spec_k + 1`` under speculative decoding.
-    Per-phase wall time lands on the framework Timeline (phases
-    ``SERVE_PREFILL`` / ``SERVE_DECODE``) when one is active.
+    Each call is one span of ``obs/trace.py``
+    (``hvd_tpu_engine_prefill`` / ``hvd_tpu_engine_decode``): in the
+    span ring, on a live profiler's host plane, and mirrored onto the
+    framework Timeline when one is active (docs/tracing.md).
     """
 
     def __init__(self, model: GPT, params, *,
@@ -577,18 +580,6 @@ class InferenceEngine:
 
     # --- host-side slot API -------------------------------------------------
 
-    def _activity(self, name: str, phase: str, args=None):
-        """Timeline span for one serving phase (no-op without an active
-        framework timeline)."""
-        import contextlib
-
-        from .. import basics
-
-        tl = basics.peek("timeline")   # fail-soft: None pre-init
-        if tl is None or not tl.enabled:
-            return contextlib.nullcontext()
-        return tl.activity(name, phase, args)
-
     def _next_rng(self):
         self._rng, sub = jax.random.split(self._rng)
         return sub
@@ -710,7 +701,14 @@ class InferenceEngine:
         """Prefill ``prompt`` into ``slot``; returns the first sampled
         token.  One compiled program per (bucket, slot-batch) shape —
         on the paged tier the bucket covers only the non-resident
-        suffix."""
+        suffix.  The whole call is one ``hvd_tpu_engine_prefill``
+        span."""
+        span_args = {"slot": int(slot), "prompt_len": len(prompt)}
+        with trace_mod.span("hvd_tpu_engine_prefill", args=span_args):
+            return self._start(slot, prompt, sampling, span_args)
+
+    def _start(self, slot: int, prompt: Sequence[int],
+               sampling: SamplingParams, span_args: dict) -> int:
         with self._slot_lock:
             if self._active[slot]:
                 raise RuntimeError(f"slot {slot} is already active")
@@ -725,16 +723,14 @@ class InferenceEngine:
             padded = np.zeros((1, L), np.int32)
             padded[0, :ns] = np.asarray(prompt[hit:], np.int32)
             fn = self._prefill_fns[L]
-            with self._activity(f"serve/slot{slot}", "SERVE_PREFILL",
-                                {"bucket": L, "prompt_len": n,
-                                 "prefix_hit": hit}):
-                token, self._pools = fn(
-                    self._params, self._pools,
-                    jnp.asarray(self._table[slot]), jnp.asarray(padded),
-                    jnp.int32(hit), jnp.int32(ns), self._next_rng(),
-                    jnp.float32(sampling.temperature),
-                    jnp.int32(sampling.top_k))
-                token = int(token)
+            span_args.update(bucket=L, prefix_hit=hit)
+            token, self._pools = fn(
+                self._params, self._pools,
+                jnp.asarray(self._table[slot]), jnp.asarray(padded),
+                jnp.int32(hit), jnp.int32(ns), self._next_rng(),
+                jnp.float32(sampling.temperature),
+                jnp.int32(sampling.top_k))
+            token = int(token)
             self._kv.index_prompt(slot, prompt)
         else:
             hit = 0
@@ -742,14 +738,13 @@ class InferenceEngine:
             padded = np.zeros((1, L), np.int32)
             padded[0, :n] = np.asarray(prompt, np.int32)
             fn = self._prefill_fns[L]
-            with self._activity(f"serve/slot{slot}", "SERVE_PREFILL",
-                                {"bucket": L, "prompt_len": n}):
-                token, self._caches = fn(
-                    self._params, self._caches, jnp.asarray(padded),
-                    jnp.int32(n), jnp.int32(slot), self._next_rng(),
-                    jnp.float32(sampling.temperature),
-                    jnp.int32(sampling.top_k))
-                token = int(token)
+            span_args.update(bucket=L, prefix_hit=0)
+            token, self._caches = fn(
+                self._params, self._caches, jnp.asarray(padded),
+                jnp.int32(n), jnp.int32(slot), self._next_rng(),
+                jnp.float32(sampling.temperature),
+                jnp.int32(sampling.top_k))
+            token = int(token)
         if self._drafter is not None:
             # The drafter recomputes the full prompt (its dense cache
             # shares nothing) — it is the small model by construction.
@@ -766,36 +761,39 @@ class InferenceEngine:
         """One decode step for every active slot → ``{slot: [tokens]}``
         (one token per slot on the plain path; up to ``spec_k + 1``
         under speculative decoding).  Inactive rows ride along masked
-        and write into the trash block."""
-        act, pos, temps, topks, last_tokens, spec = self._slot_snapshot()
-        active = [int(s) for s in np.nonzero(act)[0]]
+        and write into the trash block.  A step with an active slot is
+        one ``hvd_tpu_engine_decode`` span: the host's table building,
+        the dispatch, the device's work and the token fence."""
+        snap = self._slot_snapshot()
+        active = [int(s) for s in np.nonzero(snap[0])[0]]
         if not active:
             return {}
+        with trace_mod.span("hvd_tpu_engine_decode",
+                            args={"active": len(active)}):
+            return self._step(active, snap)
+
+    def _step(self, active: List[int],
+              snap: tuple) -> Dict[int, List[int]]:
+        act, pos, temps, topks, last_tokens, spec = snap
         if self._drafter is not None and any(
                 spec[s] and temps[s] <= 0 for s in active):
-            return self._step_spec(
-                active, (act, pos, temps, topks, last_tokens, spec))
+            return self._step_spec(active, snap)
         positions = np.where(act, pos, 0).astype(np.int32)
         if self.kv_mode == "paged":
             for s in active:
                 self._kv.ensure_writable(s, int(positions[s]), 1)
-            with self._activity("serve/decode", "SERVE_DECODE",
-                                {"batch": len(active)}):
-                nxt, self._pools = self._decode_fn(
-                    self._params, self._pools, jnp.asarray(self._table),
-                    jnp.asarray(last_tokens), jnp.asarray(positions),
-                    jnp.asarray(temps), jnp.asarray(topks),
-                    self._next_rng())
-                nxt = np.asarray(nxt)
+            nxt, self._pools = self._decode_fn(
+                self._params, self._pools, jnp.asarray(self._table),
+                jnp.asarray(last_tokens), jnp.asarray(positions),
+                jnp.asarray(temps), jnp.asarray(topks),
+                self._next_rng())
         else:
-            with self._activity("serve/decode", "SERVE_DECODE",
-                                {"batch": len(active)}):
-                nxt, self._caches = self._decode_fn(
-                    self._params, self._caches,
-                    jnp.asarray(last_tokens), jnp.asarray(positions),
-                    jnp.asarray(temps), jnp.asarray(topks),
-                    self._next_rng())
-                nxt = np.asarray(nxt)
+            nxt, self._caches = self._decode_fn(
+                self._params, self._caches,
+                jnp.asarray(last_tokens), jnp.asarray(positions),
+                jnp.asarray(temps), jnp.asarray(topks),
+                self._next_rng())
+        nxt = np.asarray(nxt)
         out = {}
         for s in active:
             toks = [int(nxt[s])]
@@ -820,26 +818,24 @@ class InferenceEngine:
             p = int(positions[s])
             self._kv.ensure_writable(s, p, min(K + 1, self.max_seq_len - p))
         spec_ok = act & spec & (temps <= 0)
-        with self._activity("serve/decode", "SERVE_DECODE",
-                            {"batch": len(active), "spec_k": K}):
-            draft, self._drafter_caches = self._spec_draft_fn(
-                self._drafter_params, self._drafter_caches,
-                jnp.asarray(last_tokens), jnp.asarray(positions))
-            if self._tp_mesh is not None:
-                # The drafter runs single-device (it is the small model
-                # by construction); re-home its committed draft onto the
-                # TP mesh so the verify program sees one device set.
-                draft = jax.device_put(
-                    np.asarray(draft),
-                    NamedSharding(self._tp_mesh, PartitionSpec()))
-            out, accepted, self._pools = self._spec_verify_fn(
-                self._params, self._pools, jnp.asarray(self._table),
-                jnp.asarray(last_tokens), draft,
-                jnp.asarray(positions), jnp.asarray(temps),
-                jnp.asarray(topks), jnp.asarray(spec_ok),
-                self._next_rng())
-            out = np.asarray(out)
-            accepted = np.asarray(accepted)
+        draft, self._drafter_caches = self._spec_draft_fn(
+            self._drafter_params, self._drafter_caches,
+            jnp.asarray(last_tokens), jnp.asarray(positions))
+        if self._tp_mesh is not None:
+            # The drafter runs single-device (it is the small model by
+            # construction); re-home its committed draft onto the TP
+            # mesh so the verify program sees one device set.
+            draft = jax.device_put(
+                np.asarray(draft),
+                NamedSharding(self._tp_mesh, PartitionSpec()))
+        out, accepted, self._pools = self._spec_verify_fn(
+            self._params, self._pools, jnp.asarray(self._table),
+            jnp.asarray(last_tokens), draft,
+            jnp.asarray(positions), jnp.asarray(temps),
+            jnp.asarray(topks), jnp.asarray(spec_ok),
+            self._next_rng())
+        out = np.asarray(out)
+        accepted = np.asarray(accepted)
         result: Dict[int, List[int]] = {}
         spec_emitted = spec_steps = 0
         for s in active:
@@ -931,7 +927,15 @@ class InferenceEngine:
         temperature resumption is then bit-identical to the
         uninterrupted run; with concurrent traffic it stays
         distributionally correct (greedy is deterministic either
-        way)."""
+        way).  One ``hvd_tpu_engine_prefill`` span (``resumed``)."""
+        span_args = {"slot": int(slot), "resumed": True}
+        with trace_mod.span("hvd_tpu_engine_prefill", args=span_args):
+            return self._resume_slot(slot, prompt, emitted, sampling, rng,
+                                     span_args)
+
+    def _resume_slot(self, slot: int, prompt: Sequence[int],
+                     emitted: Sequence[int], sampling: SamplingParams,
+                     rng, span_args: dict) -> int:
         with self._slot_lock:
             if self._active[slot]:
                 raise RuntimeError(f"slot {slot} is already active")
@@ -943,8 +947,10 @@ class InferenceEngine:
         self.check_prompt_tokens(prompt)
         seq = prompt + emitted[:-1]
         n = len(seq)
+        span_args["prompt_len"] = n
         if self.kv_mode == "paged":
             hit = self._kv.begin_request(slot, seq)
+            span_args["prefix_hit"] = hit
             # Recompute the non-resident tail in bucket-sized chunks:
             # the paged prefill program takes a start offset, so a
             # resumed sequence longer than the largest bucket (a long
@@ -960,16 +966,14 @@ class InferenceEngine:
                 padded = np.zeros((1, L), np.int32)
                 padded[0, :ns] = np.asarray(seq[pos:pos + ns], np.int32)
                 fn = self._prefill_fns[L]
-                with self._activity(f"serve/slot{slot}", "SERVE_PREFILL",
-                                    {"bucket": L, "prompt_len": n,
-                                     "prefix_hit": hit, "resumed": True}):
-                    _, self._pools = fn(
-                        self._params, self._pools,
-                        jnp.asarray(self._table[slot]),
-                        jnp.asarray(padded), jnp.int32(pos),
-                        jnp.int32(ns), self._next_rng(),
-                        jnp.float32(sampling.temperature),
-                        jnp.int32(sampling.top_k))
+                span_args["bucket"] = L     # the last chunk's
+                _, self._pools = fn(
+                    self._params, self._pools,
+                    jnp.asarray(self._table[slot]),
+                    jnp.asarray(padded), jnp.int32(pos),
+                    jnp.int32(ns), self._next_rng(),
+                    jnp.float32(sampling.temperature),
+                    jnp.int32(sampling.top_k))
                 pos += ns
             self._kv.index_prompt(slot, seq)
         else:
@@ -978,14 +982,12 @@ class InferenceEngine:
             padded = np.zeros((1, L), np.int32)
             padded[0, :n] = np.asarray(seq, np.int32)
             fn = self._prefill_fns[L]
-            with self._activity(f"serve/slot{slot}", "SERVE_PREFILL",
-                                {"bucket": L, "prompt_len": n,
-                                 "resumed": True}):
-                _, self._caches = fn(
-                    self._params, self._caches, jnp.asarray(padded),
-                    jnp.int32(n), jnp.int32(slot), self._next_rng(),
-                    jnp.float32(sampling.temperature),
-                    jnp.int32(sampling.top_k))
+            span_args["bucket"] = L
+            _, self._caches = fn(
+                self._params, self._caches, jnp.asarray(padded),
+                jnp.int32(n), jnp.int32(slot), self._next_rng(),
+                jnp.float32(sampling.temperature),
+                jnp.int32(sampling.top_k))
         if rng is not None and not self.active_slots():
             self._rng = jnp.asarray(np.asarray(rng, np.uint32))
         if self._drafter is not None:
@@ -1107,7 +1109,17 @@ class InferenceEngine:
         distributionally correct (greedy/speculative requests are
         deterministic either way).  Digest verification happens in the
         migration layer BEFORE this call — corrupt payloads never reach
-        the pool."""
+        the pool.  One ``hvd_tpu_engine_prefill`` span (``imported``):
+        the binding stands where a prefill would."""
+        with trace_mod.span("hvd_tpu_engine_prefill",
+                            args={"slot": int(slot), "imported": True,
+                                  "prompt_len": len(prompt)}):
+            self._import_slot_kv(slot, prompt, k_blocks, v_blocks,
+                                 first_token, sampling, rng)
+
+    def _import_slot_kv(self, slot: int, prompt: Sequence[int],
+                        k_blocks, v_blocks, first_token: int,
+                        sampling: SamplingParams, rng) -> None:
         if self.kv_mode != "paged":
             raise RuntimeError("KV import requires the paged cache "
                                "(HVD_TPU_SERVE_KV=paged)")

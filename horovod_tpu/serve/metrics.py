@@ -2,9 +2,12 @@
 
 The two latencies that define an LLM serving SLO are time-to-first-token
 (TTFT: admission + prefill) and time-per-output-token (TPOT: decode
-cadence under continuous batching).  Both are recorded per request by
-the batcher and aggregated here into percentile snapshots with the same
-JSON-friendly shape ``benchmarks/serving_bench.py`` emits, so the live
+cadence under continuous batching); beside them stand the two a tail
+is made of, the wait in the admission queue (``queue_wait_ms``) and the
+gap between consecutive tokens (``itl_ms``: a prefill that stalls the
+others shows here and not in TPOT's mean).  All are recorded per
+request by the batcher and aggregated here into percentile snapshots
+with the same JSON-friendly shape ``benchmarks/serving_bench.py`` emits, so the live
 ``StatsRequest`` endpoint and the offline bench artifact read
 identically.
 
@@ -20,7 +23,7 @@ from __future__ import annotations
 
 import threading
 import time
-from typing import Dict, Optional
+from typing import Dict, Optional, Sequence
 
 from ..obs.metrics import Ring, percentile  # noqa: F401 (re-export)
 
@@ -61,6 +64,11 @@ class ServingStats:
         self._lock = threading.Lock()
         self._ttft_s = Ring(window)       # guarded-by: _lock
         self._tpot_s = Ring(window)       # guarded-by: _lock
+        # From the request's own stamps (ServeRequest.admitted_at /
+        # token_times): the wait in the admission queue, and every gap
+        # between consecutive tokens of one request.
+        self._queue_wait_s = Ring(window)  # guarded-by: _lock
+        self._itl_s = Ring(window)         # guarded-by: _lock
         self._occupancy = Ring(window)    # guarded-by: _lock
         self._queue_depth = Ring(window)  # guarded-by: _lock
         self.completed = 0                # guarded-by: _lock
@@ -109,11 +117,17 @@ class ServingStats:
 
     def record_request(self, ttft_s: float, n_tokens: int,
                        total_s: float, qos_class: Optional[str] = None,
-                       tenant: Optional[str] = None) -> None:
+                       tenant: Optional[str] = None,
+                       queue_wait_s: Optional[float] = None,
+                       itl_s: Sequence[float] = ()) -> None:
         with self._lock:
             self.completed += 1
             self.tokens_out += n_tokens
             self._ttft_s.append(ttft_s)
+            if queue_wait_s is not None:
+                self._queue_wait_s.append(queue_wait_s)
+            for gap in itl_s:
+                self._itl_s.append(gap)
             tpot = None
             if n_tokens > 1 and total_s > ttft_s:
                 # TPOT is the inter-token cadence after the first token.
@@ -211,7 +225,10 @@ class ServingStats:
                 "queue_depth_mean": (round(sum(queued) / len(queued), 2)
                                      if queued else None),
             }
-            for name, samples in (("ttft_ms", ttft), ("tpot_ms", tpot)):
+            for name, samples in (
+                    ("ttft_ms", ttft), ("tpot_ms", tpot),
+                    ("queue_wait_ms", self._queue_wait_s.values()),
+                    ("itl_ms", self._itl_s.values())):
                 for q in (50, 99):
                     v = percentile(samples, q)
                     out[f"{name}_p{q}"] = (round(v * 1e3, 3)

@@ -190,8 +190,8 @@ class TestEngineDecode:
         finally:
             hvd.stop_timeline()
         text = open(path).read()
-        assert "SERVE_PREFILL" in text
-        assert "SERVE_DECODE" in text
+        assert "hvd_tpu_engine_prefill" in text
+        assert "hvd_tpu_engine_decode" in text
 
 
 class TestPagedKV:
@@ -646,6 +646,212 @@ class TestBatcher:
         assert not long_req.done.is_set()
         _pump(b, [long_req, late])
         assert long_req.error is None and late.error is None
+
+
+class TestRequestLifecycle:
+    """ISSUE 24: the request's own stamps and the program's spans at
+    the scheduler and engine boundaries (docs/tracing.md) — recorded
+    for every request, not only for one that came in over the wire."""
+
+    @pytest.fixture(autouse=True)
+    def _clean_ring(self):
+        from horovod_tpu.obs import trace
+
+        trace.configure(enabled=True)
+        trace.clear()
+        yield
+        trace.configure(enabled=True)
+        trace.clear()
+
+    def _serve(self, model_and_params, n=3, new_tokens=4):
+        b = _batcher(model_and_params)   # 2 slots
+        reqs = [b.submit([i + 1, i + 2, i + 3],
+                         SamplingParams(max_new_tokens=new_tokens))
+                for i in range(n)]
+        _pump(b, reqs)
+        return b, reqs
+
+    def test_in_process_request_gets_its_phase_spans_under_one_trace(
+            self, model_and_params):
+        from horovod_tpu.obs import trace
+
+        assert trace.current() is None     # no ambient context here
+        _, reqs = self._serve(model_and_params)
+        spans = trace.snapshot()
+        for r in reqs:
+            mine = [s for s in spans if s["trace_id"] == r.trace_ctx[0]]
+            names = sorted(s["name"] for s in mine)
+            assert names == ["hvd_tpu_serve_decode", "hvd_tpu_serve_prefill",
+                             "hvd_tpu_serve_queued",
+                             "hvd_tpu_serve_request"]
+            (root,) = [s for s in mine
+                       if s["name"] == "hvd_tpu_serve_request"]
+            assert root["parent_id"] is None
+            assert root["args"]["request_id"] == r.request_id
+            assert root["args"]["tokens"] == len(r.tokens)
+            assert all(s["parent_id"] == root["span_id"]
+                       for s in mine if s is not root)
+            assert trace.unresolved_parents(mine) == []
+
+    def test_ambient_context_is_kept_and_roots_nothing(
+            self, model_and_params):
+        from horovod_tpu.obs import trace
+
+        b = _batcher(model_and_params)
+        with trace.span("hvd_tpu_rpc_server", kind="server") as ctx:
+            r = b.submit([1, 2, 3], SamplingParams(max_new_tokens=2))
+        _pump(b, [r])
+        assert r.trace_ctx == ctx and r.trace_root is False
+        mine = [s["name"] for s in trace.snapshot()
+                if s["trace_id"] == ctx[0]]
+        assert "hvd_tpu_serve_queued" in mine
+        assert "hvd_tpu_serve_request" not in mine
+
+    def test_token_times_and_admission_stamps(self, model_and_params):
+        _, reqs = self._serve(model_and_params, n=3, new_tokens=5)
+        for r in reqs:
+            assert len(r.token_times) == len(r.tokens) == 5
+            assert r.token_times == sorted(r.token_times)
+            assert r.token_times[0] == r.first_token_at
+            assert (r.submitted_at <= r.admitted_at <= r.first_token_at
+                    <= r.finished_at)
+        # Two slots, three requests: the third waited for a slot.
+        assert max(r.admitted_at - r.submitted_at for r in reqs) > 0
+
+    def test_queued_span_is_the_queue_wait_on_the_span_clock(
+            self, model_and_params):
+        from horovod_tpu.obs import trace
+
+        _, reqs = self._serve(model_and_params)
+        spans = trace.snapshot()
+        for r in reqs:
+            (q,) = [s for s in spans if s["name"] == "hvd_tpu_serve_queued"
+                    and s["trace_id"] == r.trace_ctx[0]]
+            assert q["start_us"] == pytest.approx(
+                trace.mono_us(r.submitted_at))
+            assert q["dur_us"] == pytest.approx(
+                (r.admitted_at - r.submitted_at) * 1e6, abs=1.0)
+
+    def test_step_span_holds_its_engine_children(self, model_and_params):
+        from horovod_tpu.obs import trace
+
+        self._serve(model_and_params)
+        spans = trace.snapshot()
+        steps = [s for s in spans if s["name"] == "hvd_tpu_serve_step"]
+        assert steps
+        seen = set()
+        for st in steps:
+            kids = [s for s in spans if s["parent_id"] == st["span_id"]]
+            seen |= {k["name"] for k in kids}
+            assert {k["name"] for k in kids} <= {
+                "hvd_tpu_engine_prefill", "hvd_tpu_engine_decode"}
+            for k in kids:
+                assert k["trace_id"] == st["trace_id"]
+                assert st["start_us"] <= k["start_us"]
+                assert (k["start_us"] + k["dur_us"]
+                        <= st["start_us"] + st["dur_us"] + 1.0)
+            assert st["dur_us"] - sum(k["dur_us"] for k in kids) >= -1.0
+            args = st["args"]
+            assert set(args) == {"active", "queued", "admitted", "emitted"}
+            assert args["admitted"] == sum(
+                k["name"] == "hvd_tpu_engine_prefill" for k in kids)
+            assert (args["active"] > 0) == any(
+                k["name"] == "hvd_tpu_engine_decode" for k in kids)
+        assert seen == {"hvd_tpu_engine_prefill", "hvd_tpu_engine_decode"}
+        assert sum(s["args"]["admitted"] for s in steps) == 3
+        prefill = [s for s in spans
+                   if s["name"] == "hvd_tpu_engine_prefill"]
+        assert all({"slot", "bucket", "prompt_len", "prefix_hit"}
+                   <= set(s["args"]) for s in prefill)
+
+    def test_idle_step_records_nothing(self, model_and_params):
+        from horovod_tpu.obs import trace
+
+        b = _batcher(model_and_params)
+        for _ in range(5):
+            assert b.step() == 0
+        assert trace.snapshot() == []
+
+    def test_tracing_off_records_nothing_and_allocates_no_context(
+            self, model_and_params):
+        from horovod_tpu.obs import trace
+
+        trace.configure(enabled=False)
+        _, reqs = self._serve(model_and_params)
+        assert trace.snapshot() == []
+        for r in reqs:
+            assert r.trace_ctx is None and r.trace_root is False
+            # The stamps do not hang on tracing.
+            assert len(r.token_times) == len(r.tokens)
+            assert r.admitted_at is not None
+
+    def test_failed_request_still_closes_its_trace(self, model_and_params):
+        from horovod_tpu.obs import trace
+
+        b = _batcher(model_and_params)
+        r = b.submit([1, 2, 3], SamplingParams(max_new_tokens=4))
+        assert b.cancel(r.request_id)
+        (root,) = [s for s in trace.snapshot()
+                   if s["name"] == "hvd_tpu_serve_request"]
+        assert root["args"]["error"] == "cancelled"
+        assert root["trace_id"] == r.trace_ctx[0]
+
+    def test_stats_report_queue_wait_and_inter_token_latency(
+            self, model_and_params):
+        b, reqs = self._serve(model_and_params, n=3, new_tokens=5)
+        snap = b.snapshot()
+        for key in ("queue_wait_ms_p50", "queue_wait_ms_p99",
+                    "itl_ms_p50", "itl_ms_p99"):
+            assert snap[key] is not None and snap[key] >= 0
+        gaps = [b2 - a for r in reqs
+                for a, b2 in zip(r.token_times, r.token_times[1:])]
+        assert snap["itl_ms_p99"] == pytest.approx(max(gaps) * 1e3,
+                                                   abs=1e-3)
+        waits = sorted(r.admitted_at - r.submitted_at for r in reqs)
+        assert snap["queue_wait_ms_p99"] == pytest.approx(
+            waits[-1] * 1e3, abs=1e-3)
+
+    def test_profiler_trace_holds_the_programs_spans_on_the_host_plane(
+            self, model_and_params, tmp_path):
+        """The bridge: two batcher steps under a live ``jax.profiler``
+        session leave the program's span names on ``/host:CPU`` of the
+        ``.xplane.pb`` — the plane whose clock the device's operations
+        share on a chip."""
+        import glob
+
+        b = _batcher(model_and_params)
+        warm = b.submit([9, 8, 7], SamplingParams(max_new_tokens=2))
+        _pump(b, [warm])                     # compile outside the trace
+        r = b.submit([1, 2, 3], SamplingParams(max_new_tokens=8))
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            b.step()
+            b.step()
+        finally:
+            jax.profiler.stop_trace()
+        _pump(b, [r])
+        (path,) = glob.glob(str(tmp_path / "plugins" / "profile" / "*"
+                                / "*.xplane.pb"))
+        data = jax.profiler.ProfileData.from_file(path)
+        (host,) = [p for p in data.planes if p.name == "/host:CPU"]
+        found = {}
+        for line in host.lines:
+            for ev in line.events:
+                if ev.name.startswith("hvd_tpu_"):
+                    found.setdefault(ev.name, []).append(
+                        (ev.start_ns, ev.start_ns + ev.duration_ns,
+                         dict(ev.stats)))
+        assert len(found["hvd_tpu_serve_step"]) == 2
+        assert len(found["hvd_tpu_engine_prefill"]) == 1
+        assert len(found["hvd_tpu_engine_decode"]) == 2
+        steps = found["hvd_tpu_serve_step"]
+        for name in ("hvd_tpu_engine_prefill", "hvd_tpu_engine_decode"):
+            for a, z, _ in found[name]:
+                assert any(sa <= a and z <= sz for sa, sz, _ in steps)
+        # Scalar args known at entry ride along as the event's stats.
+        assert found["hvd_tpu_engine_decode"][0][2]["active"] == 1
+        # After-the-fact spans stay in the ring.
+        assert "hvd_tpu_serve_queued" not in found
 
 
 class TestServeFaultSite:

@@ -120,6 +120,233 @@ class TestSpanBasics:
         assert seen["ctx"] is None
 
 
+class TestOneClockAndBridge:
+    """ISSUE 24: one span path on one clock, bridged to the profiler
+    (docs/tracing.md "One span path, one clock")."""
+
+    def test_now_us_is_the_monotonic_clock_on_one_wall_anchor(self):
+        t = time.monotonic()
+        assert trace.now_us() == pytest.approx(trace.mono_us(t), abs=5e3)
+        # The anchor makes it read as unix time, to within clock steps.
+        assert trace.now_us() == pytest.approx(time.time() * 1e6, abs=5e6)
+        # Differences are exactly the monotonic clock's.
+        assert trace.mono_us(t + 0.25) - trace.mono_us(t) == \
+            pytest.approx(250_000.0)
+
+    def test_span_and_reconstructed_span_share_the_clock(self):
+        t0 = time.monotonic()
+        with trace.span("hvd_tpu_step", root=True):
+            time.sleep(0.002)
+        t1 = time.monotonic()
+        trace.record_span("hvd_tpu_serve_queued", parent=None,
+                          start_us=trace.mono_us(t0),
+                          dur_us=(t1 - t0) * 1e6)
+        live, rebuilt = trace.snapshot()
+        assert rebuilt["start_us"] <= live["start_us"]
+        assert (live["start_us"] + live["dur_us"]
+                <= rebuilt["start_us"] + rebuilt["dur_us"])
+
+    def test_span_args_are_read_when_the_span_closes(self):
+        counts = {"queued": 2}
+        with trace.span("hvd_tpu_serve_step", args=counts):
+            counts["emitted"] = 5
+        (rec,) = trace.snapshot()
+        assert rec["args"] == {"queued": 2, "emitted": 5}
+        assert rec["args"] is not counts
+
+    def test_configure_pins_rank_and_timeline_mirror(self, monkeypatch):
+        recorded = []
+
+        class FakeTimeline:
+            enabled = True
+
+            def _now_us(self):
+                return 5_000_000.0
+
+            def record(self, cat, name, start, dur, args=None):
+                recorded.append((name, args))
+
+            def flow(self, *a, **k):
+                pass
+
+        monkeypatch.delenv("HVD_TPU_PROCESS_ID", raising=False)
+        before = trace.process_rank()
+        trace.configure(rank=3, timeline=FakeTimeline())
+        try:
+            assert trace.process_rank() == 3
+            with trace.span("hvd_tpu_engine_decode", args={"active": 4}):
+                pass
+            (rec,) = trace.snapshot()
+            assert rec["rank"] == 3
+            ((name, args),) = recorded
+            assert name == "hvd_tpu_engine_decode"
+            assert args["active"] == 4 and args["span_id"] == rec["span_id"]
+        finally:
+            trace.configure(rank=before, timeline=None)
+        with trace.span("hvd_tpu_engine_decode"):
+            pass
+        assert len(recorded) == 1           # mirror detached
+        monkeypatch.setenv("HVD_TPU_PROCESS_ID", "5")
+        trace.configure(rank=None)
+        try:
+            assert trace.process_rank() == 5    # the launch env answers
+        finally:
+            trace.configure(rank=before)
+
+    def test_init_pins_this_process_rank_and_timeline(self, tmp_path):
+        import jax
+
+        assert hvd.is_initialized()
+        assert trace.process_rank() == jax.process_index()
+        path = str(tmp_path / "tl.json")
+        hvd.start_timeline(path)
+        try:
+            with trace.span("hvd_tpu_engine_decode", args={"active": 1}):
+                pass
+        finally:
+            hvd.stop_timeline()
+        assert "hvd_tpu_engine_decode" in open(path).read()
+        trace.clear()
+        with trace.span("hvd_tpu_engine_decode"):
+            pass                        # a stopped timeline takes nothing
+        assert "hvd_tpu_engine_decode" in open(path).read()
+
+    def test_span_reaches_a_live_profiler_session(self, tmp_path):
+        import glob
+
+        import jax
+
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            with trace.span("hvd_tpu_step", root=True,
+                            args={"kind": "train", "step": 7,
+                                  "skipped": [1, 2]}):
+                with trace.span("hvd_tpu_ckpt_save"):
+                    time.sleep(0.001)
+            trace.record_span("hvd_tpu_serve_queued", parent=None,
+                              start_us=trace.now_us(), dur_us=1.0)
+        finally:
+            jax.profiler.stop_trace()
+        (path,) = glob.glob(str(tmp_path / "plugins" / "profile" / "*"
+                                / "*.xplane.pb"))
+        data = jax.profiler.ProfileData.from_file(path)
+        (host,) = [p for p in data.planes if p.name == "/host:CPU"]
+        events = {ev.name: ev for line in host.lines for ev in line.events
+                  if ev.name.startswith("hvd_tpu_")}
+        assert set(events) == {"hvd_tpu_step", "hvd_tpu_ckpt_save"}
+        outer, inner = events["hvd_tpu_step"], events["hvd_tpu_ckpt_save"]
+        assert outer.start_ns <= inner.start_ns
+        assert (inner.start_ns + inner.duration_ns
+                <= outer.start_ns + outer.duration_ns)
+        stats = dict(outer.stats)
+        assert stats["kind"] == "train" and stats["step"] == 7
+        assert "skipped" not in stats      # scalars only
+        # Ring and profiler agree on how long the span took.
+        (ring_outer,) = _by_name(trace.snapshot(), "hvd_tpu_step")
+        assert outer.duration_ns / 1e3 == pytest.approx(
+            ring_outer["dur_us"], rel=0.2, abs=200.0)
+
+    def test_ids_and_pid_cost_no_system_call_and_survive_a_fork(
+            self, monkeypatch):
+        """Span ids come from a generator seeded once and the pid is
+        read once (a span on the hot path makes no system call); the
+        at-fork hook gives a child its own ids and its own pid."""
+        monkeypatch.setattr(os, "urandom", lambda n: pytest.fail(
+            "a span id asked the OS for entropy"))
+        monkeypatch.setattr(os, "getpid", lambda: pytest.fail(
+            "a span asked the OS for the pid"))
+        with trace.span("hvd_tpu_step", root=True):
+            with trace.span("hvd_tpu_ckpt_save"):
+                pass
+        monkeypatch.undo()
+        root, child = sorted(trace.snapshot(),
+                             key=lambda r: r["parent_id"] is not None)
+        assert len(root["trace_id"]) == 32 and len(root["span_id"]) == 16
+        int(root["trace_id"], 16), int(child["span_id"], 16)
+        assert root["pid"] == child["pid"] == os.getpid()
+        ids = {trace._new_id(8) for _ in range(1000)}
+        assert len(ids) == 1000
+        # What a forked child runs: same state in, different ids out.
+        state = trace._ids.getstate()
+        nxt = trace._new_id(8)
+        trace._ids.setstate(state)
+        monkeypatch.setattr(trace, "_pid", -1)
+        trace._after_fork_in_child()
+        assert trace._new_id(8) != nxt
+        assert trace._pid == os.getpid()
+
+    def test_disabled_span_enters_no_annotation(self, monkeypatch):
+        import contextlib
+
+        entered = []
+
+        def spy(name, args):
+            entered.append(name)
+            return contextlib.nullcontext()
+
+        monkeypatch.setattr(trace, "_annotation", spy)
+        trace.configure(enabled=False)
+        with trace.span("hvd_tpu_step", root=True):
+            pass
+        assert entered == []
+
+
+class TestTrainStepScopes:
+    """ISSUE 24 part C: the compiled train step names its phases in
+    every operation's metadata, and nothing else changes."""
+
+    def _lowered(self, tx):
+        import jax.numpy as jnp
+
+        loss_fn = lambda p, b: (((b @ p["w"]) + p["b"]) ** 2).mean()  # noqa: E731
+        step = hvd.make_train_step(loss_fn, tx, donate=False)
+        params = {"w": jnp.ones((4, 3)), "b": jnp.zeros((3,))}
+        opt_state = tx.init(params)
+        batch = jnp.ones((hvd.size() * 2, 4))
+        inner = getattr(step, "__wrapped__", step)
+        text = inner.lower(params, opt_state, batch).as_text(
+            debug_info=True)
+        return text, step(params, opt_state, batch)
+
+    @pytest.mark.parametrize("wrapped", [False, True])
+    def test_scopes_are_in_the_lowered_step(self, wrapped):
+        import optax
+
+        tx = optax.adam(1e-2)
+        if wrapped:
+            tx = hvd.DistributedOptimizer(tx)
+        text, (params, _, loss) = self._lowered(tx)
+        for scope in ("hvd_tpu_fwd_bwd", "hvd_tpu_optimizer",
+                      "hvd_tpu_wire_pack", "hvd_tpu_wire_unpack",
+                      "hvd_tpu_wire_bucket_0"):
+            assert scope in text, scope
+        if wrapped:
+            # The optimizer's own wire nests inside the optimizer scope.
+            assert "hvd_tpu_optimizer/hvd_tpu_wire_bucket_0" in text
+        assert np.isfinite(float(loss))
+
+    def test_scopes_change_no_operation(self, monkeypatch):
+        """Metadata only: with the scopes taken away the step lowers to
+        the same operations."""
+        import contextlib
+        import re
+
+        import optax
+
+        def ops_of(text):
+            body = re.sub(r"loc\(.*?\)\s*$", "", text, flags=re.M)
+            body = "\n".join(l for l in body.splitlines()
+                             if not l.lstrip().startswith("#loc"))
+            return re.sub(r"\s+", " ", body)
+
+        with_scopes, _ = self._lowered(optax.sgd(0.1))
+        monkeypatch.setattr(trace, "scope",
+                            lambda name: contextlib.nullcontext())
+        without, _ = self._lowered(optax.sgd(0.1))
+        assert "hvd_tpu_fwd_bwd" not in without
+        assert ops_of(with_scopes) == ops_of(without)
+
+
 class TestDeferredRoot:
     """new_context/use_context + record_span(ctx=): a root whose
     interval is only known at completion (serving_bench --trace) still
@@ -153,8 +380,6 @@ class TestDeferredRoot:
         wall clock — a phase recorded long after the interval (the
         batcher's queued window, recorded at prefill start) must not be
         shown ending at 'now'."""
-        from horovod_tpu import basics
-
         recorded = []
 
         class FakeTimeline:
@@ -169,11 +394,14 @@ class TestDeferredRoot:
             def flow(self, *a, **k):
                 pass
 
-        monkeypatch.setattr(basics, "is_initialized", lambda: True)
-        monkeypatch.setattr(basics._state, "timeline", FakeTimeline())
-        end_wall = trace.now_us() - 250_000.0    # ended 250 ms ago
-        trace.record_span("hvd_tpu_serve_queued", parent=None,
-                          start_us=end_wall - 50_000.0, dur_us=50_000.0)
+        trace.configure(timeline=FakeTimeline())
+        try:
+            end_wall = trace.now_us() - 250_000.0    # ended 250 ms ago
+            trace.record_span("hvd_tpu_serve_queued", parent=None,
+                              start_us=end_wall - 50_000.0,
+                              dur_us=50_000.0)
+        finally:
+            trace.configure(timeline=None)
         ((name, start, dur),) = recorded
         assert name == "hvd_tpu_serve_queued"
         # Back-dated from the TL's "now" by lag (250 ms) + dur (50 ms).
