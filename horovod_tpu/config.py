@@ -739,7 +739,7 @@ class Config:
     # Distributed tracing + crash flight recorder (horovod_tpu/obs/
     # trace.py + flight.py; docs/tracing.md).
     trace: bool = True                        # HVD_TPU_TRACE (span recording gate)
-    trace_ring: int = 2048                    # HVD_TPU_TRACE_RING (per-process span ring size)
+    trace_ring: int = 16384                   # HVD_TPU_TRACE_RING (per-process span ring size)
     flight: bool = True                       # HVD_TPU_FLIGHT (crash-dump gate)
     flight_dir: str = ""                      # HVD_TPU_FLIGHT_DIR ("" = <tempdir>/hvd_tpu_flight)
     flight_ring: int = 512                    # HVD_TPU_FLIGHT_RING (event ring size)
@@ -879,7 +879,7 @@ class Config:
             metrics_window=_env_pos_int("METRICS_WINDOW", 1024),
             straggler_factor=_env_straggler_factor(),
             trace=_env_bool("TRACE", True),
-            trace_ring=_env_pos_int("TRACE_RING", 2048),
+            trace_ring=_env_pos_int("TRACE_RING", 16384),
             flight=_env_bool("FLIGHT", True),
             flight_dir=_env("FLIGHT_DIR", "") or "",
             flight_ring=_env_pos_int("FLIGHT_RING", 512),

@@ -65,8 +65,8 @@ def init_kv_cache(config: GPTConfig, batch_size: int, max_len: int):
     the set of compiled shapes small.
 
     The paged alternative (``horovod_tpu/serve/kv``) replaces the dense
-    per-slot rows with one ``[num_blocks, block, H, D]`` pool per layer
-    plus a per-slot block table; :class:`Attention` accepts either
+    per-slot rows with one ``[num_blocks, block, H * D]`` pool per
+    layer plus a per-slot block table; :class:`Attention` accepts either
     layout (``{"k", "v"}`` vs ``{"k_pool", "v_pool", "table"}``)."""
     head_dim = config.d_model // config.n_head
     shape = (batch_size, max_len, config.n_head, head_dim)
@@ -93,6 +93,13 @@ def _tp_shard(cfg: GPTConfig, x, *spec):
 
 
 class Attention(nn.Module):
+    """Self-attention.  ``attn(x)`` is the training forward.  With a KV
+    cache, ``attn(x, cache=..., positions=...)`` writes the chunk's K/V
+    into the cache, attends over the cache it has written and returns
+    ``(out, {"k", "v"})``, the updated cache: the dense rows, or, for a
+    paged cache (``{"k_pool", "v_pool", "table"}``), the pools, written
+    through the block table."""
+
     config: GPTConfig
     mesh: Optional[Mesh] = None
 
@@ -120,35 +127,38 @@ class Attention(nn.Module):
         if cache is not None:
             # KV-cache path (serving prefill chunks and single-token
             # decode steps): write this chunk's K/V at its absolute
-            # ``positions`` (``[B, T]``, per-row offsets — continuous
-            # batching puts every slot at a different depth), then run
-            # exact masked attention over the padded cache.  Keys at
-            # indices beyond a row's position are stale/padding and the
-            # ``<= position`` mask excludes them — padding correctness
-            # needs no separate key mask.
+            # ``positions`` (``[B, T]``; continuous batching puts every
+            # slot at a different depth), attend over the cache just
+            # written, and return it.  Keys beyond a row's position are
+            # stale or padding and the ``<= position`` mask excludes
+            # them: padding and intra-chunk causality need no other.
             #
-            # Two cache layouts share the math:
-            # * dense ``{"k", "v"}`` — per-slot ``[B, S, H, D]`` rows;
-            #   the updated rows ARE the new cache and are returned.
-            # * paged ``{"k_pool", "v_pool", "table"}`` — one
-            #   ``[num_blocks, block, H, D]`` pool per layer plus a
-            #   per-row block table (``serve/kv/``): the view is
-            #   gathered block-indexed (view row ``i`` is the token at
-            #   absolute position ``i`` of that row's chain), the chunk
-            #   is written into the view for intra-chunk causality, and
-            #   the raw chunk K/V is returned for the engine to scatter
-            #   into the pool through the same block table (invalid
-            #   positions route to the reserved trash block there).
-            paged = "k_pool" in cache
-            if paged:
+            # Dense ``{"k", "v"}``: per-slot ``[B, S, H, D]`` rows.
+            # Paged ``{"k_pool", "v_pool", "table"}``: one ``[num_blocks,
+            # block, H * D]`` pool per layer, written and gathered
+            # through the per-row block table (view row ``i`` is the
+            # token at position ``i`` of the row's chain; invalid
+            # positions reach the trash block by the table's last
+            # column).  Write before read, and heads in one row: each
+            # keeps a donated pool updated in place (docs/serving.md).
+            if "k_pool" in cache:
                 table = cache["table"]           # [B, n_cols] block ids
-                k_base = cache["k_pool"][table].reshape(B, -1, H, D)
-                v_base = cache["v_pool"][table].reshape(B, -1, H, D)
+                k_pool, v_pool = cache["k_pool"], cache["v_pool"]
+                block = k_pool.shape[1]
+                blk = jnp.take_along_axis(table, positions // block, axis=1)
+                off = positions % block
+                k_new = k_pool.at[blk, off].set(
+                    k.reshape(B, T, C).astype(k_pool.dtype))
+                v_new = v_pool.at[blk, off].set(
+                    v.reshape(B, T, C).astype(v_pool.dtype))
+                k_all = k_new[table].reshape(B, -1, H, D)
+                v_all = v_new[table].reshape(B, -1, H, D)
             else:
-                k_base, v_base = cache["k"], cache["v"]
-            row = jnp.arange(B)[:, None]
-            k_all = k_base.at[row, positions].set(k.astype(k_base.dtype))
-            v_all = v_base.at[row, positions].set(v.astype(v_base.dtype))
+                row = jnp.arange(B)[:, None]
+                k_new = k_all = cache["k"].at[row, positions].set(
+                    k.astype(cache["k"].dtype))
+                v_new = v_all = cache["v"].at[row, positions].set(
+                    v.astype(cache["v"].dtype))
             S = k_all.shape[1]
             scores = jnp.einsum("bqhd,bkhd->bhqk", q, k_all)
             scores = scores.astype(jnp.float32) * (D ** -0.5)
@@ -160,9 +170,7 @@ class Attention(nn.Module):
             # under TP, so the head outputs all-gather here and every
             # shard computes the full projection — bitwise identical.
             merged = _tp_shard(cfg, out.reshape(B, T, C))
-            if paged:
-                return proj(merged), {"k": k, "v": v}
-            return proj(merged), {"k": k_all, "v": v_all}
+            return proj(merged), {"k": k_new, "v": v_new}
         if cfg.attention == "ring":
             if self.mesh is None:
                 raise ValueError("attention='ring' requires a mesh")
@@ -253,10 +261,12 @@ class GPT(nn.Module):
     """Decoder-only LM.  ``apply(params, tokens)`` → logits ``[B, T, V]``.
 
     Serving mode: ``apply(params, tokens, kv_caches=caches,
-    positions=pos)`` (caches from :func:`init_kv_cache`, ``pos`` the
-    ``[B, T]`` absolute positions of the chunk) returns ``(logits,
-    new_caches)`` — the jitted prefill/decode primitive behind
-    ``horovod_tpu.serve.engine``."""
+    positions=pos)`` (caches from :func:`init_kv_cache` or the engine's
+    paged pools, ``pos`` the ``[B, T]`` absolute positions of the
+    chunk) returns ``(logits, new_caches)`` — the jitted prefill/decode
+    primitive behind ``horovod_tpu.serve.engine``.  Either layout,
+    ``new_caches`` is the cache the model updated: one ``{"k", "v"}``
+    per layer, dense rows or whole pools."""
 
     config: GPTConfig
     mesh: Optional[Mesh] = None

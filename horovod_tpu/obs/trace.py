@@ -75,7 +75,7 @@ _TRUE = {"1", "true", "yes", "on"}
 
 _lock = threading.Lock()
 _enabled: Optional[bool] = None          # guarded-by: _lock (lazy env gate)
-_ring: "deque" = deque(maxlen=2048)      # guarded-by: _lock
+_ring: "deque" = deque(maxlen=16384)     # guarded-by: _lock
 _tls = threading.local()                 # .ctx = (trace_id, span_id) or None
 _UNSET = object()
 # Pinned by configure() (hvd.init / start_timeline / shutdown) so that a

@@ -16,17 +16,22 @@ The serving hot path is two compiled programs:
 
 Two KV layouts live under this one API (``HVD_TPU_SERVE_KV``):
 
-* **paged** (default) — one ``[num_blocks, block, H, D]`` pool per
+* **paged** (default) — one ``[num_blocks, block, H * D]`` pool per
   layer plus a host-side block table (``serve/kv/``): requests map
   onto refcounted fixed-size token blocks, identical prompt prefixes
   share physical blocks (copy-on-write on first divergent write), and
   unreferenced prefix blocks are LRU-evicted under pressure.  The
   jitted programs index the pool *through* a per-slot block-table
   array, so there is still ONE compiled decode program — the table is
-  data, not shape.  Block 0 is a reserved *trash block*: unmapped
-  table entries point at it and invalid positions (padding, rejected
-  speculative tokens, past-the-cache) clamp into it, which replaces
-  every masking lattice around scatter/gather.
+  data, not shape.  The model writes the step's K/V through the table,
+  then attends over the updated pool, and returns the pools; the
+  programs here hand back what it returns, as on the dense tier, and
+  a donated pool is updated in place (``docs/serving.md`` has what a
+  read before the write, or heads kept apart in the pool, cost).
+  Block 0 is a reserved *trash block*: unmapped table entries point at
+  it and invalid positions (padding, past-the-cache, idle rows) clamp
+  into it, which replaces every masking lattice around
+  scatter/gather.
 * **dense** — the original per-slot ``[slots, S, H, D]`` rows; kept as
   the token-identity oracle the paged path is tested against.
 
@@ -173,7 +178,8 @@ class InferenceEngine:
         # model's gather-before-contract constraints keep the decode
         # bitwise identical to tp=1, so TP is a capacity/latency knob,
         # never a correctness one.  The paged KV pool shards on its
-        # head dim (each device holds H/tp heads of every block) while
+        # rows (heads lie side by side in a row, so each device holds
+        # H/tp heads of every block) while
         # the block table and BlockPool bookkeeping stay rank-invariant
         # host state.
         self.tp = int(tp if tp is not None else cfg.serve_tp)
@@ -253,17 +259,17 @@ class InferenceEngine:
                     f"(1 trash + slots x blocks_per_slot) — active "
                     f"requests could deadlock on allocation")
             self.kv_blocks = budget
-            shape = (budget, self.kv_block, model.config.n_head, head_dim)
+            shape = (budget, self.kv_block, model.config.n_head * head_dim)
 
             def _pool_zeros():
                 z = jnp.zeros(shape, model.config.dtype)
                 if self._tp_mesh is not None:
                     # Head-sharded pool: each shard device holds only
-                    # its H/tp heads of every block; the block table
-                    # stays whole-pool host state.
+                    # its H/tp heads' part of every row; the block
+                    # table stays whole-pool host state.
                     return jax.device_put(z, NamedSharding(
                         self._tp_mesh,
-                        PartitionSpec(None, None, "tensor", None)))
+                        PartitionSpec(None, None, "tensor")))
                 return _beside(params, z)
 
             self._pools = [{"k": _pool_zeros(), "v": _pool_zeros()}
@@ -405,22 +411,6 @@ class InferenceEngine:
                  "table": tables}
                 for i in range(self._model.config.n_layer)]
 
-    def _scatter_chunk(self, pools, chunk, blk, off):
-        """Write chunk K/V rows into the pools at ``(blk, off)`` (flat
-        index arrays; invalid traffic already routed to the trash
-        block by the callers' position clamping)."""
-        new = []
-        for i in range(self._model.config.n_layer):
-            k_c = chunk[i]["k"].reshape((-1,) + chunk[i]["k"].shape[-2:])
-            v_c = chunk[i]["v"].reshape((-1,) + chunk[i]["v"].shape[-2:])
-            new.append({
-                "k": pools[i]["k"].at[blk, off].set(
-                    k_c.astype(pools[i]["k"].dtype)),
-                "v": pools[i]["v"].at[blk, off].set(
-                    v_c.astype(pools[i]["v"].dtype)),
-            })
-        return new
-
     def _copy_impl(self, pools, src, dst):
         self.trace_counts["kv_copy"] += 1  # trace-time only
         return [{"k": p["k"].at[dst].set(p["k"][src]),
@@ -433,8 +423,8 @@ class InferenceEngine:
                                     jnp.int32(dst))
 
     def _import_impl(self, pools, blk, k, v):
-        """Write one wire-received block's K/V (``[n_layer, block, H,
-        D]``) into every layer's pool at block ``blk`` — the binding
+        """Write one wire-received block's K/V (``[n_layer, block,
+        H * D]``) into every layer's pool at block ``blk`` — the binding
         half of live KV migration (ONE compiled program: the block id
         is data, not shape)."""
         self.trace_counts["kv_import"] += 1  # trace-time only
@@ -446,7 +436,7 @@ class InferenceEngine:
 
     def _make_paged_prefill(self, L: int):
         model = self._model
-        B, S, SV = self.kv_block, self.max_seq_len, self._view_len
+        S, SV = self.max_seq_len, self._view_len
 
         def prefill(params, pools, table_row, tokens, start, length,
                     rng, temp, topk):
@@ -457,17 +447,17 @@ class InferenceEngine:
             self.trace_counts[f"prefill_{L}"] += 1  # trace-time only
             idx = jnp.arange(L, dtype=jnp.int32)
             valid = (idx < length) & (start + idx < S)
+            # Invalid rows (padding, past the cache) take the view's
+            # last position: the table's trash column.
             positions = jnp.where(valid, start + idx, SV - 1)
             caches = self._paged_caches(pools, table_row[None])
-            logits, chunk = model.apply(
+            logits, new = model.apply(
                 {"params": params}, tokens, kv_caches=caches,
                 positions=positions[None])
             last = jax.lax.dynamic_index_in_dim(logits[0], length - 1,
                                                 axis=0, keepdims=False)
             token = _sample(last[None].astype(jnp.float32), rng,
                             temp[None], topk[None])[0]
-            blk = table_row[positions // B]   # invalid -> trash column
-            new = self._scatter_chunk(pools, chunk, blk, positions % B)
             return token, new
 
         return jax.jit(prefill, donate_argnums=self._donate)
@@ -476,14 +466,10 @@ class InferenceEngine:
                            positions, temps, topks, rng):
         self.trace_counts["decode"] += 1  # trace-time only
         caches = self._paged_caches(pools, tables)
-        logits, chunk = self._model.apply(
+        logits, new = self._model.apply(
             {"params": params}, tokens[:, None], kv_caches=caches,
             positions=positions[:, None])
         nxt = _sample(logits[:, -1].astype(jnp.float32), rng, temps, topks)
-        B = self.kv_block
-        blk = jnp.take_along_axis(tables, (positions // B)[:, None],
-                                  axis=1)[:, 0]
-        new = self._scatter_chunk(pools, chunk, blk, positions % B)
         return nxt, new
 
     # --- compiled programs: speculative tier --------------------------------
@@ -543,21 +529,24 @@ class InferenceEngine:
         Chunk ``[t0, d1..dK]`` runs at positions ``p..p+K``; the
         accepted prefix is the longest run of drafts matching the
         target's own greedy chain, so the emitted tokens are exactly
-        what plain greedy decode would produce (docs/serving.md).  Only
-        chunk rows ``<= accepted`` persist their K/V — rejected rows
-        scatter into the trash block and the correct token rewrites
-        that position next step.  Rows with ``spec_ok`` false (no
-        opt-in, or temperature sampling) accept nothing and emit one
-        plain-sampled token."""
+        what plain greedy decode would produce (docs/serving.md).  The
+        model writes every chunk row's K/V at its position before it
+        attends (positions past the cache go to the trash block), so
+        ``accepted`` decides only how far the slot advances.  A
+        rejected row is left at a position beyond the slot's new
+        length: the ``<= position`` mask hides it, and the next step
+        writes that position before any query can see it.  Rows with
+        ``spec_ok`` false (no opt-in, or temperature sampling) accept
+        nothing and emit one plain-sampled token."""
         self.trace_counts["spec_verify"] += 1  # trace-time only
         K = self.spec_k
-        B, S, SV = self.kv_block, self.max_seq_len, self._view_len
+        S, SV = self.max_seq_len, self._view_len
         chunk_toks = jnp.concatenate([tokens[:, None], draft], axis=1)
         idx = jnp.arange(K + 1, dtype=jnp.int32)[None]
         pos = positions[:, None] + idx
         pos_safe = jnp.where(pos < S, pos, SV - 1)
         caches = self._paged_caches(pools, tables)
-        logits, chunk = self._model.apply(
+        logits, new = self._model.apply(
             {"params": params}, chunk_toks, kv_caches=caches,
             positions=pos_safe)
         logits = logits.astype(jnp.float32)
@@ -571,11 +560,6 @@ class InferenceEngine:
                                jnp.maximum(S - 1 - positions, 0))
         first = _sample(logits[:, 0], rng, temps, topks)
         out = greedy.at[:, 0].set(first)   # argmax already, unless temp>0
-        keep = (idx <= accepted[:, None]) & (pos < S)
-        pos_w = jnp.where(keep, pos, SV - 1)
-        blk = jnp.take_along_axis(tables, pos_w // B, axis=1)
-        new = self._scatter_chunk(pools, chunk, blk.reshape(-1),
-                                  (pos_w % B).reshape(-1))
         return out, accepted, new
 
     # --- host-side slot API -------------------------------------------------
@@ -1091,8 +1075,14 @@ class InferenceEngine:
         if not chain:
             raise RuntimeError(f"slot {slot} has no KV chain to export")
         idx = jnp.asarray(chain, jnp.int32)
-        k = np.stack([np.asarray(p["k"][idx]) for p in self._pools])
-        v = np.stack([np.asarray(p["v"][idx]) for p in self._pools])
+        # The wire keeps heads apart (migration ships head shards); the
+        # pool stores a token's heads as one row.
+        wire = (len(self._pools), len(chain), self.kv_block,
+                self._model.config.n_head, -1)
+        k = np.stack([np.asarray(p["k"][idx])
+                      for p in self._pools]).reshape(wire)
+        v = np.stack([np.asarray(p["v"][idx])
+                      for p in self._pools]).reshape(wire)
         return len(chain), k, v
 
     def import_slot_kv(self, slot: int, prompt: Sequence[int],
@@ -1137,10 +1127,12 @@ class InferenceEngine:
                 f"{n}-token prompt ({expected} expected at block size "
                 f"{self.kv_block})")
         chain = self._kv.bind_imported(slot, nb)
+        rows = (len(self._pools), self.kv_block, -1)   # heads merged
         for j, blk in enumerate(chain):
             self._pools = self._import_fn(
                 self._pools, jnp.int32(blk),
-                jnp.asarray(k_blocks[:, j]), jnp.asarray(v_blocks[:, j]))
+                jnp.asarray(k_blocks[:, j]).reshape(rows),
+                jnp.asarray(v_blocks[:, j]).reshape(rows))
         # The imported prefix is resident here now: index it so later
         # admissions (and the global prefix directory) hit it — the
         # "prefix-directory hit landing on a decode replica" path.

@@ -2,7 +2,7 @@
 
 The vLLM-style order-of-magnitude lever on serving occupancy (ROADMAP
 item 3): instead of one dense ``[slots, S, H, D]`` row per request,
-every layer keeps ONE preallocated ``[num_blocks, block, H, D]`` pool
+every layer keeps ONE preallocated ``[num_blocks, block, H * D]`` pool
 and each request maps its sequence onto a chain of fixed-size token
 blocks through a host-side block table.  Identical prompt prefixes
 resolve to the same physical blocks (radix-trie prefix index),

@@ -1,14 +1,14 @@
 """Refcounted block pool: the host-side allocator behind paged KV.
 
 One :class:`BlockPool` manages the block ids of one engine's
-preallocated per-layer device pools (``[num_blocks, block, H, D]``;
+preallocated per-layer device pools (``[num_blocks, block, H * D]``;
 the device arrays themselves live in the engine — this module never
 imports jax).  Responsibilities:
 
 * **Allocation** — block ids come from a free list; block 0 is
   reserved as the *trash block*: unmapped block-table entries point at
   it, and the jitted programs route every invalid write (padding,
-  rejected speculative tokens, positions past the cache) there, so the
+  idle rows, positions past the cache) there, so the
   compiled code needs no masking lattice around scatter/gather.
 * **Refcounting + prefix sharing** — a request's chain in the
   :class:`~horovod_tpu.serve.kv.prefix.PrefixIndex` increfs every

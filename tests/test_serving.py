@@ -325,6 +325,104 @@ class TestPagedKV:
         assert r2.tokens == _greedy_reference(model, params, pre + [2], 3)
 
 
+class TestPagedWriteThenRead:
+    """ISSUE 25: the paged programs write the chunk's K/V through the
+    block table first and attend over the written pool, so a donated
+    pool is updated in place and nothing but the slot's own blocks (and
+    the trash block) is ever written."""
+
+    PROGRAMS = ("decode", "prefill_8", "prefill_16", "spec_verify",
+                "kv_copy", "kv_import")
+
+    @staticmethod
+    def _lowerable(eng, name):
+        """``(function, arguments, index of the pools)`` of one paged
+        program, as the engine calls it."""
+        n, cols = eng.max_slots, eng.blocks_per_slot + 1
+
+        def i32(*shape):
+            return jnp.zeros(shape, jnp.int32)
+
+        def f32(*shape):
+            return jnp.zeros(shape, jnp.float32)
+
+        rng = jax.random.PRNGKey(0)
+        params, pools = eng.params, eng._pools
+        if name == "decode":
+            return eng._decode_paged_impl, (
+                params, pools, i32(n, cols), i32(n), i32(n), f32(n),
+                i32(n), rng), 1
+        if name.startswith("prefill_"):
+            L = int(name.split("_")[1])
+            return eng._make_paged_prefill(L).__wrapped__, (
+                params, pools, i32(cols), i32(1, L), i32(), i32(), rng,
+                f32(), i32()), 1
+        if name == "spec_verify":
+            return eng._spec_verify_impl, (
+                params, pools, i32(n, cols), i32(n), i32(n, eng.spec_k),
+                i32(n), f32(n), i32(n), jnp.zeros(n, bool), rng), 1
+        if name == "kv_copy":
+            return eng._copy_impl, (pools, i32(), i32()), 0
+        block = jnp.zeros((len(pools),) + pools[0]["k"].shape[1:],
+                          pools[0]["k"].dtype)
+        return eng._import_impl, (pools, i32(), block, block), 0
+
+    @pytest.mark.parametrize("program", PROGRAMS)
+    def test_donated_pools_are_updated_in_place(self, model_and_params,
+                                                program):
+        """With the pools donated (the engine turns donation off on the
+        CPU, so it is asked for here), the optimised HLO of every paged
+        program aliases each pool to an output and holds no copy of a
+        whole pool.  A gather of the pool as it came in, beside a later
+        scatter into it, makes XLA keep such a copy per pool."""
+        import re
+
+        eng = _engine(model_and_params, kv_cache="paged", kv_block=4)
+        fn, args, pools_at = self._lowerable(eng, program)
+        hlo = jax.jit(fn, donate_argnums=(pools_at,)).lower(
+            *args).compile().as_text()
+        pool = eng._pools[0]["k"]
+        n_pools = 2 * len(eng._pools)
+        shape = ",".join(str(d) for d in pool.shape)
+        copies = re.findall(
+            r"^.*= \w+\[%s\]\S* copy\(.*$" % shape, hlo, re.M)
+        assert not copies, copies[:2]
+        header = hlo.split("\n", 1)[0]
+        aliased = re.findall(
+            r"\{\d+\}: \((\d+), \{\}, (?:may|must)-alias\)", header)
+        assert len(set(aliased)) == n_pools, header[:300]
+
+    @pytest.mark.parametrize("upto", ["prefill", "decode"])
+    def test_only_the_slots_chain_and_trash_are_written(
+            self, model_and_params, upto):
+        """A prefill padded to its bucket (5 tokens in 8) and a decode
+        step beside an empty slot write padding and the idle row's K/V
+        somewhere: the trash block, and no block of anyone else."""
+        from horovod_tpu.serve.kv import TRASH_BLOCK
+
+        model, params = model_and_params
+        eng = _engine(model_and_params, kv_cache="paged", kv_block=4)
+        fill = jax.random.normal(jax.random.PRNGKey(7),
+                                 eng._pools[0]["k"].shape)
+        eng._pools = [{"k": fill + i, "v": fill - i}
+                      for i in range(len(eng._pools))]
+        before = [{n: np.asarray(a) for n, a in p.items()}
+                  for p in eng._pools]
+        prompt = [3, 1, 4, 1, 5]
+        toks = [eng.start(0, prompt, SamplingParams(max_new_tokens=4))]
+        if upto == "decode":
+            toks.extend(eng.step()[0])
+        owned = set(eng._kv.chain_blocks(0)) | {TRASH_BLOCK}
+        others = [b for b in range(eng.kv_blocks) if b not in owned]
+        assert others and len(owned) == 3    # two chain blocks + trash
+        for was, now in zip(before, eng._pools):
+            for name in ("k", "v"):
+                np.testing.assert_array_equal(
+                    np.asarray(now[name])[others], was[name][others])
+        # ... and what the other blocks hold never reaches a query.
+        assert toks == _greedy_reference(model, params, prompt, len(toks))
+
+
 class TestBlockPoolUnit:
     """Host-side allocator invariants (no jax involved)."""
 
@@ -476,6 +574,44 @@ class TestSpeculative:
                 (k, prompt)
         stats = eng.kv_stats()
         assert stats["spec_accept_per_verify"] >= 1.0
+
+    def test_all_rejected_drafts_emit_plain_greedy(self, model_and_params):
+        """Every draft wrong: the step emits the one plain greedy token,
+        and the rejected rows' K/V, written at positions past the
+        slot's new length (ISSUE 25: the write no longer waits for the
+        verdict), is hidden by the mask and overwritten before any
+        query reaches it — the following steps, with drafts that are
+        right again, still equal plain decode."""
+        model, params = model_and_params
+        K, n = 3, 12
+        eng = self._spec_engine(model_and_params, (model, params), K)
+        prompt = [3, 14, 15]
+        ref = _greedy_reference(model, params, prompt, n + K)
+        toks = [eng.start(0, prompt, SamplingParams(max_new_tokens=n,
+                                                    spec=True))]
+        assert toks == ref[:1]
+        draft_fn, wrong = eng._spec_draft_fn, [True]
+
+        def drafts(*args):
+            draft, dcaches = draft_fn(*args)
+            if wrong[0]:
+                at = len(toks)
+                bad = [(t + 1) % VOCAB for t in ref[at:at + K]]
+                draft = jnp.asarray(draft).at[0].set(
+                    jnp.asarray(bad, jnp.int32))
+            return draft, dcaches
+
+        eng._spec_draft_fn = drafts
+        for _ in range(4):
+            out = eng.step()[0]
+            assert out == ref[len(toks):len(toks) + 1], (out, toks)
+            toks.extend(out)
+        assert eng.kv_stats()["spec_accept_per_verify"] == 1.0
+        wrong[0] = False
+        while len(toks) < n:
+            toks.extend(eng.step()[0])
+        assert toks[:n] == ref[:n]
+        assert eng.kv_stats()["spec_accept_per_verify"] > 1.0
 
     def test_mixed_spec_and_plain_slots_share_the_batch(
             self, model_and_params):

@@ -155,3 +155,44 @@ def test_fused_matmul_allgather_compiles_for_v5e(topo):
             x, w, axis="hvd", interpret=False),
         [P(), P(None, "hvd")], P(),
         ((512, 1024), jnp.bfloat16), ((1024, 4096), jnp.bfloat16))
+
+
+def test_paged_decode_holds_no_pool_copy_on_v5e(topo):
+    """The serving decode program at GPT-2 XL's attention widths (25
+    heads of 64, 8 slots, 1025 blocks of 16), pools donated: the chip's
+    compiler must update every KV pool in place.  The CPU's compiler
+    (tests/test_serving.py) sees the order of the write and the read;
+    only this one sees the layout: a pool kept as ``[blocks, block, H,
+    D]`` gets a device layout with the blocks in the lanes, the scatter
+    and the gather want the rows, and every program then converts each
+    pool on the way in and out — a whole-pool copy that was 55 % of the
+    device's time in serving (PERF.md, PR 25)."""
+    import re
+
+    from horovod_tpu.models.transformer import GPT, GPTConfig
+    from horovod_tpu.serve import InferenceEngine
+
+    model = GPT(GPTConfig(vocab_size=512, n_layer=1, n_head=25,
+                          d_model=1600, d_ff=256, max_seq_len=1024))
+    params = model.init(jax.random.PRNGKey(0),
+                        jnp.zeros((1, 8), jnp.int32))["params"]
+    eng = InferenceEngine(model, params, max_slots=8,
+                          prefill_buckets=(64,), max_seq_len=1024,
+                          kv_cache="paged", kv_block=16, kv_blocks=1025)
+    one = SingleDeviceSharding(topo.devices[0])
+
+    def described(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one), tree)
+
+    n, cols = eng.max_slots, eng.blocks_per_slot + 1
+    i32, f32 = (described(jnp.zeros(n, dt))
+                for dt in (jnp.int32, jnp.float32))
+    text = jax.jit(eng._decode_paged_impl, donate_argnums=(1,)).lower(
+        described(params), described(eng._pools),
+        described(jnp.zeros((n, cols), jnp.int32)), i32, i32, f32, i32,
+        described(jax.random.PRNGKey(0))).compile().as_text()
+    shape = ",".join(str(d) for d in eng._pools[0]["k"].shape)
+    copies = re.findall(r"^.*= \w+\[%s\]\S* copy\(.*$" % shape, text, re.M)
+    assert not copies, copies[:2]
+    assert text.split("\n", 1)[0].count("-alias)") == 2

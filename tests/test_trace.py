@@ -105,7 +105,7 @@ class TestSpanBasics:
             assert len(spans) == 8
             assert [s["args"]["i"] for s in spans] == list(range(12, 20))
         finally:
-            trace.configure(ring=2048)
+            trace.configure(ring=16384)
 
     def test_context_is_thread_local(self):
         seen = {}
