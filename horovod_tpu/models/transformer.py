@@ -17,7 +17,7 @@ bfloat16 activations by default: the MXU-native dtype.
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, Optional
+from typing import Any, Optional, Tuple
 
 import flax.linen as nn
 import jax
@@ -45,6 +45,20 @@ class GPTConfig:
     moe_every: int = 2                 # every Nth block is MoE (rest dense)
     dtype: Any = jnp.bfloat16
     param_dtype: Any = jnp.float32
+    # The block's vocabulary.  The defaults are GPT-2's block and give
+    # the parameter tree and the programs this model always had.
+    norm: str = "layernorm"            # 'layernorm' | 'rmsnorm'
+    norm_eps: float = 1e-6
+    positions: str = "learned"         # 'learned': a table of max_seq_len rows | 'rope': no table
+    rope_theta: float = 10000.0
+    n_kv_head: int = 0                 # 0 = n_head: one KV head per query head
+    head_dim: int = 0                  # 0 = d_model // n_head
+    qk_norm: bool = False              # RMS norm of q and k per head, before positions
+    mlp: str = "gelu"                  # 'gelu' | 'swiglu' (gated SiLU)
+    # What mixes tokens in a layer: 'attention' (softmax over a K/V
+    # cache) or 'retention' (ops/retention.py: a fixed-size state).
+    # One word for every layer, or one per layer.
+    mixer: Any = "attention"
     # Tensor-parallel serving (docs/tp_serving.md): a 1-D ``tensor``
     # mesh makes one decode replica span ``tp`` chips.  Placement is
     # column-parallel only (qkv/up kernels sharded on the output dim,
@@ -56,20 +70,69 @@ class GPTConfig:
     tp_mesh: Optional[Mesh] = None
     tp_axis: str = "tensor"
 
+    @property
+    def kv_heads(self) -> int:
+        return self.n_kv_head or self.n_head
+
+    @property
+    def head_size(self) -> int:
+        return self.head_dim or self.d_model // self.n_head
+
+    @property
+    def mixers(self) -> Tuple[str, ...]:
+        """The mixer of every layer."""
+        kinds = ((self.mixer,) * self.n_layer if isinstance(self.mixer, str)
+                 else tuple(self.mixer))
+        if len(kinds) != self.n_layer or set(kinds) - set(MIXERS):
+            raise ValueError(
+                f"mixer must be one of {MIXERS} or {self.n_layer} of them, "
+                f"got {self.mixer!r}")
+        return kinds
+
+
+MIXERS = ("attention", "retention")
+
+
+def cache_kinds(config: GPTConfig) -> Tuple[str, ...]:
+    """What each layer keeps between the tokens of a request, as the
+    model declares it: ``'kv'`` (keys and values of every position so
+    far: :func:`init_kv_cache`, or the engine's paged pools) or
+    ``'state'`` (a fixed-size retention state: :func:`init_state_cache`).
+    The serving engine chooses its cache from this."""
+    return tuple("kv" if m == "attention" else "state"
+                 for m in config.mixers)
+
+
+def init_state_cache(config: GPTConfig, batch_size: int):
+    """The retention state of ``batch_size`` rows, zeros: per layer one
+    float32 ``{"s": [B, K, d/2 + 1, d, d], "z": [B, K, d/2 + 1, d]}``
+    (``ops/retention.py`` has the layout), whatever the context length.
+    In a model's ``kv_caches`` such an entry may also carry ``"valid"``
+    (``[B, T]`` bool): tokens that are not valid — padding of a prefill
+    bucket, rows of a decode step that hold no request — leave the state
+    as it is."""
+    from ..ops import retention
+
+    s_shape, z_shape = retention.state_shapes(
+        batch_size, config.kv_heads, config.head_size)
+    return [{"s": jnp.zeros(s_shape, jnp.float32),
+             "z": jnp.zeros(z_shape, jnp.float32)}
+            for _ in range(config.n_layer)]
+
 
 def init_kv_cache(config: GPTConfig, batch_size: int, max_len: int):
     """Preallocated per-layer KV cache for autoregressive decode
-    (serve/engine.py): one ``{"k", "v"}`` pair of ``[B, max_len, H, D]``
-    arrays per block.  Allocated once per serving slot-batch so the
+    (serve/engine.py): one ``{"k", "v"}`` pair of ``[B, max_len, K, D]``
+    arrays per block (``K`` KV heads of size ``D``, both as the
+    configuration states them).  Allocated once per serving slot-batch so the
     decode hot path never reallocates; the engine's length buckets keep
     the set of compiled shapes small.
 
     The paged alternative (``horovod_tpu/serve/kv``) replaces the dense
-    per-slot rows with one ``[num_blocks, block, H * D]`` pool per
+    per-slot rows with one ``[num_blocks, block, K * D]`` pool per
     layer plus a per-slot block table; :class:`Attention` accepts either
     layout (``{"k", "v"}`` vs ``{"k_pool", "v_pool", "table"}``)."""
-    head_dim = config.d_model // config.n_head
-    shape = (batch_size, max_len, config.n_head, head_dim)
+    shape = (batch_size, max_len, config.kv_heads, config.head_size)
     return [{"k": jnp.zeros(shape, config.dtype),
              "v": jnp.zeros(shape, config.dtype)}
             for _ in range(config.n_layer)]
@@ -92,6 +155,68 @@ def _tp_shard(cfg: GPTConfig, x, *spec):
         x, NamedSharding(cfg.tp_mesh, PartitionSpec(*spec)))
 
 
+class RMSNorm(nn.Module):
+    """``x / rms(x) * scale`` over the last axis, computed in float32."""
+
+    config: GPTConfig
+
+    @nn.compact
+    def __call__(self, x):
+        cfg = self.config
+        scale = self.param("scale", nn.initializers.ones, (x.shape[-1],),
+                           cfg.param_dtype)
+        x32 = x.astype(jnp.float32)
+        y = x32 * jax.lax.rsqrt(
+            jnp.mean(jnp.square(x32), axis=-1, keepdims=True) + cfg.norm_eps)
+        return (y * scale.astype(jnp.float32)).astype(cfg.dtype)
+
+
+def _norm(cfg: GPTConfig, name: str):
+    """The block's norm, under the one name either kind answers to."""
+    if cfg.norm == "rmsnorm":
+        return RMSNorm(cfg, name=name)
+    if cfg.norm != "layernorm":
+        raise ValueError(f"Unknown norm {cfg.norm!r}")
+    return nn.LayerNorm(epsilon=cfg.norm_eps, dtype=cfg.dtype, name=name)
+
+
+def _rope(x, positions, theta: float):
+    """Rotary positions on ``x [B, T, N, D]`` at absolute ``positions
+    [B, T]``: the half-split convention (``x1, x2`` = the two halves of
+    a head; ``x1 cos - x2 sin, x2 cos + x1 sin``), angles in float32
+    from the position itself, so there is no table and no longest
+    sequence."""
+    half = x.shape[-1] // 2
+    inv = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
+    ang = positions.astype(jnp.float32)[..., None, None] * inv
+    cos, sin = jnp.cos(ang), jnp.sin(ang)
+    x32 = x.astype(jnp.float32)
+    x1, x2 = x32[..., :half], x32[..., half:]
+    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
+                           axis=-1).astype(x.dtype)
+
+
+def _dense(cfg: GPTConfig, features: int, name: str):
+    return nn.Dense(features, use_bias=False, dtype=cfg.dtype,
+                    param_dtype=cfg.param_dtype, name=name)
+
+
+def _positioned(cfg: GPTConfig, q, k, positions):
+    """``q`` and ``k`` as the mixers see them: the per-head RMS norm
+    (``qk_norm``; inside a mixer's ``@nn.compact`` call, so the two
+    scales are that mixer's ``q_norm`` / ``k_norm``), then rotary
+    positions (``positions='rope'``)."""
+    if cfg.qk_norm:
+        q = RMSNorm(cfg, name="q_norm")(q)
+        k = RMSNorm(cfg, name="k_norm")(k)
+    if cfg.positions == "rope":
+        if positions is None:
+            positions = jnp.arange(q.shape[1], dtype=jnp.int32)[None]
+        q = _rope(q, positions, cfg.rope_theta)
+        k = _rope(k, positions, cfg.rope_theta)
+    return q, k
+
+
 class Attention(nn.Module):
     """Self-attention.  ``attn(x)`` is the training forward.  With a KV
     cache, ``attn(x, cache=..., positions=...)`` writes the chunk's K/V
@@ -106,24 +231,28 @@ class Attention(nn.Module):
     @nn.compact
     def __call__(self, x, cache=None, positions=None):
         cfg = self.config
-        B, T, C = x.shape
-        H = cfg.n_head
-        D = C // H
-        qkv = nn.Dense(3 * C, use_bias=False, dtype=cfg.dtype,
-                       param_dtype=cfg.param_dtype, name="qkv")(x)
-        q, k, v = jnp.split(qkv, 3, axis=-1)
+        B, T, _ = x.shape
+        H, K, D = cfg.n_head, cfg.kv_heads, cfg.head_size
+        C = H * D
+        qkv = _dense(cfg, (H + 2 * K) * D, "qkv")(x)
+        q, k, v = jnp.split(qkv, [H * D, (H + K) * D], axis=-1)
         # Under TP the qkv kernel is column-sharded, so q/k/v arrive
         # head-sharded; pin the layout explicitly so the paged pool
         # writes and the attention einsums stay head-local (each shard
         # computes its own H/tp heads completely — bitwise).
         q = _tp_shard(cfg, q.reshape(B, T, H, D),
                       None, None, cfg.tp_axis, None)
-        k = _tp_shard(cfg, k.reshape(B, T, H, D),
+        k = _tp_shard(cfg, k.reshape(B, T, K, D),
                       None, None, cfg.tp_axis, None)
-        v = _tp_shard(cfg, v.reshape(B, T, H, D),
+        v = _tp_shard(cfg, v.reshape(B, T, K, D),
                       None, None, cfg.tp_axis, None)
-        proj = nn.Dense(C, use_bias=False, dtype=cfg.dtype,
-                        param_dtype=cfg.param_dtype, name="out")
+        q, k = _positioned(cfg, q, k, positions)
+        proj = _dense(cfg, cfg.d_model, "out")
+
+        def per_query_head(x):
+            # Grouped KV heads: each is read by H / K query heads.
+            return x if K == H else jnp.repeat(x, H // K, axis=2)
+
         if cache is not None:
             # KV-cache path (serving prefill chunks and single-token
             # decode steps): write this chunk's K/V at its absolute
@@ -135,7 +264,7 @@ class Attention(nn.Module):
             #
             # Dense ``{"k", "v"}``: per-slot ``[B, S, H, D]`` rows.
             # Paged ``{"k_pool", "v_pool", "table"}``: one ``[num_blocks,
-            # block, H * D]`` pool per layer, written and gathered
+            # block, K * D]`` pool per layer, written and gathered
             # through the per-row block table (view row ``i`` is the
             # token at position ``i`` of the row's chain; invalid
             # positions reach the trash block by the table's last
@@ -148,17 +277,18 @@ class Attention(nn.Module):
                 blk = jnp.take_along_axis(table, positions // block, axis=1)
                 off = positions % block
                 k_new = k_pool.at[blk, off].set(
-                    k.reshape(B, T, C).astype(k_pool.dtype))
+                    k.reshape(B, T, K * D).astype(k_pool.dtype))
                 v_new = v_pool.at[blk, off].set(
-                    v.reshape(B, T, C).astype(v_pool.dtype))
-                k_all = k_new[table].reshape(B, -1, H, D)
-                v_all = v_new[table].reshape(B, -1, H, D)
+                    v.reshape(B, T, K * D).astype(v_pool.dtype))
+                k_all = k_new[table].reshape(B, -1, K, D)
+                v_all = v_new[table].reshape(B, -1, K, D)
             else:
                 row = jnp.arange(B)[:, None]
                 k_new = k_all = cache["k"].at[row, positions].set(
                     k.astype(cache["k"].dtype))
                 v_new = v_all = cache["v"].at[row, positions].set(
                     v.astype(cache["v"].dtype))
+            k_all, v_all = per_query_head(k_all), per_query_head(v_all)
             S = k_all.shape[1]
             scores = jnp.einsum("bqhd,bkhd->bhqk", q, k_all)
             scores = scores.astype(jnp.float32) * (D ** -0.5)
@@ -171,6 +301,7 @@ class Attention(nn.Module):
             # shard computes the full projection — bitwise identical.
             merged = _tp_shard(cfg, out.reshape(B, T, C))
             return proj(merged), {"k": k_new, "v": v_new}
+        k, v = per_query_head(k), per_query_head(v)
         if cfg.attention == "ring":
             if self.mesh is None:
                 raise ValueError("attention='ring' requires a mesh")
@@ -207,12 +338,78 @@ class Attention(nn.Module):
         return proj(_tp_shard(cfg, out.reshape(B, T, C)))
 
 
+class Retention(nn.Module):
+    """Power retention in the place of attention (``ops/retention.py``
+    has the operator and its three forms).  Per layer: ``q`` (H heads),
+    ``k``, ``v`` (K heads, each read by H / K query heads), a gate
+    ``log g = log_sigmoid(gate(x))`` per KV head and token, in float32;
+    q and k go through the configuration's per-head norm and positions
+    as attention's do.  ``retn(x)`` is the training-shaped forward (the
+    chunked form from a zero state).  With a state,
+    ``retn(x, cache={"s", "z"[, "valid"]}, positions=...)`` continues
+    from it — a chunk of a prompt (chunked form) or one token a row
+    (recurrent form) — and returns ``(out, {"s", "z"})``: the state
+    after the chunk, the same size whatever came before."""
+
+    config: GPTConfig
+
+    @nn.compact
+    def __call__(self, x, cache=None, positions=None):
+        from ..ops import retention
+
+        cfg = self.config
+        B, T, _ = x.shape
+        H, K, D = cfg.n_head, cfg.kv_heads, cfg.head_size
+        q = _dense(cfg, H * D, "q")(x).reshape(B, T, H, D)
+        k = _dense(cfg, K * D, "k")(x).reshape(B, T, K, D)
+        v = _dense(cfg, K * D, "v")(x).reshape(B, T, K, D)
+        log_g = jax.nn.log_sigmoid(nn.Dense(
+            K, dtype=jnp.float32, param_dtype=cfg.param_dtype,
+            bias_init=_gate_bias, name="gate")(x))
+        q, k = _positioned(cfg, q, k, positions)
+        proj = _dense(cfg, cfg.d_model, "out")
+        if cache is None:
+            out, _ = retention.retention_chunked(q, k, v, log_g)
+            return proj(out.astype(cfg.dtype).reshape(B, T, H * D))
+        state, valid = (cache["s"], cache["z"]), cache.get("valid")
+        # The scopes hold the operator alone; the projections are the
+        # block's, outside them.
+        if T == 1:
+            with jax.named_scope("hvd_tpu_retention_decode"):
+                out, state = retention.retention_step(
+                    q[:, 0], k[:, 0], v[:, 0], log_g[:, 0], state,
+                    None if valid is None else valid[:, 0])
+        else:
+            with jax.named_scope("hvd_tpu_retention_prefill"):
+                out, state = retention.retention_chunked(
+                    q, k, v, log_g, state, valid)
+        out = proj(out.astype(cfg.dtype).reshape(B, T, H * D))
+        return out, {"s": state[0], "z": state[1]}
+
+
+def _gate_bias(key, shape, dtype=jnp.float32):
+    """The gate's bias at initialisation: memories from about eight
+    tokens (sigmoid(2)) to about four hundred (sigmoid(6)), one per KV
+    head."""
+    del key
+    return jnp.linspace(2.0, 6.0, shape[0]).astype(dtype)
+
+
 class MlpBlock(nn.Module):
     config: GPTConfig
 
     @nn.compact
     def __call__(self, x):
         cfg = self.config
+        if cfg.mlp == "swiglu":
+            # Gated SiLU: down(silu(gate(x)) * up(x)).  Column-parallel
+            # placement is the plain MLP's; this branch is not TP-placed
+            # yet (plan.tp_param_spec knows no ``gate``).
+            h = nn.silu(_dense(cfg, cfg.d_ff, "gate")(x)) \
+                * _dense(cfg, cfg.d_ff, "up")(x)
+            return _dense(cfg, cfg.d_model, "down")(h)
+        if cfg.mlp != "gelu":
+            raise ValueError(f"Unknown mlp {cfg.mlp!r}")
         x = nn.Dense(cfg.d_ff, use_bias=False, dtype=cfg.dtype,
                      param_dtype=cfg.param_dtype, name="up")(x)
         # Column-parallel ``up`` leaves the d_ff activation sharded;
@@ -229,17 +426,21 @@ class Block(nn.Module):
     config: GPTConfig
     mesh: Optional[Mesh] = None
     use_moe: bool = False
+    mixer: str = "attention"
 
     @nn.compact
     def __call__(self, x, cache=None, positions=None):
         cfg = self.config
-        attn_in = nn.LayerNorm(dtype=cfg.dtype, name="ln1")(x)
-        attn = Attention(cfg, self.mesh, name="attn")
+        attn_in = _norm(cfg, "ln1")(x)
+        if self.mixer == "retention":
+            attn = Retention(cfg, name="retn")
+        else:
+            attn = Attention(cfg, self.mesh, name="attn")
         new_cache = None
         if cache is not None:
             a, new_cache = attn(attn_in, cache=cache, positions=positions)
         else:
-            a = attn(attn_in)
+            a = attn(attn_in, positions=positions)
         x = x + a
         if self.use_moe:
             from ..parallel.moe import MoEMlp
@@ -251,7 +452,7 @@ class Block(nn.Module):
                          name="moe")
         else:
             ffn = MlpBlock(cfg, name="mlp")
-        x = x + ffn(nn.LayerNorm(dtype=cfg.dtype, name="ln2")(x))
+        x = x + ffn(_norm(cfg, "ln2")(x))
         if cache is not None:
             return x, new_cache
         return x
@@ -266,16 +467,21 @@ class GPT(nn.Module):
     chunk) returns ``(logits, new_caches)`` — the jitted prefill/decode
     primitive behind ``horovod_tpu.serve.engine``.  Either layout,
     ``new_caches`` is the cache the model updated: one ``{"k", "v"}``
-    per layer, dense rows or whole pools."""
+    per layer, dense rows or whole pools — or, for a retention layer
+    (:func:`cache_kinds`), its ``{"s", "z"}`` state.  ``logit_rows``
+    (``[B]``) asks for the logits of one position a row, ``[B, 1, V]``:
+    a prefill needs the last real token's, not a bucket's worth of a
+    large vocabulary."""
 
     config: GPTConfig
     mesh: Optional[Mesh] = None
 
     @nn.compact
     def __call__(self, tokens, return_hidden: bool = False,
-                 kv_caches=None, positions=None):
+                 kv_caches=None, positions=None, logit_rows=None):
         cfg = self.config
         B, T = tokens.shape
+        mixers = cfg.mixers
         if kv_caches is not None:
             if cfg.attention in ("ring", "ulysses"):
                 # Sequence-sharded training layouts have no KV-cache
@@ -289,26 +495,33 @@ class GPT(nn.Module):
         tok_emb = nn.Embed(cfg.vocab_size, cfg.d_model,
                            param_dtype=cfg.param_dtype,
                            dtype=cfg.dtype, name="embed")(tokens)
-        pos_emb = self.param(
-            "pos_embed", nn.initializers.normal(0.02),
-            (cfg.max_seq_len, cfg.d_model), cfg.param_dtype,
-        )
-        if kv_caches is not None:
-            x = tok_emb + pos_emb[positions].astype(cfg.dtype)
+        if cfg.positions == "learned":
+            pos_emb = self.param(
+                "pos_embed", nn.initializers.normal(0.02),
+                (cfg.max_seq_len, cfg.d_model), cfg.param_dtype,
+            )
+            if kv_caches is not None:
+                x = tok_emb + pos_emb[positions].astype(cfg.dtype)
+            else:
+                x = tok_emb + pos_emb[None, :T].astype(cfg.dtype)
+        elif cfg.positions == "rope":
+            x = tok_emb     # the mixers rotate q and k; there is no table
         else:
-            x = tok_emb + pos_emb[None, :T].astype(cfg.dtype)
+            raise ValueError(f"Unknown positions {cfg.positions!r}")
         new_caches = []
         for i in range(cfg.n_layer):
             use_moe = (cfg.moe_experts > 0
                        and (i + 1) % max(1, cfg.moe_every) == 0)
-            block = Block(cfg, self.mesh, use_moe=use_moe,
+            block = Block(cfg, self.mesh, use_moe=use_moe, mixer=mixers[i],
                           name=f"block_{i}")
             if kv_caches is not None:
                 x, c = block(x, cache=kv_caches[i], positions=positions)
                 new_caches.append(c)
             else:
                 x = block(x)
-        x = nn.LayerNorm(dtype=cfg.dtype, name="ln_f")(x)
+        if logit_rows is not None:
+            x = jnp.take_along_axis(x, logit_rows[:, None, None], axis=1)
+        x = _norm(cfg, "ln_f")(x)
         if return_hidden:
             # Pre-head activations for the chunked-vocab loss
             # (ops/xent.py) — the lm_head matmul happens inside the

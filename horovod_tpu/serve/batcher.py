@@ -199,14 +199,16 @@ class ContinuousBatcher:
         # from the HVD_TPU_QOS_* knobs; the admission queue is the
         # weighted-fair scheduler (a single unconfigured flow is exact
         # FIFO, so default behavior is unchanged), and deadline-aware
-        # preemption is gated on the paged cache — eviction is only
-        # cheap when the KV survives in the prefix index.
+        # preemption is gated on a cache a resume can rebuild in
+        # chunks: the paged one (eviction is cheap, the KV survives in
+        # the prefix index) or a retention state (nothing survives;
+        # the resume recomputes the sequence, carrying the state).
         self._policy = (qos_policy if qos_policy is not None
                         else QosPolicy.from_config(cfg))
         self._preempt_enabled = (
             bool(qos_preempt if qos_preempt is not None
                  else cfg.qos_preempt)
-            and engine.kv_mode == "paged")
+            and engine.kv_mode in ("paged", "state"))
         # Interactive TTFT SLO (HVD_TPU_QOS_SLO_TTFT_MS): with it set,
         # preemption fires aggressively enough to land interactive
         # first tokens inside the budget; 0 = deadline feasibility only.

@@ -14,7 +14,10 @@ The serving hot path is two compiled programs:
   continuous-batching property: admission never waits for the batch to
   drain.
 
-Two KV layouts live under this one API (``HVD_TPU_SERVE_KV``):
+Three caches live under this one API.  The model declares what each
+of its layers keeps (``models.transformer.cache_kinds``): keys and
+values, which ``HVD_TPU_SERVE_KV`` lays out as **paged** or **dense**,
+or a fixed-size retention **state**:
 
 * **paged** (default) — one ``[num_blocks, block, H * D]`` pool per
   layer plus a host-side block table (``serve/kv/``): requests map
@@ -34,6 +37,17 @@ Two KV layouts live under this one API (``HVD_TPU_SERVE_KV``):
   scatter/gather.
 * **dense** — the original per-slot ``[slots, S, H, D]`` rows; kept as
   the token-identity oracle the paged path is tested against.
+* **state** — what a model of retention layers gets when nothing is
+  set: per slot and layer one float32 ``(S, z)`` pair of a size that
+  does not depend on the context (``models.transformer.
+  init_state_cache``).  A prefill takes the slot's state in and gives
+  it back — zeros when it starts at position 0, the carried state when
+  it continues (a resume's later chunks) — and a bucket's padding never
+  enters it; decode updates every slot's state in place, in one
+  program, rows without a request left as they are.  There are no
+  blocks, no table and no prefix to share; preemption keeps nothing and
+  a resume recomputes.  ``max_seq_len`` is then the most positions a
+  request may reach, and costs no memory.
 
 **Speculative decoding** (per-request opt-in via
 ``SamplingParams(spec=True)``; greedy requests only): a small drafter
@@ -64,7 +78,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec
 
-from ..models.transformer import GPT, init_kv_cache
+from ..models.transformer import (GPT, cache_kinds, init_kv_cache,
+                                  init_state_cache)
 from ..obs import trace as trace_mod
 from ..utils.logging import get_logger
 from .kv import BlockPool, TRASH_BLOCK
@@ -84,7 +99,8 @@ def resolved_config():
 
 
 class PromptTooLongError(ValueError):
-    """Prompt exceeds the largest prefill bucket / cache length."""
+    """Prompt exceeds the largest prefill bucket, or the positions a
+    request may reach."""
 
 
 @dataclasses.dataclass(frozen=True)
@@ -158,20 +174,42 @@ class InferenceEngine:
         self._params = params
         self.max_slots = int(max_slots or cfg.serve_max_batch)
         self.max_seq_len = int(max_seq_len or model.config.max_seq_len)
-        if self.max_seq_len > model.config.max_seq_len:
+        if (model.config.positions == "learned"
+                and self.max_seq_len > model.config.max_seq_len):
             raise ValueError(
                 f"max_seq_len {self.max_seq_len} exceeds the model's "
                 f"positional table ({model.config.max_seq_len})")
         buckets = tuple(prefill_buckets or cfg.serve_prefill_buckets)
-        # Clamp buckets to the cache length; keep at least one.
+        # Clamp buckets to the most positions a request may reach; keep
+        # at least one.
         self.prefill_buckets = tuple(sorted(
             {min(int(b), self.max_seq_len) for b in buckets if b > 0}))
         if not self.prefill_buckets:
             raise ValueError(f"no usable prefill buckets in {buckets}")
-        self.kv_mode = (kv_cache or cfg.serve_kv).lower()
-        if self.kv_mode not in ("paged", "dense"):
+        # The model declares what its layers keep; the engine picks the
+        # cache from that when the caller sets nothing.
+        kinds = set(cache_kinds(model.config))
+        if len(kinds) > 1:
+            raise ValueError(
+                "a model that keeps K/V in some layers and a retention "
+                "state in others is not servable yet: the engine holds "
+                "one kind of cache")
+        stateful = kinds == {"state"}
+        self.kv_mode = (kv_cache or ("state" if stateful
+                                     else cfg.serve_kv)).lower()
+        if self.kv_mode not in ("paged", "dense", "state"):
             raise ValueError(f"unknown kv_cache mode {self.kv_mode!r}; "
-                             f"expected 'paged' or 'dense'")
+                             f"expected 'paged', 'dense' or 'state'")
+        if stateful and self.kv_mode != "state":
+            raise ValueError(
+                f"kv_cache={self.kv_mode!r} does not fit this model: its "
+                f"layers keep a retention state, not keys and values, and "
+                f"are served from kv_cache='state'")
+        if self.kv_mode == "state" and not stateful:
+            raise ValueError(
+                "kv_cache='state' does not fit this model: its layers "
+                "keep keys and values, and are served from "
+                "kv_cache='paged' or 'dense'")
         # Tensor-parallel replica (docs/tp_serving.md): the forward
         # shards over a 1-D ``tensor`` mesh spanning the first ``tp``
         # local devices — column-parallel qkv/up placement plus the
@@ -187,15 +225,19 @@ class InferenceEngine:
         if self.tp < 1:
             raise ValueError(f"tp must be >= 1, got {self.tp}")
         if self.tp > 1:
+            if self.kv_mode == "state":
+                raise ValueError(
+                    "tensor-parallel serving of a retention state is not "
+                    "built yet: the state is not sharded over its heads")
             if self.kv_mode != "paged":
                 raise ValueError(
                     "tensor-parallel serving requires the paged KV "
                     "cache (HVD_TPU_SERVE_KV=paged) — the head-sharded "
                     "pool is the TP layout")
-            if model.config.n_head % self.tp:
+            if model.config.kv_heads % self.tp:
                 raise ValueError(
-                    f"tp={self.tp} must divide the model's head count "
-                    f"({model.config.n_head}) for the head-sharded pool")
+                    f"tp={self.tp} must divide the model's KV head count "
+                    f"({model.config.kv_heads}) for the head-sharded pool")
             from ..plan import tp_plan
 
             plan = tp_plan(self.tp)
@@ -239,8 +281,21 @@ class InferenceEngine:
         # no donation support (it would only warn), so gate on backend.
         self._donate = (1,) if jax.default_backend() != "cpu" else ()
         n_layer = model.config.n_layer
-        head_dim = model.config.d_model // model.config.n_head
-        if self.kv_mode == "paged":
+        kv_heads, head_dim = model.config.kv_heads, model.config.head_size
+        self._states = None
+        self.state_resets = 0
+        if self.kv_mode == "state":
+            self.kv_block = 0
+            self.kv_blocks = 0
+            self._kv = None
+            self._caches = None
+            self._states = _beside(params, init_state_cache(
+                model.config, self.max_slots))
+            self._decode_fn = jax.jit(self._decode_state_impl,
+                                      donate_argnums=self._donate)
+            self._prefill_fns = {L: self._make_state_prefill(L)
+                                 for L in self.prefill_buckets}
+        elif self.kv_mode == "paged":
             self.kv_block = int(kv_block or cfg.serve_kv_block)
             if self.kv_block < 1:
                 raise ValueError(f"kv_block must be >= 1, got "
@@ -259,7 +314,7 @@ class InferenceEngine:
                     f"(1 trash + slots x blocks_per_slot) — active "
                     f"requests could deadlock on allocation")
             self.kv_blocks = budget
-            shape = (budget, self.kv_block, model.config.n_head * head_dim)
+            shape = (budget, self.kv_block, kv_heads * head_dim)
 
             def _pool_zeros():
                 z = jnp.zeros(shape, model.config.dtype)
@@ -288,12 +343,12 @@ class InferenceEngine:
             dt_size = np.dtype(model.config.dtype).itemsize
             self._kv = BlockPool(
                 budget, self.kv_block, self._table, self._copy_block,
-                heads=model.config.n_head // self.tp,
+                heads=kv_heads // self.tp,
                 tp_degree=self.tp,
-                # Per-SHARD bytes of one block: K+V rows for the H/tp
+                # Per-SHARD bytes of one block: K+V rows for the K/tp
                 # heads this shard holds, across every layer.
                 bytes_per_block=(2 * n_layer * self.kv_block
-                                 * (model.config.n_head // self.tp)
+                                 * (kv_heads // self.tp)
                                  * head_dim * dt_size))
             self._caches = None
             self._decode_fn = jax.jit(self._decode_paged_impl,
@@ -318,6 +373,11 @@ class InferenceEngine:
         self.spec_verify_steps = 0
         self.spec_accepted_tokens = 0
         if drafter is not None:
+            if self.kv_mode == "state":
+                raise ValueError(
+                    "speculative decoding over a retention state is not "
+                    "built yet: a rejected draft cannot be taken back out "
+                    "of the state")
             if self.kv_mode != "paged":
                 raise ValueError("speculative decoding requires the "
                                  "paged KV cache (HVD_TPU_SERVE_KV=paged)")
@@ -472,6 +532,81 @@ class InferenceEngine:
         nxt = _sample(logits[:, -1].astype(jnp.float32), rng, temps, topks)
         return nxt, new
 
+    # --- compiled programs: state tier --------------------------------------
+
+    def _make_state_prefill(self, L: int):
+        model = self._model
+
+        def prefill(params, states, tokens, start, length, slot, rng,
+                    temp, topk):
+            # ``start`` = positions already in the slot's state (0 for a
+            # new request, which therefore begins from zeros whatever
+            # the slot held; a resume's later chunks continue);
+            # ``length`` = real tokens in the L-padded chunk.  Both are
+            # traced: one program per bucket.
+            self.trace_counts[f"prefill_{L}"] += 1  # trace-time only
+            idx = jnp.arange(L, dtype=jnp.int32)
+            carried = start > 0
+
+            def row(big):
+                mine = jax.lax.dynamic_slice_in_dim(big, slot, 1, axis=0)
+                return jnp.where(carried, mine, jnp.zeros_like(mine))
+
+            caches = [{"s": row(st["s"]), "z": row(st["z"]),
+                       "valid": (idx < length)[None]} for st in states]
+            logits, new = model.apply(
+                {"params": params}, tokens, kv_caches=caches,
+                positions=(start + idx)[None],
+                logit_rows=jnp.reshape(length - 1, (1,)))
+            token = _sample(logits[:, 0].astype(jnp.float32), rng,
+                            temp[None], topk[None])[0]
+
+            def write(big, mine):
+                return jax.lax.dynamic_update_slice_in_dim(
+                    big, mine, slot, axis=0)
+
+            return token, [{"s": write(st["s"], n["s"]),
+                            "z": write(st["z"], n["z"])}
+                           for st, n in zip(states, new)]
+
+        return jax.jit(prefill, donate_argnums=self._donate)
+
+    def _decode_state_impl(self, params, states, tokens, positions,
+                           active, temps, topks, rng):
+        # The dense step's arguments and ``active``: a row without a
+        # request leaves its state as it is (a dense row's stale keys
+        # hide behind the position mask; a state has no such mask).
+        self.trace_counts["decode"] += 1  # trace-time only
+        caches = [dict(st, valid=active[:, None]) for st in states]
+        logits, new = self._model.apply(
+            {"params": params}, tokens[:, None], kv_caches=caches,
+            positions=positions[:, None])
+        nxt = _sample(logits[:, -1].astype(jnp.float32), rng, temps, topks)
+        return nxt, new
+
+    def _state_prefill(self, slot: int, seq: List[int],
+                       sampling: SamplingParams, span_args: dict) -> int:
+        """``seq`` into ``slot``'s state from position 0, in
+        bucket-sized chunks with the state carried (a prompt is one
+        chunk; a resumed sequence may be longer than the largest
+        bucket).  Returns the token sampled after the last chunk."""
+        top = self.prefill_buckets[-1]
+        pos, n, token = 0, len(seq), None
+        while pos < n:
+            ns = min(n - pos, top)
+            L = self.bucket_for(ns)
+            padded = np.zeros((1, L), np.int32)
+            padded[0, :ns] = np.asarray(seq[pos:pos + ns], np.int32)
+            span_args["bucket"] = L     # the last chunk's
+            token, self._states = self._prefill_fns[L](
+                self._params, self._states, jnp.asarray(padded),
+                jnp.int32(pos), jnp.int32(ns), jnp.int32(slot),
+                self._next_rng(), jnp.float32(sampling.temperature),
+                jnp.int32(sampling.top_k))
+            pos += ns
+        self.state_resets += 1
+        return int(token)
+
     # --- compiled programs: speculative tier --------------------------------
 
     def _make_draft_prefill(self, L: int):
@@ -585,7 +720,8 @@ class InferenceEngine:
         if prompt_len >= self.max_seq_len:
             raise PromptTooLongError(
                 f"prompt of {prompt_len} tokens leaves no room to "
-                f"generate (cache length {self.max_seq_len})")
+                f"generate (a request may reach {self.max_seq_len} "
+                f"positions)")
         return self.bucket_for(prompt_len)
 
     def check_prompt_tokens(self, prompt: Sequence[int]) -> int:
@@ -614,9 +750,10 @@ class InferenceEngine:
             return [int(s) for s in np.nonzero(self._active)[0]]
 
     def slot_full(self, slot: int) -> bool:
-        """True when the next decode would write past the cache (the
-        next decode writes K/V at index ``_positions[slot]``, valid
-        while it is ``< max_seq_len``)."""
+        """True when the next decode would pass ``max_seq_len`` (the
+        next decode works at index ``_positions[slot]``, valid while it
+        is ``< max_seq_len``: the cache's last row, or with a retention
+        state the last position a request may reach)."""
         with self._slot_lock:
             return int(self._positions[slot]) >= self.max_seq_len
 
@@ -668,7 +805,8 @@ class InferenceEngine:
     def prefix_probe(self, prompt: Sequence[int]) -> int:
         """Resident-prefix length for ``prompt`` right now (no side
         effects) — the batcher's admission-time lookup; 0 on the dense
-        tier."""
+        tier, and always 0 over a retention state (a state holds no
+        prefix apart from the rest)."""
         if self._kv is None:
             return 0
         return self._kv.probe(list(prompt))
@@ -687,7 +825,8 @@ class InferenceEngine:
         on the paged tier the bucket covers only the non-resident
         suffix.  The whole call is one ``hvd_tpu_engine_prefill``
         span."""
-        span_args = {"slot": int(slot), "prompt_len": len(prompt)}
+        span_args = {"slot": int(slot), "prompt_len": len(prompt),
+                     "cache": self.kv_mode}
         with trace_mod.span("hvd_tpu_engine_prefill", args=span_args):
             return self._start(slot, prompt, sampling, span_args)
 
@@ -699,7 +838,11 @@ class InferenceEngine:
         prompt = [int(t) for t in prompt]
         n = len(prompt)
         self.check_prompt_tokens(prompt)
-        if self.kv_mode == "paged":
+        if self.kv_mode == "state":
+            hit = 0
+            span_args["prefix_hit"] = 0
+            token = self._state_prefill(slot, prompt, sampling, span_args)
+        elif self.kv_mode == "paged":
             hit = self._kv.begin_request(slot, prompt)
             ns = n - hit
             L = self.bucket_for(ns)
@@ -763,7 +906,13 @@ class InferenceEngine:
                 spec[s] and temps[s] <= 0 for s in active):
             return self._step_spec(active, snap)
         positions = np.where(act, pos, 0).astype(np.int32)
-        if self.kv_mode == "paged":
+        if self.kv_mode == "state":
+            nxt, self._states = self._decode_fn(
+                self._params, self._states,
+                jnp.asarray(last_tokens), jnp.asarray(positions),
+                jnp.asarray(act), jnp.asarray(temps), jnp.asarray(topks),
+                self._next_rng())
+        elif self.kv_mode == "paged":
             for s in active:
                 self._kv.ensure_writable(s, int(positions[s]), 1)
             nxt, self._pools = self._decode_fn(
@@ -845,7 +994,11 @@ class InferenceEngine:
         are reused (stale keys invisible behind the position mask);
         paged tier: the chain's references drop and unreferenced
         prompt blocks stay resident for future prefix hits until
-        evicted."""
+        evicted; state tier: the slot's state stays where it is, out of
+        every program's sight (decode leaves rows without a request as
+        they are), and the prefill that next binds the slot begins from
+        zeros — so a release from another thread touches no device
+        array."""
         if self._kv is not None:
             self._kv.release(slot)
         self._clear_slot(slot)
@@ -883,8 +1036,9 @@ class InferenceEngine:
 
     def can_resume(self, n_prompt: int, n_emitted: int) -> bool:
         """Whether a generation of this shape survives a
-        preempt/resume cycle here: the paged tier rebuilds arbitrarily
-        long tails in bucket-sized chunks, but a drafter's dense cache
+        preempt/resume cycle here: the paged and state tiers rebuild
+        arbitrarily long sequences in bucket-sized chunks, but a
+        drafter's dense cache
         has no chunked rebuild — its prefill writes one whole bucket —
         so on drafter engines only sequences fitting the largest
         bucket are preemptible (the scheduler skips other victims)."""
@@ -912,7 +1066,8 @@ class InferenceEngine:
         uninterrupted run; with concurrent traffic it stays
         distributionally correct (greedy is deterministic either
         way).  One ``hvd_tpu_engine_prefill`` span (``resumed``)."""
-        span_args = {"slot": int(slot), "resumed": True}
+        span_args = {"slot": int(slot), "resumed": True,
+                     "cache": self.kv_mode}
         with trace_mod.span("hvd_tpu_engine_prefill", args=span_args):
             return self._resume_slot(slot, prompt, emitted, sampling, rng,
                                      span_args)
@@ -932,7 +1087,14 @@ class InferenceEngine:
         seq = prompt + emitted[:-1]
         n = len(seq)
         span_args["prompt_len"] = n
-        if self.kv_mode == "paged":
+        if self.kv_mode == "state":
+            # Nothing survived the preemption: the whole sequence is
+            # recomputed, the state carried from chunk to chunk, and
+            # the token sampled after it is discarded.
+            hit = 0
+            span_args["prefix_hit"] = 0
+            self._state_prefill(slot, seq, sampling, span_args)
+        elif self.kv_mode == "paged":
             hit = self._kv.begin_request(slot, seq)
             span_args["prefix_hit"] = hit
             # Recompute the non-resident tail in bucket-sized chunks:
@@ -1068,6 +1230,10 @@ class InferenceEngine:
         non-trash chain blocks move.  Called at the prefill→decode
         boundary, when the chain covers exactly the prompt's positions
         ``[0, n_prompt)``."""
+        if self.kv_mode == "state":
+            raise RuntimeError(
+                "a retention state has no migration frame yet: "
+                "export_slot_kv ships K/V blocks")
         if self.kv_mode != "paged":
             raise RuntimeError("KV export requires the paged cache "
                                "(HVD_TPU_SERVE_KV=paged)")
@@ -1078,7 +1244,7 @@ class InferenceEngine:
         # The wire keeps heads apart (migration ships head shards); the
         # pool stores a token's heads as one row.
         wire = (len(self._pools), len(chain), self.kv_block,
-                self._model.config.n_head, -1)
+                self._model.config.kv_heads, -1)
         k = np.stack([np.asarray(p["k"][idx])
                       for p in self._pools]).reshape(wire)
         v = np.stack([np.asarray(p["v"][idx])
@@ -1103,13 +1269,18 @@ class InferenceEngine:
         the binding stands where a prefill would."""
         with trace_mod.span("hvd_tpu_engine_prefill",
                             args={"slot": int(slot), "imported": True,
-                                  "prompt_len": len(prompt)}):
+                                  "prompt_len": len(prompt),
+                                  "cache": self.kv_mode}):
             self._import_slot_kv(slot, prompt, k_blocks, v_blocks,
                                  first_token, sampling, rng)
 
     def _import_slot_kv(self, slot: int, prompt: Sequence[int],
                         k_blocks, v_blocks, first_token: int,
                         sampling: SamplingParams, rng) -> None:
+        if self.kv_mode == "state":
+            raise RuntimeError(
+                "a retention state has no migration frame yet: "
+                "import_slot_kv binds K/V blocks")
         if self.kv_mode != "paged":
             raise RuntimeError("KV import requires the paged cache "
                                "(HVD_TPU_SERVE_KV=paged)")
@@ -1168,11 +1339,23 @@ class InferenceEngine:
     # --- observability ------------------------------------------------------
 
     def kv_stats(self) -> Dict:
-        """JSON-ready paged-KV + speculative counters (merged into the
-        batcher's snapshot and the serving bench artifact)."""
+        """JSON-ready counters of the cache this engine holds, and the
+        speculative ones (merged into the batcher's snapshot and the
+        serving bench artifact): the paged pool's blocks, hits and
+        evictions; nothing for dense rows; for a retention state
+        ``state_bytes`` (all slots and layers, whatever the context),
+        ``state_slots_touched`` (slots whose state one decode step
+        reads and writes: all of them, rows without a request ride
+        along) and ``state_resets`` (prefills that began a slot's state
+        from zeros)."""
         out: Dict = {}
         if self._kv is not None:
             out.update(self._kv.stats())
+        if self._states is not None:
+            out["state_bytes"] = int(sum(
+                x.nbytes for x in jax.tree.leaves(self._states)))
+            out["state_slots_touched"] = self.max_slots
+            out["state_resets"] = self.state_resets
         if self._drafter is not None:
             steps = self.spec_verify_steps
             out["spec_verify_steps"] = steps

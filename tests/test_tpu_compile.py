@@ -196,3 +196,78 @@ def test_paged_decode_holds_no_pool_copy_on_v5e(topo):
     copies = re.findall(r"^.*= \w+\[%s\]\S* copy\(.*$" % shape, text, re.M)
     assert not copies, copies[:2]
     assert text.split("\n", 1)[0].count("-alias)") == 2
+
+
+def test_retention_step_kernel_compiles_for_v5e(topo):
+    """The decode step of power retention at the published widths of
+    the benchmark's ``brumby-14b``: 8 slots, 40 query / 8 KV heads of
+    128, one layer's float32 state (272 MB) updated in place."""
+    from horovod_tpu.ops import retention
+
+    one = SingleDeviceSharding(topo.devices[0])
+
+    def sds(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    s_shape, z_shape = retention.state_shapes(8, 8, 128)
+    compiled = jax.jit(
+        lambda q, k, v, g, S, z: retention.retention_step(
+            q, k, v, g, (S, z), interpret=False),
+        donate_argnums=(4, 5)).lower(
+            sds((8, 40, 128), jnp.bfloat16), sds((8, 8, 128), jnp.bfloat16),
+            sds((8, 8, 128), jnp.bfloat16), sds((8, 8)), sds(s_shape),
+            sds(z_shape)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "hvd_tpu_retention_step" in text
+    # In place: no second copy of the state, no temporary of its size.
+    memory = compiled.memory_analysis()
+    state_bytes = 4 * (np.prod(s_shape) + np.prod(z_shape))
+    assert memory.alias_size_in_bytes >= state_bytes
+    assert memory.temp_size_in_bytes < state_bytes // 16
+
+
+def test_state_decode_program_updates_its_states_in_place_on_v5e(
+        topo, monkeypatch):
+    """The engine's decode program over a retention state, at the
+    benchmark's ``brumby-14b`` head widths (40 query / 8 KV heads of
+    128, 8 slots; two layers, a small vocabulary and feed-forward), with
+    the step's kernel as the chip gets it: both layers' states are
+    donated and updated in place, and no temporary is of a state's
+    size."""
+    from horovod_tpu.models.transformer import (GPT, GPTConfig,
+                                                init_state_cache)
+    from horovod_tpu.ops import retention
+    from horovod_tpu.serve import InferenceEngine
+
+    step = retention.retention_step
+    monkeypatch.setattr(retention, "retention_step",
+                        lambda *a: step(*a, interpret=False))
+    model = GPT(GPTConfig(
+        vocab_size=512, n_layer=2, n_head=40, n_kv_head=8, head_dim=128,
+        d_model=640, d_ff=256, max_seq_len=4096, norm="rmsnorm",
+        positions="rope", qk_norm=True, mlp="swiglu", mixer="retention",
+        dtype=jnp.bfloat16, param_dtype=jnp.bfloat16))
+    params = jax.eval_shape(
+        lambda: model.init(jax.random.PRNGKey(0),
+                           jnp.zeros((1, 8), jnp.int32))["params"])
+    one = SingleDeviceSharding(topo.devices[0])
+
+    def described(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one), tree)
+
+    eng = InferenceEngine.__new__(InferenceEngine)
+    eng._model, eng.trace_counts = model, {"decode": 0}
+    states = jax.eval_shape(lambda: init_state_cache(model.config, 8))
+    i32, f32, flag = (described(jnp.zeros(8, dt))
+                      for dt in (jnp.int32, jnp.float32, jnp.bool_))
+    compiled = jax.jit(eng._decode_state_impl, donate_argnums=(1,)).lower(
+        described(params), described(states), i32, i32, flag, f32, i32,
+        described(jax.random.PRNGKey(0))).compile()
+    text = compiled.as_text()
+    assert text.count("hvd_tpu_retention_step") >= 2
+    memory = compiled.memory_analysis()
+    state_bytes = sum(int(np.prod(x.shape)) * 4
+                      for x in jax.tree.leaves(states))
+    assert memory.alias_size_in_bytes >= state_bytes
+    assert memory.temp_size_in_bytes < state_bytes // 16
