@@ -138,9 +138,6 @@ def init_kv_cache(config: GPTConfig, batch_size: int, max_len: int):
             for _ in range(config.n_layer)]
 
 
-_NEG_INF = -1e30  # additive mask value (matches parallel/ring_attention)
-
-
 def _tp_shard(cfg: GPTConfig, x, *spec):
     """Anchor ``x`` on the serving TP mesh (identity when unsharded).
     A bare ``_tp_shard(cfg, x)`` — empty spec — forces the all-gather
@@ -249,10 +246,6 @@ class Attention(nn.Module):
         q, k = _positioned(cfg, q, k, positions)
         proj = _dense(cfg, cfg.d_model, "out")
 
-        def per_query_head(x):
-            # Grouped KV heads: each is read by H / K query heads.
-            return x if K == H else jnp.repeat(x, H // K, axis=2)
-
         if cache is not None:
             # KV-cache path (serving prefill chunks and single-token
             # decode steps): write this chunk's K/V at its absolute
@@ -264,44 +257,67 @@ class Attention(nn.Module):
             #
             # Dense ``{"k", "v"}``: per-slot ``[B, S, H, D]`` rows.
             # Paged ``{"k_pool", "v_pool", "table"}``: one ``[num_blocks,
-            # block, K * D]`` pool per layer, written and gathered
-            # through the per-row block table (view row ``i`` is the
-            # token at position ``i`` of the row's chain; invalid
-            # positions reach the trash block by the table's last
-            # column).  Write before read, and heads in one row: each
-            # keeps a donated pool updated in place (docs/serving.md).
-            if "k_pool" in cache:
+            # block, row]`` pool per layer, a token's ``K * D`` numbers
+            # first in its row (the engine pads the row to whole vectors
+            # of 128 lanes), written through the per-row block table;
+            # invalid positions reach the trash block by the table's
+            # last column.  Write before read, and heads in one row:
+            # each keeps a donated pool updated in place
+            # (docs/serving.md).  What reads the pool is chosen by the
+            # chunk's shape.  One token a row (a decode step): the
+            # table is walked to each row's length, in one kernel on
+            # the TPU (``ops/paged_attention.py::paged_decode``); under
+            # tensor parallelism the step stays on the view
+            # (docs/tp_serving.md).  A chunk of several tokens
+            # (prefill buckets, a prefix hit's suffix, speculative
+            # verify): the gathered view — view row ``i`` is the token
+            # at position ``i`` of the row's chain — which for them is
+            # compute-shaped.
+            from ..ops import paged_attention
+
+            paged = "k_pool" in cache
+            if paged:
                 table = cache["table"]           # [B, n_cols] block ids
                 k_pool, v_pool = cache["k_pool"], cache["v_pool"]
-                block = k_pool.shape[1]
+                block, row = k_pool.shape[1:]
                 blk = jnp.take_along_axis(table, positions // block, axis=1)
                 off = positions % block
-                k_new = k_pool.at[blk, off].set(
-                    k.reshape(B, T, K * D).astype(k_pool.dtype))
-                v_new = v_pool.at[blk, off].set(
-                    v.reshape(B, T, K * D).astype(v_pool.dtype))
-                k_all = k_new[table].reshape(B, -1, K, D)
-                v_all = v_new[table].reshape(B, -1, K, D)
+
+                def rows(x):
+                    x = x.reshape(B, T, K * D).astype(k_pool.dtype)
+                    return x if row == K * D else jnp.pad(
+                        x, ((0, 0), (0, 0), (0, row - K * D)))
+
+                k_new = k_pool.at[blk, off].set(rows(k))
+                v_new = v_pool.at[blk, off].set(rows(v))
             else:
-                row = jnp.arange(B)[:, None]
-                k_new = k_all = cache["k"].at[row, positions].set(
+                at = jnp.arange(B)[:, None]
+                k_new = cache["k"].at[at, positions].set(
                     k.astype(cache["k"].dtype))
-                v_new = v_all = cache["v"].at[row, positions].set(
+                v_new = cache["v"].at[at, positions].set(
                     v.astype(cache["v"].dtype))
-            k_all, v_all = per_query_head(k_all), per_query_head(v_all)
-            S = k_all.shape[1]
-            scores = jnp.einsum("bqhd,bkhd->bhqk", q, k_all)
-            scores = scores.astype(jnp.float32) * (D ** -0.5)
-            visible = jnp.arange(S)[None, None, :] <= positions[:, :, None]
-            scores = jnp.where(visible[:, None], scores, _NEG_INF)
-            probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
-            out = jnp.einsum("bhqk,bkhd->bqhd", probs, v_all)
+            if paged and T == 1 and cfg.tp_mesh is None:
+                # The scope holds the attention alone; the projections
+                # are the block's, outside it.
+                with jax.named_scope("hvd_tpu_paged_attention"):
+                    out = paged_attention.paged_decode(
+                        q[:, 0], k_new, v_new, table, positions[:, 0],
+                        K)[:, None]
+            else:
+                k_all, v_all = ((paged_attention.gathered_view(
+                    x, table, K, D) for x in (k_new, v_new)) if paged
+                    else (k_new, v_new))
+                out = paged_attention.view_attention(q, k_all, v_all,
+                                                     positions)
             # Gather-before-contract: the ``out`` kernel is replicated
             # under TP, so the head outputs all-gather here and every
             # shard computes the full projection — bitwise identical.
             merged = _tp_shard(cfg, out.reshape(B, T, C))
             return proj(merged), {"k": k_new, "v": v_new}
-        k, v = per_query_head(k), per_query_head(v)
+        if K != H:
+            # Grouped KV heads: each is read by H / K query heads.
+            k = jnp.repeat(k, H // K, axis=2)
+            v = jnp.repeat(v, H // K, axis=2)
         if cfg.attention == "ring":
             if self.mesh is None:
                 raise ValueError("attention='ring' requires a mesh")

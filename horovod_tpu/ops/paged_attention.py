@@ -1,0 +1,287 @@
+"""Attention of one decode step over a paged KV cache.
+
+The serving engine keeps a layer's keys and values in a pool of blocks,
+``[num_blocks, block, K * D]`` (a token's ``K`` KV heads of ``D`` side
+by side in one row), and a request's chain of blocks in a row of the
+block table.  A decode step has one query token a row, at the row's own
+depth, and has to read each row's keys ``0 .. position``.
+
+The gathered *view* does that for any chunk: ``pool[table]`` laid out
+as ``[B, n_cols * block, K, D]``, scores over all of it, a mask.  Its
+cost is that of the table's width, whatever the rows hold, and for one
+query token a row the gather, the re-layout of 64-wide heads and the
+scores over dead positions were 23 of a 34 ms decode step of GPT-2 XL
+(PERF.md, PR 27).  :func:`view_attention` is that arithmetic; prefill
+chunks (``T > 1``) and the dense cache keep it.
+
+:func:`paged_decode` is the single-token case.  On the TPU it is one
+Pallas kernel a layer (``hvd_tpu_paged_decode``): the table and the
+positions go in as scalar prefetch, the pools stay in HBM, and for each
+row the kernel walks the row's table to ``position // block + 1``
+blocks, fetching them by asynchronous copy, several blocks a wave, the
+next wave in flight while the present one is scored (the pattern of
+``jax.experimental.pallas.ops.tpu.paged_attention``, whose pool layout
+and head sizes are not this repository's).  A row at position 0 (a slot
+without a request rides along so, on trash blocks) costs one block.
+
+**No head is sliced out of a pool row.**  The query goes in as a
+block-diagonal matrix ``[heads, K * D]`` — row ``h`` holds ``q[h]`` in
+the columns of its KV head and zero elsewhere, built once a row — so
+the scores of every head against a wave ``[n, K * D]`` are one MXU
+product, and the read-out is ``p [heads, n] . V [n, K * D]``, of which
+the block diagonal is kept at the end.  The wasted products are zeros.
+Grouped KV heads (``K < H``) are covered by the same matrix: the
+``H / K`` query heads of a group are rows that share a column block.
+bfloat16 inputs, float32 scores, softmax and sums, probabilities
+rounded to the pool's dtype before the product with V.
+
+Off the TPU :func:`paged_decode` runs the view's arithmetic in
+``jax.numpy``.  ``interpret`` is the tree-wide escape hatch of a kernel
+(True: the kernel under the interpreter, which is how the tests reach
+it; False: the kernel compiled wherever this runs, which is how it is
+compiled for a described chip).
+
+**What the kernel asks of the pool.**  Mosaic copies a block only if
+its last dimension is whole vectors of 128 lanes and its rows whole
+sublane tiles (16 rows of bfloat16, 8 of float32).  So the engine pads
+a pool row to a multiple of 128 (GPT-2 XL's 25 x 64 = 1,600 to 1,664:
+the bytes the chip's tiled layout gave the row anyway), a token's ``K *
+D`` numbers first; a pool that is not so shaped takes the view's
+arithmetic everywhere.
+"""
+
+from __future__ import annotations
+
+import functools
+from typing import Optional
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from .pallas_common import _LANES, _SUBLANES, round_up
+
+_NEG_INF = -1e30
+# Tokens fetched and scored together, one wave of block copies: at most
+# this many, and no more than fill _WAVE_BYTES.  Two waves of K and of
+# V are resident (4 x 128 x 1,664 x 2 B = 1.7 MB at GPT-2 XL's row).  A
+# wave is scored whole, so a short row pays for all of it: 128 against
+# 256 is 0.5 us less for a row of one block and 4 % more for rows of
+# 1,024 tokens (PERF.md, PR 27).
+_WAVE_TOKENS = 128
+_WAVE_BYTES = 1 << 20
+
+
+def view_attention(q, k_all, v_all, positions):
+    """Attention of ``q [B, T, H, D]`` at absolute ``positions [B, T]``
+    over per-row keys and values ``[B, S, K, D]`` (dense cache rows, or
+    a paged cache's gathered view): a query sees keys ``0 .. position``.
+    Scores rounded to ``q``'s dtype by the product, softmax in float32,
+    probabilities rounded to ``q``'s dtype.  Returns ``[B, T, H, D]``."""
+    H, K, D = q.shape[2], k_all.shape[2], q.shape[3]
+    if K != H:
+        # Grouped KV heads: each is read by H / K query heads.
+        k_all = jnp.repeat(k_all, H // K, axis=2)
+        v_all = jnp.repeat(v_all, H // K, axis=2)
+    S = k_all.shape[1]
+    scores = jnp.einsum("bqhd,bkhd->bhqk", q, k_all)
+    scores = scores.astype(jnp.float32) * (D ** -0.5)
+    visible = jnp.arange(S)[None, None, :] <= positions[:, :, None]
+    scores = jnp.where(visible[:, None], scores, _NEG_INF)
+    probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
+    return jnp.einsum("bhqk,bkhd->bqhd", probs, v_all)
+
+
+def gathered_view(pool, table, kv_heads: int, head_dim: int):
+    """``pool [num_blocks, block, row]`` through ``table [B, n_cols]``
+    as per-row keys or values ``[B, n_cols * block, K, D]``: view row
+    ``i`` is the token at position ``i`` of the row's chain.  A pool
+    row holds the ``K * D`` numbers of a token first and may be padded
+    beyond them (to whole vectors of 128 lanes)."""
+    B = table.shape[0]
+    return pool[table][..., :kv_heads * head_dim].reshape(
+        B, -1, kv_heads, head_dim)
+
+
+def _decode_view(q, k_pool, v_pool, table, positions, kv_heads):
+    D = q.shape[-1]
+    return view_attention(
+        q[:, None], gathered_view(k_pool, table, kv_heads, D),
+        gathered_view(v_pool, table, kv_heads, D), positions[:, None])[:, 0]
+
+
+def _sublane_tile(dtype) -> int:
+    return _SUBLANES * 4 // jnp.dtype(dtype).itemsize
+
+
+def _decode_kernel(table_ref, pos_ref, q_ref, k_hbm, v_hbm, o_ref,
+                   k_buf, v_buf, sem, first_ref, m_ref, l_ref, acc_ref, *,
+                   wave: int, groups: int, kv_rows: int, head_dim: int):
+    """One row of the batch: walk its table to its length."""
+    b = pl.program_id(0)
+    block = k_hbm.shape[1]
+    n = wave * block
+    D, Kp = head_dim, kv_rows
+    pos = pos_ref[b]
+    n_waves = (pos // block + wave) // wave
+
+    def copies(row, i, slot, start: bool):
+        # Wave ``i`` of ``row``'s blocks into buffer ``slot``; only the
+        # row's live blocks move.  A wait needs the copy's size and
+        # semaphore, not its source.
+        first_col = i * wave
+        live = jnp.minimum(pos_ref[row] // block + 1 - first_col, wave)
+
+        def one(w, _):
+            blk = table_ref[row, first_col + w] if start else 0
+            rows = pl.ds(pl.multiple_of(w * block, block), block)
+            for hbm, buf, s in ((k_hbm, k_buf, 0), (v_hbm, v_buf, 1)):
+                copy = pltpu.make_async_copy(
+                    hbm.at[blk], buf.at[slot, rows], sem.at[s, slot])
+                copy.start() if start else copy.wait()
+            return 0
+
+        jax.lax.fori_loop(0, live, one, 0)
+
+    @pl.when(b == 0)
+    def _():
+        # A wave's dead rows are multiplied by probabilities of exactly
+        # zero; what they hold must be a number.  After this they only
+        # ever hold pool rows.
+        v_buf[...] = jnp.zeros_like(v_buf)
+        first_ref[0] = 0
+        copies(0, 0, 0, True)
+
+    # The buffer of this row's first wave: the row before started it
+    # while it scored its own last wave.
+    first = first_ref[0]
+
+    # The block-diagonal query: row ``g * Kp + k`` is query head ``k * G
+    # + g`` in the columns of KV head ``k``.
+    head = jax.lax.broadcasted_iota(jnp.int32, (Kp, k_hbm.shape[2]), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (Kp, k_hbm.shape[2]), 1)
+    diagonal = (col >= head * D) & (col < (head + 1) * D)
+    q_rows = [jnp.where(diagonal, q_ref[0, g:g + 1, :].astype(jnp.float32),
+                        0.0).astype(k_buf.dtype) for g in range(groups)]
+    q_bd = q_rows[0] if groups == 1 else jnp.concatenate(q_rows, axis=0)
+
+    m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
+    l_ref[...] = jnp.zeros_like(l_ref)
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    def body(i, _):
+        slot = (first + i) % 2
+
+        @pl.when(i + 1 < n_waves)
+        def _():
+            copies(b, i + 1, 1 - slot, True)
+
+        @pl.when((i + 1 == n_waves) & (b + 1 < pl.num_programs(0)))
+        def _():
+            copies(b + 1, 0, 1 - slot, True)
+
+        copies(b, i, slot, False)
+        s = jax.lax.dot_general(
+            q_bd, k_buf[slot], (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * (D ** -0.5)   # [R, n]
+        at = i * n + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        s = jnp.where(at <= pos, s, _NEG_INF)
+        m = m_ref[:, :1]
+        m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        corr = jnp.exp(m - m_new)
+        l_ref[...] = jnp.broadcast_to(
+            l_ref[:, :1] * corr + jnp.sum(p, axis=-1, keepdims=True),
+            l_ref.shape)
+        acc_ref[...] = acc_ref[...] * corr + jax.lax.dot_general(
+            p.astype(v_buf.dtype), v_buf[slot], (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
+        return 0
+
+    jax.lax.fori_loop(0, n_waves, body, 0)
+    first_ref[0] = (first + n_waves) % 2
+
+    # Each head's own D columns of its row: the block diagonal, summed
+    # down the rows of a group into one pool-shaped row.
+    out = acc_ref[...] / l_ref[:, :1]
+    for g in range(groups):
+        mine = jnp.where(diagonal, out[g * Kp:(g + 1) * Kp], 0.0)
+        o_ref[0, g:g + 1, :] = jnp.sum(
+            mine, axis=0, keepdims=True).astype(o_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("kv_heads", "interpret"))
+def _decode_pallas(q, k_pool, v_pool, table, positions, kv_heads, *,
+                   interpret: bool):
+    # Jitted so that a model's layers, which call this with the same
+    # shapes, share one trace and one lowering of the kernel: lowering
+    # it anew for each of GPT-2 XL's 48 layers took a process 25 s.
+    B, H, D = q.shape
+    _, block, row = k_pool.shape
+    K, G = kv_heads, H // kv_heads
+    tokens = min(_WAVE_TOKENS,
+                 _WAVE_BYTES // (row * jnp.dtype(k_pool.dtype).itemsize))
+    wave = max(1, tokens // block)
+    # A group's K heads take whole tiles of the query matrix's rows.
+    Kp = round_up(K, _sublane_tile(k_pool.dtype))
+    R = G * Kp
+    # Query head k * G + g goes to group row g, laid out as a pool row.
+    q_g = q.reshape(B, K, G, D).swapaxes(1, 2).reshape(B, G, K * D)
+    q_g = jnp.pad(q_g.astype(k_pool.dtype),
+                  ((0, 0), (0, 0), (0, row - K * D)))
+    per_row = pl.BlockSpec((1, G, row), lambda b, table, pos: (b, 0, 0))
+    in_hbm = pl.BlockSpec(memory_space=pl.ANY)
+    out = pl.pallas_call(
+        functools.partial(_decode_kernel, wave=wave, groups=G, kv_rows=Kp,
+                          head_dim=D),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(B,),
+            in_specs=[per_row, in_hbm, in_hbm],
+            out_specs=per_row,
+            scratch_shapes=[
+                pltpu.VMEM((2, wave * block, row), k_pool.dtype),
+                pltpu.VMEM((2, wave * block, row), v_pool.dtype),
+                pltpu.SemaphoreType.DMA((2, 2)),
+                pltpu.SMEM((1,), jnp.int32),              # first buffer
+                pltpu.VMEM((R, _LANES), jnp.float32),     # running max
+                pltpu.VMEM((R, _LANES), jnp.float32),     # denominator
+                pltpu.VMEM((R, row), jnp.float32),        # numerator
+            ]),
+        out_shape=jax.ShapeDtypeStruct((B, G, row), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            # Rows in order: each starts the next one's first copies.
+            dimension_semantics=("arbitrary",)),
+        name="hvd_tpu_paged_decode",
+        interpret=interpret,
+    )(table.astype(jnp.int32),
+      # The kernel reads the table as far as the position says.
+      jnp.clip(positions.astype(jnp.int32), 0, table.shape[1] * block - 1),
+      q_g, k_pool, v_pool)
+    out = out[..., :K * D].reshape(B, G, K, D)
+    return out.swapaxes(1, 2).reshape(B, H, D)
+
+
+def paged_decode(q, k_pool, v_pool, table, positions, kv_heads: int, *,
+                 interpret: Optional[bool] = None):
+    """Attention of a single-token decode step over a paged cache.
+    ``q [B, H, D]``; ``k_pool``, ``v_pool [num_blocks, block, row]``
+    with this step's keys and values already written, a token's
+    ``kv_heads * D`` numbers first in its row; ``table [B, n_cols]``
+    block ids; ``positions [B]``: row ``b`` sees the tokens ``0 ..
+    positions[b]`` of its chain, token ``i`` in row ``i % block`` of
+    block ``table[b, i // block]``.  ``H`` is a multiple of
+    ``kv_heads``: grouped KV heads go through the kernel too.  On the
+    TPU the kernel, elsewhere the view's arithmetic; ``interpret``: see
+    the module's text.  A pool the kernel cannot copy by block — a row
+    that is not whole vectors of 128 lanes, a block that is not whole
+    sublane tiles — takes the view's arithmetic everywhere.  Returns
+    ``[B, H, D]`` in ``q``'s dtype."""
+    _, block, row = k_pool.shape
+    plain = interpret is None and jax.default_backend() != "tpu"
+    if plain or row % _LANES or block % _sublane_tile(k_pool.dtype):
+        return _decode_view(q, k_pool, v_pool, table, positions, kv_heads)
+    return _decode_pallas(q, k_pool, v_pool, table, positions,
+                          kv_heads=kv_heads, interpret=bool(interpret))

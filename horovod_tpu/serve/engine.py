@@ -81,6 +81,7 @@ from jax.sharding import NamedSharding, PartitionSpec
 from ..models.transformer import (GPT, cache_kinds, init_kv_cache,
                                   init_state_cache)
 from ..obs import trace as trace_mod
+from ..ops.pallas_common import _LANES, round_up
 from ..utils.logging import get_logger
 from .kv import BlockPool, TRASH_BLOCK
 
@@ -314,7 +315,18 @@ class InferenceEngine:
                     f"(1 trash + slots x blocks_per_slot) — active "
                     f"requests could deadlock on allocation")
             self.kv_blocks = budget
-            shape = (budget, self.kv_block, kv_heads * head_dim)
+            # A pool row holds a token's heads side by side, padded to
+            # whole vectors of 128 lanes: the decode kernel copies
+            # blocks out of the pool, and the chip copies only whole
+            # vectors (ops/paged_attention.py).  The chip's tiled
+            # layout pads a row so anyway, so the pad costs no memory
+            # there.  A head-sharded pool keeps heads alone in its row:
+            # a shard's part has to be whole heads, and the
+            # tensor-parallel step reads through the view.
+            self._kv_row = kv_heads * head_dim
+            shape = (budget, self.kv_block,
+                     self._kv_row if self.tp > 1
+                     else round_up(self._kv_row, _LANES))
 
             def _pool_zeros():
                 z = jnp.zeros(shape, model.config.dtype)
@@ -334,6 +346,9 @@ class InferenceEngine:
             self._table = np.full(
                 (self.max_slots, self.blocks_per_slot + 1),
                 TRASH_BLOCK, np.int32)
+            self.paged_decode_steps = 0
+            self.paged_live_blocks = 0
+            self.paged_view_blocks = 0
             self._copy_fn = jax.jit(
                 self._copy_impl,
                 donate_argnums=(0,) if self._donate else ())
@@ -488,9 +503,10 @@ class InferenceEngine:
         half of live KV migration (ONE compiled program: the block id
         is data, not shape)."""
         self.trace_counts["kv_import"] += 1  # trace-time only
-        return [{"k": pools[i]["k"].at[blk].set(
+        heads = self._kv_row        # the rest of a pool row is padding
+        return [{"k": pools[i]["k"].at[blk, :, :heads].set(
                      k[i].astype(pools[i]["k"].dtype)),
-                 "v": pools[i]["v"].at[blk].set(
+                 "v": pools[i]["v"].at[blk, :, :heads].set(
                      v[i].astype(pools[i]["v"].dtype))}
                 for i in range(self._model.config.n_layer)]
 
@@ -895,12 +911,12 @@ class InferenceEngine:
         active = [int(s) for s in np.nonzero(snap[0])[0]]
         if not active:
             return {}
-        with trace_mod.span("hvd_tpu_engine_decode",
-                            args={"active": len(active)}):
-            return self._step(active, snap)
+        args = {"active": len(active)}     # _step adds what it learns
+        with trace_mod.span("hvd_tpu_engine_decode", args=args):
+            return self._step(active, snap, args)
 
-    def _step(self, active: List[int],
-              snap: tuple) -> Dict[int, List[int]]:
+    def _step(self, active: List[int], snap: tuple,
+              span_args: dict) -> Dict[int, List[int]]:
         act, pos, temps, topks, last_tokens, spec = snap
         if self._drafter is not None and any(
                 spec[s] and temps[s] <= 0 for s in active):
@@ -915,6 +931,15 @@ class InferenceEngine:
         elif self.kv_mode == "paged":
             for s in active:
                 self._kv.ensure_writable(s, int(positions[s]), 1)
+            if self._tp_mesh is None:
+                # The decode step's attention walks each row's table to
+                # its length (ops/paged_attention.py); the gathered
+                # view would have read every column of every row.
+                live = int((positions // self.kv_block + 1).sum())
+                span_args["live_blocks"] = live
+                self.paged_decode_steps += 1
+                self.paged_live_blocks += live
+                self.paged_view_blocks += self._table.size
             nxt, self._pools = self._decode_fn(
                 self._params, self._pools, jnp.asarray(self._table),
                 jnp.asarray(last_tokens), jnp.asarray(positions),
@@ -1245,9 +1270,10 @@ class InferenceEngine:
         # pool stores a token's heads as one row.
         wire = (len(self._pools), len(chain), self.kv_block,
                 self._model.config.kv_heads, -1)
-        k = np.stack([np.asarray(p["k"][idx])
+        heads = self._kv_row        # the rest of a pool row is padding
+        k = np.stack([np.asarray(p["k"][idx][..., :heads])
                       for p in self._pools]).reshape(wire)
-        v = np.stack([np.asarray(p["v"][idx])
+        v = np.stack([np.asarray(p["v"][idx][..., :heads])
                       for p in self._pools]).reshape(wire)
         return len(chain), k, v
 
@@ -1342,8 +1368,15 @@ class InferenceEngine:
         """JSON-ready counters of the cache this engine holds, and the
         speculative ones (merged into the batcher's snapshot and the
         serving bench artifact): the paged pool's blocks, hits and
-        evictions; nothing for dense rows; for a retention state
-        ``state_bytes`` (all slots and layers, whatever the context),
+        evictions, and how far its decode steps walked the block table:
+        ``paged_decode_steps`` (decode steps whose attention walked
+        each row's table to its length; none under tensor parallelism
+        or while every step is a speculative verify, which read the
+        gathered view), ``paged_live_blocks`` (blocks walked, summed
+        over those steps and their rows; a row without a request walks
+        one) and ``paged_view_blocks`` (rows x table columns, what the
+        view would have read); nothing for dense rows; for a retention
+        state ``state_bytes`` (all slots and layers, whatever the context),
         ``state_slots_touched`` (slots whose state one decode step
         reads and writes: all of them, rows without a request ride
         along) and ``state_resets`` (prefills that began a slot's state
@@ -1351,6 +1384,9 @@ class InferenceEngine:
         out: Dict = {}
         if self._kv is not None:
             out.update(self._kv.stats())
+            out["paged_decode_steps"] = self.paged_decode_steps
+            out["paged_live_blocks"] = self.paged_live_blocks
+            out["paged_view_blocks"] = self.paged_view_blocks
         if self._states is not None:
             out["state_bytes"] = int(sum(
                 x.nbytes for x in jax.tree.leaves(self._states)))

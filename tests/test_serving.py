@@ -325,6 +325,96 @@ class TestPagedKV:
         assert r2.tokens == _greedy_reference(model, params, pre + [2], 3)
 
 
+    @staticmethod
+    def _through_the_kernel(monkeypatch):
+        """From here on the model's decode steps reach the paged decode
+        kernel through the interpreter; returns the list its calls are
+        counted in (one per layer and trace)."""
+        from horovod_tpu.ops import paged_attention
+
+        decode, calls = paged_attention.paged_decode, []
+
+        def interpreted(*args):
+            calls.append(args[1].shape)
+            return decode(*args, interpret=True)
+
+        monkeypatch.setattr(paged_attention, "paged_decode", interpreted)
+        return calls
+
+    def test_kernel_and_view_serve_the_same_tokens(self, model_and_params,
+                                                   monkeypatch):
+        """The engine's greedy tokens over a paged cache, decode steps
+        through the kernel (interpreted) and through the view: the same
+        through admissions at different depths, a prompt that fills
+        whole blocks, a release beside a running request and the slot
+        used again."""
+        model, params = model_and_params
+
+        def serve(eng):
+            greedy = SamplingParams(max_new_tokens=16)
+            a = [eng.start(0, [3, 1, 4, 1, 5], greedy)]
+            for _ in range(4):                  # slot 0 alone, into block 2
+                a.extend(eng.step()[0])
+            b = [eng.start(1, list(range(20, 36)), greedy)]  # two blocks
+            for _ in range(3):
+                toks = eng.step()
+                a.extend(toks[0])
+                b.extend(toks[1])
+            eng.release(0)                      # slot 1 runs on beside it
+            b.extend(eng.step()[1])
+            c = [eng.start(0, [9, 2, 6], greedy)]            # slot 0 again
+            for _ in range(5):
+                toks = eng.step()
+                b.extend(toks[1])
+                c.extend(toks[0])
+            return a, b, c
+
+        view = serve(_engine(model_and_params, kv_cache="paged",
+                             kv_block=8))
+        calls = self._through_the_kernel(monkeypatch)
+        eng = _engine(model_and_params, kv_cache="paged", kv_block=8)
+        assert serve(eng) == view
+        # One trace of the decode program, a kernel a layer, on a pool
+        # row of whole vectors; prefill chunks never come here.
+        assert calls == [eng._pools[0]["k"].shape] * model.config.n_layer
+        assert calls[0][-1] == 128
+        assert view[0] == _greedy_reference(model, params, [3, 1, 4, 1, 5],
+                                            len(view[0]))
+
+    def test_kv_stats_count_the_blocks_a_decode_step_walks(
+            self, model_and_params):
+        """Two slots, blocks of 8, a table of 4 + 1 columns: the view
+        would read 10 blocks a step.  A 5-token prompt decodes at
+        positions 5, 6, 7 (one block, and one for the idle row on the
+        trash block) and 8 (two, and one)."""
+        from horovod_tpu.obs import trace
+
+        trace.configure(enabled=True)
+        trace.clear()
+        eng = _engine(model_and_params, kv_cache="paged", kv_block=8)
+        eng.start(0, [3, 1, 4, 1, 5], SamplingParams(max_new_tokens=8))
+        stats = eng.kv_stats()
+        assert (stats["paged_decode_steps"], stats["paged_live_blocks"],
+                stats["paged_view_blocks"]) == (0, 0, 0)
+        for _ in range(4):
+            eng.step()
+        stats = eng.kv_stats()
+        assert stats["paged_decode_steps"] == 4
+        assert stats["paged_live_blocks"] == 2 + 2 + 2 + 3
+        assert stats["paged_view_blocks"] == 4 * 2 * 5
+        walked = [s["args"]["live_blocks"] for s in trace.snapshot()
+                  if s["name"] == "hvd_tpu_engine_decode"]
+        assert walked == [2, 2, 2, 3]
+        trace.clear()
+        # A dense cache walks no table and reports none of it.
+        dense = _engine(model_and_params, kv_cache="dense")
+        dense.start(0, [3, 1, 4], SamplingParams(max_new_tokens=4))
+        dense.step()
+        assert not any(k.startswith("paged_") for k in dense.kv_stats())
+        assert all("live_blocks" not in s["args"] for s in trace.snapshot()
+                   if s["name"] == "hvd_tpu_engine_decode")
+
+
 class TestPagedWriteThenRead:
     """ISSUE 25: the paged programs write the chunk's K/V through the
     block table first and attend over the written pool, so a donated
@@ -363,7 +453,9 @@ class TestPagedWriteThenRead:
                 i32(n), f32(n), i32(n), jnp.zeros(n, bool), rng), 1
         if name == "kv_copy":
             return eng._copy_impl, (pools, i32(), i32()), 0
-        block = jnp.zeros((len(pools),) + pools[0]["k"].shape[1:],
+        cfg = eng._model.config     # the wire's row: heads, no padding
+        block = jnp.zeros((len(pools), eng.kv_block,
+                           cfg.kv_heads * cfg.head_size),
                           pools[0]["k"].dtype)
         return eng._import_impl, (pools, i32(), block, block), 0
 

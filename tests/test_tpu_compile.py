@@ -157,21 +157,49 @@ def test_fused_matmul_allgather_compiles_for_v5e(topo):
         ((512, 1024), jnp.bfloat16), ((1024, 4096), jnp.bfloat16))
 
 
-def test_paged_decode_holds_no_pool_copy_on_v5e(topo):
+def test_paged_decode_kernel_compiles_for_v5e(topo):
+    """The decode step's attention over a paged cache at GPT-2 XL's
+    widths, the kernel alone: 8 rows, 25 heads of 64 in a pool row
+    padded to 1,664, 1025 blocks of 16, a table of 65 columns.  (A row
+    of 1,600 Mosaic refuses: it copies only whole vectors of 128.)"""
+    from horovod_tpu.ops import paged_attention
+
+    one = SingleDeviceSharding(topo.devices[0])
+
+    def sds(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    text = _compile(
+        lambda q, k, v, table, pos: paged_attention.paged_decode(
+            q, k, v, table, pos, 25, interpret=False),
+        sds((8, 25, 64)), sds((1025, 16, 1664)), sds((1025, 16, 1664)),
+        sds((8, 65), jnp.int32), sds((8,), jnp.int32))
+    assert "hvd_tpu_paged_decode" in text
+
+
+def test_paged_decode_holds_no_pool_copy_on_v5e(topo, monkeypatch):
     """The serving decode program at GPT-2 XL's attention widths (25
-    heads of 64, 8 slots, 1025 blocks of 16), pools donated: the chip's
-    compiler must update every KV pool in place.  The CPU's compiler
-    (tests/test_serving.py) sees the order of the write and the read;
-    only this one sees the layout: a pool kept as ``[blocks, block, H,
-    D]`` gets a device layout with the blocks in the lanes, the scatter
-    and the gather want the rows, and every program then converts each
-    pool on the way in and out — a whole-pool copy that was 55 % of the
-    device's time in serving (PERF.md, PR 25)."""
+    heads of 64, 8 slots, 1025 blocks of 16), pools donated, with the
+    step's kernel as the chip gets it: the chip's compiler must update
+    every KV pool in place, and nothing of the gathered view's shape is
+    left in the program.  The CPU's compiler (tests/test_serving.py)
+    sees the order of the write and the read; only this one sees the
+    layout: a pool kept as ``[blocks, block, H, D]`` gets a device
+    layout with the blocks in the lanes, the scatter and the gather
+    want the rows, and every program then converts each pool on the way
+    in and out — a whole-pool copy that was 55 % of the device's time
+    in serving (PERF.md, PR 25).  The view — the gather ``[8, 65, 16,
+    row]`` and its re-layout as ``[8, 1040, 25, 64]`` — was 23 of the
+    34 ms a decode step took after that (PERF.md, PR 27)."""
     import re
 
     from horovod_tpu.models.transformer import GPT, GPTConfig
+    from horovod_tpu.ops import paged_attention
     from horovod_tpu.serve import InferenceEngine
 
+    decode = paged_attention.paged_decode
+    monkeypatch.setattr(paged_attention, "paged_decode",
+                        lambda *a: decode(*a, interpret=False))
     model = GPT(GPTConfig(vocab_size=512, n_layer=1, n_head=25,
                           d_model=1600, d_ff=256, max_seq_len=1024))
     params = model.init(jax.random.PRNGKey(0),
@@ -192,10 +220,15 @@ def test_paged_decode_holds_no_pool_copy_on_v5e(topo):
         described(params), described(eng._pools),
         described(jnp.zeros((n, cols), jnp.int32)), i32, i32, f32, i32,
         described(jax.random.PRNGKey(0))).compile().as_text()
+    assert "hvd_tpu_paged_decode" in text
     shape = ",".join(str(d) for d in eng._pools[0]["k"].shape)
     copies = re.findall(r"^.*= \w+\[%s\]\S* copy\(.*$" % shape, text, re.M)
     assert not copies, copies[:2]
     assert text.split("\n", 1)[0].count("-alias)") == 2
+    row = eng._pools[0]["k"].shape[-1]
+    view = re.findall(r"^.*= \w+\[8,(?:1040,25,64|65,16,(?:1600|%d))\].*$"
+                      % row, text, re.M)
+    assert not view, view[:2]
 
 
 def test_retention_step_kernel_compiles_for_v5e(topo):
