@@ -12,7 +12,11 @@ The serving hot path is two compiled programs:
 * **decode** — ONE program for the whole slot batch: every active
   request advances per call, each slot at its own depth.  This is the
   continuous-batching property: admission never waits for the batch to
-  drain.
+  drain.  What it reads of the slots (last tokens, positions, active
+  flags, temperatures, top-k, the PRNG key) lives on the device and
+  the program advances it itself: the host uploads only what it
+  changed — a bind, a clear, a block table that moved — and a steady
+  step uploads nothing (docs/serving.md "The step protocol").
 
 Three caches live under this one API.  The model declares what each
 of its layers keeps (``models.transformer.cache_kinds``): keys and
@@ -71,6 +75,7 @@ from __future__ import annotations
 import collections
 import dataclasses
 import threading
+import time
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import jax
@@ -132,17 +137,45 @@ def _sample(logits, rng, temps, topks):
     return jnp.where(temps <= 0.0, greedy, sampled).astype(jnp.int32)
 
 
+def _advance(step, logits):
+    """What one decode step changes of the step state (traced; the tail
+    of all three decode programs): draw the chain's next subkey, sample
+    every row's token from ``logits [B, 1, V]``, and make it the row's
+    next input at the next position.  Rows without a request keep
+    theirs; ``active``, ``temps`` and ``topks`` are not returned, so
+    the program holds no copy of them."""
+    key, sub = jax.random.split(step["key"])
+    nxt = _sample(logits[:, -1].astype(jnp.float32), sub, step["temps"],
+                  step["topks"])
+    active = step["active"]
+    return {"key": key,
+            "tokens": jnp.where(active, nxt, step["tokens"]),
+            "positions": step["positions"] + active.astype(jnp.int32)}
+
+
+# What ``InferenceEngine._wake_runtime`` sends: never written to.
+_POKE = np.zeros(1, np.int32)
+_POKES = 8
+
+
+def _home(params):
+    """The sharding that commits fresh state to where ``params`` live,
+    or None (the default device, uncommitted) where they are not
+    replicated whole."""
+    leaves = jax.tree.leaves(params)
+    sharding = getattr(leaves[0], "sharding", None) if leaves else None
+    if sharding is None or not sharding.is_fully_replicated:
+        return None
+    return sharding
+
+
 def _beside(params, tree):
     """``tree`` (fresh KV state) committed to where ``params`` live.
     Left uncommitted, it comes back from the first compiled program
     committed to the weights' sharding, every later call then misses
     that program's cache entry, and the first prefill bucket compiles
     twice — the second time inside some request's TTFT."""
-    leaves = jax.tree.leaves(params)
-    sharding = getattr(leaves[0], "sharding", None) if leaves else None
-    if sharding is None or not sharding.is_fully_replicated:
-        return tree
-    return jax.device_put(tree, sharding)
+    return jax.device_put(tree, _home(params))
 
 
 class InferenceEngine:
@@ -262,6 +295,34 @@ class InferenceEngine:
         self._last_tokens = np.zeros(self.max_slots, np.int32)  # guarded-by: _slot_lock
         self._spec = np.zeros(self.max_slots, bool)            # guarded-by: _slot_lock
         self._prefix_hits = np.zeros(self.max_slots, np.int32)  # guarded-by: _slot_lock
+        # What a decode step reads of the slots lives on the device,
+        # beside the weights, and the decode program advances it (the
+        # token it sampled is the next input, the position the last
+        # plus one, the key the chain's next); the arrays above stay
+        # the host's mirror.  ``_slots_changed`` counts the mutations
+        # the device cannot derive (a bind, a clear); a step whose
+        # snapshot carries another count than the one last sent
+        # compares the snapshot with ``_device_slots``, the host's copy
+        # of what the device holds, and uploads the arrays that differ
+        # (``_slots_sent``, ``_device_slots``: batcher thread only).
+        # The key is committed from the start: an uncommitted first
+        # key makes every program build twice (PERF.md, PR 26).
+        self._slots_changed = 0                                # guarded-by: _slot_lock
+        self._state_home = (
+            NamedSharding(self._tp_mesh, PartitionSpec())
+            if self._tp_mesh is not None else _home(params))
+        self._step_state = {"key": self._to_device(
+            jax.random.PRNGKey(seed))}
+        self._device_slots = None
+        self._upload_slots(self._slot_arrays(self._slot_snapshot()))
+        self._slots_sent = 0
+        self.decode_steps = 0
+        self.step_state_uploads = 0    # the constructor's is not counted
+        self.staged_uploads = 0
+        self.runtime_pokes = 0
+        self._dispatch_took = 0.0
+        self._dispatch_usual = None
+        self._pokes = ()
         # Weight hot-swap state (serve/swap.py; docs/hot_swap.md): the
         # running version (the checkpoint step the params came from —
         # 0 for boot weights that never touched the store) and the
@@ -272,7 +333,6 @@ class InferenceEngine:
         self._weights_version = int(weights_version)  # guarded-by: _slot_lock
         self._staged_params = None                    # guarded-by: _slot_lock
         self._staged_version = None                   # guarded-by: _slot_lock
-        self._rng = jax.random.PRNGKey(seed)
         # Trace-time counters: the bounded-recompile contract is
         # testable (each jitted program bumps its key once per trace).
         self.trace_counts = collections.Counter()
@@ -346,6 +406,12 @@ class InferenceEngine:
             self._table = np.full(
                 (self.max_slots, self.blocks_per_slot + 1),
                 TRASH_BLOCK, np.int32)
+            # The device's copy and the bytes it was made from: the
+            # table goes up when it changed (a block boundary, an
+            # admission, a release), not every step.
+            self._table_sent = None
+            self._table_device = None
+            self.table_uploads = 0
             self.paged_decode_steps = 0
             self.paged_live_blocks = 0
             self.paged_view_blocks = 0
@@ -434,6 +500,69 @@ class InferenceEngine:
         ]
         return jax.tree_util.tree_unflatten(treedef, placed)
 
+    def _to_device(self, tree):
+        """Host arrays committed to where the weights live (replicated
+        over the tensor mesh of a TP replica): plain transfers, no
+        compiled program."""
+        return jax.device_put(tree, self._state_home)
+
+    @staticmethod
+    def _slot_arrays(snap: tuple) -> dict:
+        """A slot snapshot as the step state's five arrays: copies
+        nothing else holds, so a caller may write a row before it
+        sends them."""
+        act, pos, temps, topks, last_tokens, _, _ = snap
+        return {"tokens": last_tokens,
+                "positions": np.where(act, pos, 0).astype(np.int32),
+                "active": act, "temps": temps, "topks": topks}
+
+    def _upload_slots(self, slots: dict) -> int:
+        """Into the device's step state those of ``slots`` that differ
+        from what the device holds (``_device_slots``: all of them
+        while that is unknown); the key stays: only a program advances
+        it, only an import replaces it.  Returns how many transfers
+        that was.  Nothing mutates an array once it is sent: a
+        transfer may read its source later, or alias it."""
+        held = self._device_slots
+        send = {k: v for k, v in slots.items()
+                if held is None or not np.array_equal(v, held[k])}
+        if send:
+            self._step_state = dict(self._step_state,
+                                    **self._to_device(send))
+            self._device_slots = dict(held or {}, **send)
+        return len(send)
+
+    def _stage_bind(self, slot: int, n_prompt: int,
+                    sampling: SamplingParams) -> None:
+        """Between a prefill's dispatch and its token fence: send what
+        ``_bind_slot`` is about to change, but for the token still
+        being sampled, and the block table the prefill wrote, while
+        the device computes.  The first decode step then has one array
+        left to send, not five and a table, with the device waiting.
+        The next step compares again whatever happens until then (a
+        prefill that raises leaves the row to be sent back)."""
+        slots = self._slot_arrays(self._slot_snapshot())
+        del slots["tokens"]
+        slots["positions"][slot] = n_prompt
+        slots["active"][slot] = True
+        slots["temps"][slot] = sampling.temperature
+        slots["topks"][slot] = sampling.top_k
+        self.staged_uploads += self._upload_slots(slots)
+        self._slots_sent = None
+        if self.kv_mode == "paged":
+            self._device_table()
+
+    @property
+    def _rng(self):
+        """The PRNG key, on the device: every program that samples
+        takes it, splits it once and hands back the next (one chain, in
+        call order: prefill, decode, prefill, ...)."""
+        return self._step_state["key"]
+
+    @_rng.setter
+    def _rng(self, key):
+        self._step_state = dict(self._step_state, key=key)
+
     # --- paged-view geometry ------------------------------------------------
 
     @property
@@ -448,8 +577,9 @@ class InferenceEngine:
     def _make_prefill(self, L: int):
         model, n_layer = self._model, self._model.config.n_layer
 
-        def prefill(params, caches, tokens, length, slot, rng, temp, topk):
+        def prefill(params, caches, tokens, length, slot, key, temp, topk):
             self.trace_counts[f"prefill_{L}"] += 1  # trace-time only
+            key, rng = jax.random.split(key)
             positions = jnp.arange(L, dtype=jnp.int32)[None]
             row = init_kv_cache(model.config, 1, L)
             logits, row = model.apply({"params": params}, tokens,
@@ -466,18 +596,18 @@ class InferenceEngine:
             new = [{"k": write(caches[i]["k"], row[i]["k"]),
                     "v": write(caches[i]["v"], row[i]["v"])}
                    for i in range(n_layer)]
-            return token, new
+            return token, new, key
 
         return jax.jit(prefill, donate_argnums=self._donate)
 
-    def _decode_impl(self, params, caches, tokens, positions, temps,
-                     topks, rng):
+    def _decode_impl(self, params, caches, step):
+        # ``step``: the slots' device-resident state (``_step_state``);
+        # every decode program returns what it advanced (``_advance``).
         self.trace_counts["decode"] += 1  # trace-time only
         logits, new = self._model.apply(
-            {"params": params}, tokens[:, None], kv_caches=caches,
-            positions=positions[:, None])
-        nxt = _sample(logits[:, -1].astype(jnp.float32), rng, temps, topks)
-        return nxt, new
+            {"params": params}, step["tokens"][:, None], kv_caches=caches,
+            positions=step["positions"][:, None])
+        return new, _advance(step, logits)
 
     # --- compiled programs: paged tier --------------------------------------
 
@@ -515,12 +645,13 @@ class InferenceEngine:
         S, SV = self.max_seq_len, self._view_len
 
         def prefill(params, pools, table_row, tokens, start, length,
-                    rng, temp, topk):
+                    key, temp, topk):
             # ``start`` = resident-prefix length (the suffix's first
             # absolute position); ``length`` = real suffix tokens in
             # the L-padded chunk.  Both are traced values: one compiled
             # program per bucket regardless of hit depth.
             self.trace_counts[f"prefill_{L}"] += 1  # trace-time only
+            key, rng = jax.random.split(key)
             idx = jnp.arange(L, dtype=jnp.int32)
             valid = (idx < length) & (start + idx < S)
             # Invalid rows (padding, past the cache) take the view's
@@ -534,26 +665,24 @@ class InferenceEngine:
                                                 axis=0, keepdims=False)
             token = _sample(last[None].astype(jnp.float32), rng,
                             temp[None], topk[None])[0]
-            return token, new
+            return token, new, key
 
         return jax.jit(prefill, donate_argnums=self._donate)
 
-    def _decode_paged_impl(self, params, pools, tables, tokens,
-                           positions, temps, topks, rng):
+    def _decode_paged_impl(self, params, pools, tables, step):
         self.trace_counts["decode"] += 1  # trace-time only
         caches = self._paged_caches(pools, tables)
         logits, new = self._model.apply(
-            {"params": params}, tokens[:, None], kv_caches=caches,
-            positions=positions[:, None])
-        nxt = _sample(logits[:, -1].astype(jnp.float32), rng, temps, topks)
-        return nxt, new
+            {"params": params}, step["tokens"][:, None], kv_caches=caches,
+            positions=step["positions"][:, None])
+        return new, _advance(step, logits)
 
     # --- compiled programs: state tier --------------------------------------
 
     def _make_state_prefill(self, L: int):
         model = self._model
 
-        def prefill(params, states, tokens, start, length, slot, rng,
+        def prefill(params, states, tokens, start, length, slot, key,
                     temp, topk):
             # ``start`` = positions already in the slot's state (0 for a
             # new request, which therefore begins from zeros whatever
@@ -561,6 +690,7 @@ class InferenceEngine:
             # ``length`` = real tokens in the L-padded chunk.  Both are
             # traced: one program per bucket.
             self.trace_counts[f"prefill_{L}"] += 1  # trace-time only
+            key, rng = jax.random.split(key)
             idx = jnp.arange(L, dtype=jnp.int32)
             carried = start > 0
 
@@ -583,29 +713,29 @@ class InferenceEngine:
 
             return token, [{"s": write(st["s"], n["s"]),
                             "z": write(st["z"], n["z"])}
-                           for st, n in zip(states, new)]
+                           for st, n in zip(states, new)], key
 
         return jax.jit(prefill, donate_argnums=self._donate)
 
-    def _decode_state_impl(self, params, states, tokens, positions,
-                           active, temps, topks, rng):
-        # The dense step's arguments and ``active``: a row without a
-        # request leaves its state as it is (a dense row's stale keys
-        # hide behind the position mask; a state has no such mask).
+    def _decode_state_impl(self, params, states, step):
+        # A row without a request (the step state's ``active``) leaves
+        # its state as it is: a dense row's stale keys hide behind the
+        # position mask; a state has no such mask.
         self.trace_counts["decode"] += 1  # trace-time only
-        caches = [dict(st, valid=active[:, None]) for st in states]
+        caches = [dict(st, valid=step["active"][:, None]) for st in states]
         logits, new = self._model.apply(
-            {"params": params}, tokens[:, None], kv_caches=caches,
-            positions=positions[:, None])
-        nxt = _sample(logits[:, -1].astype(jnp.float32), rng, temps, topks)
-        return nxt, new
+            {"params": params}, step["tokens"][:, None], kv_caches=caches,
+            positions=step["positions"][:, None])
+        return new, _advance(step, logits)
 
     def _state_prefill(self, slot: int, seq: List[int],
-                       sampling: SamplingParams, span_args: dict) -> int:
+                       sampling: SamplingParams, span_args: dict,
+                       stage: bool = False) -> int:
         """``seq`` into ``slot``'s state from position 0, in
         bucket-sized chunks with the state carried (a prompt is one
         chunk; a resumed sequence may be longer than the largest
-        bucket).  Returns the token sampled after the last chunk."""
+        bucket).  Returns the token sampled after the last chunk;
+        ``stage`` sends the bind's arrays while it is being sampled."""
         top = self.prefill_buckets[-1]
         pos, n, token = 0, len(seq), None
         while pos < n:
@@ -614,13 +744,15 @@ class InferenceEngine:
             padded = np.zeros((1, L), np.int32)
             padded[0, :ns] = np.asarray(seq[pos:pos + ns], np.int32)
             span_args["bucket"] = L     # the last chunk's
-            token, self._states = self._prefill_fns[L](
+            token, self._states, self._rng = self._prefill_fns[L](
                 self._params, self._states, jnp.asarray(padded),
                 jnp.int32(pos), jnp.int32(ns), jnp.int32(slot),
-                self._next_rng(), jnp.float32(sampling.temperature),
+                self._rng, jnp.float32(sampling.temperature),
                 jnp.int32(sampling.top_k))
             pos += ns
         self.state_resets += 1
+        if stage:
+            self._stage_bind(slot, n, sampling)
         return int(token)
 
     # --- compiled programs: speculative tier --------------------------------
@@ -674,7 +806,7 @@ class InferenceEngine:
         return jnp.moveaxis(drafts[:self.spec_k], 0, 1), dcaches
 
     def _spec_verify_impl(self, params, pools, tables, tokens, draft,
-                          positions, temps, topks, spec_ok, rng):
+                          positions, temps, topks, spec_ok, key):
         """Verify the whole draft in one batched target forward.
 
         Chunk ``[t0, d1..dK]`` runs at positions ``p..p+K``; the
@@ -690,6 +822,7 @@ class InferenceEngine:
         ``spec_ok`` false (no opt-in, or temperature sampling) accept
         nothing and emit one plain-sampled token."""
         self.trace_counts["spec_verify"] += 1  # trace-time only
+        key, rng = jax.random.split(key)
         K = self.spec_k
         S, SV = self.max_seq_len, self._view_len
         chunk_toks = jnp.concatenate([tokens[:, None], draft], axis=1)
@@ -711,13 +844,9 @@ class InferenceEngine:
                                jnp.maximum(S - 1 - positions, 0))
         first = _sample(logits[:, 0], rng, temps, topks)
         out = greedy.at[:, 0].set(first)   # argmax already, unless temp>0
-        return out, accepted, new
+        return out, accepted, new, key
 
     # --- host-side slot API -------------------------------------------------
-
-    def _next_rng(self):
-        self._rng, sub = jax.random.split(self._rng)
-        return sub
 
     def bucket_for(self, prompt_len: int) -> int:
         for b in self.prefill_buckets:
@@ -773,6 +902,18 @@ class InferenceEngine:
         with self._slot_lock:
             return int(self._positions[slot]) >= self.max_seq_len
 
+    def _device_table(self):
+        """The block table on the device, sent again only when the
+        host's differs from the bytes last sent (``ensure_writable``,
+        ``begin_request`` and ``release`` change it: a row crosses a
+        block boundary one step in ``kv_block``)."""
+        if (self._table_sent is None
+                or not np.array_equal(self._table, self._table_sent)):
+            self._table_sent = self._table.copy()
+            self._table_device = self._to_device(self._table_sent)
+            self.table_uploads += 1
+        return self._table_device
+
     def _slot_snapshot(self):
         """Locked copy of the decode-relevant slot arrays: the step
         paths read ONE consistent view instead of racing router-thread
@@ -781,7 +922,8 @@ class InferenceEngine:
         with self._slot_lock:
             return (self._active.copy(), self._positions.copy(),
                     self._temps.copy(), self._topks.copy(),
-                    self._last_tokens.copy(), self._spec.copy())
+                    self._last_tokens.copy(), self._spec.copy(),
+                    self._slots_changed)
 
     # --- guarded slot-state mutation ----------------------------------------
     # The ONE place slot state changes (the hvdlint lock checker holds
@@ -799,11 +941,15 @@ class InferenceEngine:
             self._last_tokens[slot] = token    # first decode consumes it
             self._spec[slot] = bool(sampling.spec)
             self._prefix_hits[slot] = prefix_hit
+            self._slots_changed += 1
 
     def _advance_slot(self, slot: int, tokens: List[int]) -> None:
         with self._slot_lock:
             if not self._active[slot]:
                 return   # released concurrently (cancel): drop
+            # No mark: the decode program advanced the device's copy by
+            # the same token (a speculative step, which did not, says
+            # so itself).
             self._last_tokens[slot] = tokens[-1]
             self._positions[slot] += len(tokens)
 
@@ -815,6 +961,7 @@ class InferenceEngine:
             self._topks[slot] = 0
             self._spec[slot] = False
             self._prefix_hits[slot] = 0
+            self._slots_changed += 1
 
     # --- prefix sharing -----------------------------------------------------
 
@@ -857,7 +1004,8 @@ class InferenceEngine:
         if self.kv_mode == "state":
             hit = 0
             span_args["prefix_hit"] = 0
-            token = self._state_prefill(slot, prompt, sampling, span_args)
+            token = self._state_prefill(slot, prompt, sampling, span_args,
+                                        stage=True)
         elif self.kv_mode == "paged":
             hit = self._kv.begin_request(slot, prompt)
             ns = n - hit
@@ -867,12 +1015,13 @@ class InferenceEngine:
             padded[0, :ns] = np.asarray(prompt[hit:], np.int32)
             fn = self._prefill_fns[L]
             span_args.update(bucket=L, prefix_hit=hit)
-            token, self._pools = fn(
+            token, self._pools, self._rng = fn(
                 self._params, self._pools,
                 jnp.asarray(self._table[slot]), jnp.asarray(padded),
-                jnp.int32(hit), jnp.int32(ns), self._next_rng(),
+                jnp.int32(hit), jnp.int32(ns), self._rng,
                 jnp.float32(sampling.temperature),
                 jnp.int32(sampling.top_k))
+            self._stage_bind(slot, n, sampling)
             token = int(token)
             self._kv.index_prompt(slot, prompt)
         else:
@@ -882,11 +1031,12 @@ class InferenceEngine:
             padded[0, :n] = np.asarray(prompt, np.int32)
             fn = self._prefill_fns[L]
             span_args.update(bucket=L, prefix_hit=0)
-            token, self._caches = fn(
+            token, self._caches, self._rng = fn(
                 self._params, self._caches, jnp.asarray(padded),
-                jnp.int32(n), jnp.int32(slot), self._next_rng(),
+                jnp.int32(n), jnp.int32(slot), self._rng,
                 jnp.float32(sampling.temperature),
                 jnp.int32(sampling.top_k))
+            self._stage_bind(slot, n, sampling)
             token = int(token)
         if self._drafter is not None:
             # The drafter recomputes the full prompt (its dense cache
@@ -906,7 +1056,8 @@ class InferenceEngine:
         under speculative decoding).  Inactive rows ride along masked
         and write into the trash block.  A step with an active slot is
         one ``hvd_tpu_engine_decode`` span: the host's table building,
-        the dispatch, the device's work and the token fence."""
+        whatever it had to upload (``args.uploads``), the dispatch, the
+        device's work and the token fence."""
         snap = self._slot_snapshot()
         active = [int(s) for s in np.nonzero(snap[0])[0]]
         if not active:
@@ -917,47 +1068,85 @@ class InferenceEngine:
 
     def _step(self, active: List[int], snap: tuple,
               span_args: dict) -> Dict[int, List[int]]:
-        act, pos, temps, topks, last_tokens, spec = snap
+        act, pos, temps, topks, _, spec, changed = snap
         if self._drafter is not None and any(
                 spec[s] and temps[s] <= 0 for s in active):
             return self._step_spec(active, snap)
-        positions = np.where(act, pos, 0).astype(np.int32)
+        # A steady step uploads nothing: the device holds the slots'
+        # state as the last step left it, and the table as last sent.
+        uploads = 0
+        if changed != self._slots_sent:
+            uploads = self._upload_slots(self._slot_arrays(snap))
+            self._slots_sent = changed
+            self.step_state_uploads += bool(uploads)
         if self.kv_mode == "state":
-            nxt, self._states = self._decode_fn(
-                self._params, self._states,
-                jnp.asarray(last_tokens), jnp.asarray(positions),
-                jnp.asarray(act), jnp.asarray(temps), jnp.asarray(topks),
-                self._next_rng())
+            self._states, advanced = self._dispatch_decode(
+                self._params, self._states, self._step_state)
         elif self.kv_mode == "paged":
             for s in active:
-                self._kv.ensure_writable(s, int(positions[s]), 1)
+                self._kv.ensure_writable(s, int(pos[s]), 1)
             if self._tp_mesh is None:
                 # The decode step's attention walks each row's table to
                 # its length (ops/paged_attention.py); the gathered
                 # view would have read every column of every row.
-                live = int((positions // self.kv_block + 1).sum())
+                live = int((np.where(act, pos, 0) // self.kv_block
+                            + 1).sum())
                 span_args["live_blocks"] = live
                 self.paged_decode_steps += 1
                 self.paged_live_blocks += live
                 self.paged_view_blocks += self._table.size
-            nxt, self._pools = self._decode_fn(
-                self._params, self._pools, jnp.asarray(self._table),
-                jnp.asarray(last_tokens), jnp.asarray(positions),
-                jnp.asarray(temps), jnp.asarray(topks),
-                self._next_rng())
+            sent = self.table_uploads
+            table = self._device_table()
+            uploads += self.table_uploads - sent
+            self._pools, advanced = self._dispatch_decode(
+                self._params, self._pools, table, self._step_state)
         else:
-            nxt, self._caches = self._decode_fn(
-                self._params, self._caches,
-                jnp.asarray(last_tokens), jnp.asarray(positions),
-                jnp.asarray(temps), jnp.asarray(topks),
-                self._next_rng())
-        nxt = np.asarray(nxt)
+            self._caches, advanced = self._dispatch_decode(
+                self._params, self._caches, self._step_state)
+        self._step_state = dict(self._step_state, **advanced)
+        self.decode_steps += 1
+        span_args["uploads"] = uploads
+        self._wake_runtime(span_args)
+        # The fence: the state's tokens are what the step sampled.
+        nxt = np.asarray(advanced["tokens"])
+        held = self._device_slots
+        self._device_slots = dict(
+            held, tokens=nxt, positions=held["positions"] + held["active"])
         out = {}
         for s in active:
             toks = [int(nxt[s])]
             out[s] = toks
             self._advance_slot(s, toks)
         return out
+
+    def _dispatch_decode(self, *args):
+        """The decode program's dispatch, and how long the call took."""
+        t = time.perf_counter()
+        out = self._decode_fn(*args)
+        self._dispatch_took = time.perf_counter() - t
+        return out
+
+    def _wake_runtime(self, span_args: dict) -> None:
+        """After a dispatch that took far longer than they do.  On some
+        hosts the runtime, while little goes through it, answers every
+        call about a millisecond late — the dispatch, a transfer, the
+        fence — and a step that uploads nothing can stay there for a
+        whole request, where a step that uploaded seven arrays before
+        each dispatch left within three (PERF.md section 6, PR 30).  So
+        send it a few small transfers now, between the dispatch and
+        the fence: the device computes, the host would only wait, and
+        the step is no longer for them.  ``_dispatch_usual`` follows
+        the call's time down at once and up slowly, so a host that has
+        become slower for good stops being poked."""
+        took, usual = self._dispatch_took, self._dispatch_usual
+        if usual is None or took < usual:
+            self._dispatch_usual = took
+            return
+        self._dispatch_usual = usual + 0.05 * (min(took, 2 * usual) - usual)
+        if took > 1.8 * usual:
+            self._pokes = [self._to_device(_POKE) for _ in range(_POKES)]
+            self.runtime_pokes += 1
+            span_args["poked"] = True
 
     def _step_spec(self, active: List[int],
                    snap: tuple) -> Dict[int, List[int]]:
@@ -970,7 +1159,7 @@ class InferenceEngine:
         concurrent cancel between the two reads) and write into a
         just-released slot's chain."""
         K = self.spec_k
-        act, pos, temps, topks, last_tokens, spec = snap
+        act, pos, temps, topks, last_tokens, spec, _ = snap
         positions = np.where(act, pos, 0).astype(np.int32)
         for s in active:
             p = int(positions[s])
@@ -986,12 +1175,14 @@ class InferenceEngine:
             draft = jax.device_put(
                 np.asarray(draft),
                 NamedSharding(self._tp_mesh, PartitionSpec()))
-        out, accepted, self._pools = self._spec_verify_fn(
-            self._params, self._pools, jnp.asarray(self._table),
+        out, accepted, self._pools, self._rng = self._spec_verify_fn(
+            self._params, self._pools, self._device_table(),
             jnp.asarray(last_tokens), draft,
             jnp.asarray(positions), jnp.asarray(temps),
-            jnp.asarray(topks), jnp.asarray(spec_ok),
-            self._next_rng())
+            jnp.asarray(topks), jnp.asarray(spec_ok), self._rng)
+        # Rows advance here by what was accepted, on the host alone:
+        # the next plain step uploads them.
+        self._slots_sent = None
         out = np.asarray(out)
         accepted = np.asarray(accepted)
         result: Dict[int, List[int]] = {}
@@ -1138,11 +1329,11 @@ class InferenceEngine:
                 padded[0, :ns] = np.asarray(seq[pos:pos + ns], np.int32)
                 fn = self._prefill_fns[L]
                 span_args["bucket"] = L     # the last chunk's
-                _, self._pools = fn(
+                _, self._pools, self._rng = fn(
                     self._params, self._pools,
                     jnp.asarray(self._table[slot]),
                     jnp.asarray(padded), jnp.int32(pos),
-                    jnp.int32(ns), self._next_rng(),
+                    jnp.int32(ns), self._rng,
                     jnp.float32(sampling.temperature),
                     jnp.int32(sampling.top_k))
                 pos += ns
@@ -1154,13 +1345,13 @@ class InferenceEngine:
             padded[0, :n] = np.asarray(seq, np.int32)
             fn = self._prefill_fns[L]
             span_args["bucket"] = L
-            _, self._caches = fn(
+            _, self._caches, self._rng = fn(
                 self._params, self._caches, jnp.asarray(padded),
-                jnp.int32(n), jnp.int32(slot), self._next_rng(),
+                jnp.int32(n), jnp.int32(slot), self._rng,
                 jnp.float32(sampling.temperature),
                 jnp.int32(sampling.top_k))
         if rng is not None and not self.active_slots():
-            self._rng = jnp.asarray(np.asarray(rng, np.uint32))
+            self._rng = self._to_device(np.asarray(rng, np.uint32))
         if self._drafter is not None:
             # Mirror start(): the drafter recomputes the sequence (its
             # dense cache shares nothing) so speculative decode can
@@ -1335,7 +1526,7 @@ class InferenceEngine:
         # "prefix-directory hit landing on a decode replica" path.
         self._kv.index_prompt(slot, prompt)
         if rng is not None and not self.active_slots():
-            self._rng = jnp.asarray(np.asarray(rng, np.uint32))
+            self._rng = self._to_device(np.asarray(rng, np.uint32))
         if self._drafter is not None:
             # Mirror start(): the drafter recomputes the prompt (its
             # dense cache shares nothing) so speculative decode can
@@ -1367,7 +1558,16 @@ class InferenceEngine:
     def kv_stats(self) -> Dict:
         """JSON-ready counters of the cache this engine holds, and the
         speculative ones (merged into the batcher's snapshot and the
-        serving bench artifact): the paged pool's blocks, hits and
+        serving bench artifact).  For every cache how often a step had
+        to upload: ``decode_steps`` (plain decode steps),
+        ``step_state_uploads`` (those that sent some of the slots'
+        arrays again: a bind or a clear since the last step),
+        ``staged_uploads`` (arrays a prefill sent ahead of its bind,
+        while the device computed), ``runtime_pokes`` (steps whose
+        dispatch was slow enough to send the runtime small transfers
+        behind the device's work) and, where there is a block table,
+        ``table_uploads`` (times it was sent: it had changed).  The
+        paged pool's blocks, hits and
         evictions, and how far its decode steps walked the block table:
         ``paged_decode_steps`` (decode steps whose attention walked
         each row's table to its length; none under tensor parallelism
@@ -1381,9 +1581,13 @@ class InferenceEngine:
         reads and writes: all of them, rows without a request ride
         along) and ``state_resets`` (prefills that began a slot's state
         from zeros)."""
-        out: Dict = {}
+        out: Dict = {"decode_steps": self.decode_steps,
+                     "step_state_uploads": self.step_state_uploads,
+                     "staged_uploads": self.staged_uploads,
+                     "runtime_pokes": self.runtime_pokes}
         if self._kv is not None:
             out.update(self._kv.stats())
+            out["table_uploads"] = self.table_uploads
             out["paged_decode_steps"] = self.paged_decode_steps
             out["paged_live_blocks"] = self.paged_live_blocks
             out["paged_view_blocks"] = self.paged_view_blocks

@@ -19,6 +19,7 @@ import numpy as np
 import pytest
 
 import horovod_tpu as hvd
+import step_state_oracle
 from greedy_oracle import greedy_reference as _greedy_reference
 from horovod_tpu import faults
 from horovod_tpu.config import parse_fault_spec
@@ -440,8 +441,7 @@ class TestPagedWriteThenRead:
         params, pools = eng.params, eng._pools
         if name == "decode":
             return eng._decode_paged_impl, (
-                params, pools, i32(n, cols), i32(n), i32(n), f32(n),
-                i32(n), rng), 1
+                params, pools, i32(n, cols), eng._step_state), 1
         if name.startswith("prefill_"):
             L = int(name.split("_")[1])
             return eng._make_paged_prefill(L).__wrapped__, (
@@ -513,6 +513,273 @@ class TestPagedWriteThenRead:
                     np.asarray(now[name])[others], was[name][others])
         # ... and what the other blocks hold never reaches a query.
         assert toks == _greedy_reference(model, params, prompt, len(toks))
+
+
+@pytest.fixture(scope="module")
+def retention_model_and_params():
+    """A model whose layers keep a retention state: what the engine
+    serves from the ``state`` cache."""
+    cfg = GPTConfig(vocab_size=VOCAB, n_layer=2, n_head=4, n_kv_head=2,
+                    head_dim=16, d_model=32, d_ff=64, max_seq_len=32,
+                    norm="rmsnorm", positions="rope", qk_norm=True,
+                    mlp="swiglu", mixer="retention", dtype=jnp.float32,
+                    param_dtype=jnp.float32)
+    model = GPT(cfg)
+    params = model.init(jax.random.PRNGKey(0),
+                        jnp.zeros((1, 8), jnp.int32))["params"]
+    return model, params
+
+
+CACHES = ("dense", "paged", "state")
+
+
+class TestStepState:
+    """ISSUE 30: the decode step's inputs live on the device and the
+    decode program advances them; the host uploads only what it
+    changed, and the key is one chain split inside the programs, in
+    the order the parent split it on the host."""
+
+    PROMPTS = ([3, 1, 4, 1, 5], [9, 8, 7], list(range(10)))
+
+    @pytest.fixture
+    def engine_of(self, model_and_params, retention_model_and_params):
+        def build(cache, **kw):
+            kw.setdefault("seed", 11)
+            if cache == "state":
+                return _engine(retention_model_and_params, **kw)
+            if cache == "paged":
+                kw.setdefault("kv_block", 4)
+            return _engine(model_and_params, kv_cache=cache, **kw)
+
+        return build
+
+    @staticmethod
+    def _built_once(eng):
+        assert set(eng.trace_counts.values()) == {1}, eng.trace_counts
+        assert eng._decode_fn._cache_size() == 1
+        for name in eng.trace_counts:
+            if name.startswith("prefill_"):
+                L = int(name.split("_")[1])
+                assert eng._prefill_fns[L]._cache_size() == 1, name
+
+    @pytest.mark.parametrize("cache", CACHES)
+    def test_device_state_is_the_mirror_through_a_lifecycle(
+            self, engine_of, cache):
+        eng = engine_of(cache)
+        step_state_oracle.drive_lifecycle(eng, self.PROMPTS)
+        assert eng.trace_counts["decode"] == 1
+        assert {"prefill_8", "prefill_16"} <= set(eng.trace_counts)
+        self._built_once(eng)
+
+    def test_device_state_after_an_import_and_a_speculative_step(
+            self, model_and_params, engine_of):
+        """The two mutations only a paged cache has: blocks bound from
+        the wire stand where a prefill would, and a speculative step
+        advances rows on the host alone (its programs are its own)."""
+        src = engine_of("paged")
+        eng = engine_of("paged", drafter=model_and_params, spec_k=2)
+        sp = SamplingParams(max_new_tokens=20, temperature=0.8)
+        prompt = self.PROMPTS[0]
+        first = src.start(0, prompt, sp)
+        _, k, v = src.export_slot_kv(0)
+        eng.import_slot_kv(0, prompt, k, v, first, sp,
+                           rng=src.export_rng())
+        np.testing.assert_array_equal(eng.export_rng(), src.export_rng())
+        want = [src.step()[0][0] for _ in range(3)]
+        got = [step_state_oracle.step(eng)[0][0] for _ in range(3)]
+        assert got == want
+        eng.start(1, self.PROMPTS[1],
+                  SamplingParams(max_new_tokens=20, spec=True))
+        sent = eng.kv_stats()["step_state_uploads"]
+        out = eng.step()                        # draft and verify
+        assert eng.trace_counts["spec_verify"] == 1
+        assert len(out[0]) == 1 and len(out[1]) >= 1
+        assert eng.kv_stats()["step_state_uploads"] == sent
+        eng.release(1)
+        step_state_oracle.step(eng)             # plain again: uploads
+        assert eng.kv_stats()["step_state_uploads"] == sent + 1
+        self._built_once(eng)
+
+    @pytest.mark.parametrize("cache", CACHES)
+    def test_tokens_and_key_are_the_host_carried_chain(
+            self, engine_of, model_and_params, retention_model_and_params,
+            cache):
+        """The parent carried the key on the host: ``key, sub =
+        split(key)`` before every prefill and every decode step, in
+        call order.  A plain loop that does so, sampling from
+        cache-free full forwards, reproduces the engine's tokens for
+        greedy, temperature and top-k requests across admissions, and
+        after every call the engine's key is the chain's."""
+        from horovod_tpu.serve.engine import _sample
+
+        model, params = (retention_model_and_params if cache == "state"
+                         else model_and_params)
+        eng = engine_of(cache, max_slots=3)
+        forward = jax.jit(lambda t: model.apply({"params": params}, t))
+
+        def logits_after(seq):
+            padded = np.zeros((1, model.config.max_seq_len), np.int32)
+            padded[0, :len(seq)] = seq
+            return np.asarray(forward(padded)[0, len(seq) - 1], np.float32)
+
+        chain = {"key": jax.random.PRNGKey(11), "calls": 0}
+
+        def next_sub():
+            chain["key"], sub = jax.random.split(chain["key"])
+            chain["calls"] += 1
+            return sub
+
+        seqs, temps, topks = {}, np.zeros(3, np.float32), np.zeros(3, np.int32)
+
+        def start(slot, prompt, sp):
+            got = eng.start(slot, prompt, sp)
+            want = int(_sample(logits_after(prompt)[None], next_sub(),
+                               jnp.float32(sp.temperature)[None],
+                               jnp.int32(sp.top_k)[None])[0])
+            assert got == want, (slot, got, want)
+            seqs[slot] = list(prompt) + [got]
+            temps[slot], topks[slot] = sp.temperature, sp.top_k
+            np.testing.assert_array_equal(eng.export_rng(), chain["key"])
+
+        def step():
+            got = eng.step()
+            logits = np.zeros((3, VOCAB), np.float32)
+            for slot, seq in seqs.items():
+                logits[slot] = logits_after(seq)
+            want = np.asarray(_sample(jnp.asarray(logits), next_sub(),
+                                      jnp.asarray(temps),
+                                      jnp.asarray(topks)))
+            assert got == {s: [int(want[s])] for s in seqs}, chain["calls"]
+            for slot in seqs:
+                seqs[slot].append(int(want[slot]))
+            np.testing.assert_array_equal(eng.export_rng(), chain["key"])
+
+        start(0, self.PROMPTS[0], SamplingParams(max_new_tokens=20))
+        step()
+        step()
+        start(2, self.PROMPTS[1], SamplingParams(
+            max_new_tokens=20, temperature=0.9, top_k=20))
+        step()
+        step()
+        start(1, self.PROMPTS[2], SamplingParams(
+            max_new_tokens=20, temperature=1.2))
+        for _ in range(3):
+            step()
+        eng.release(0)
+        del seqs[0]
+        temps[0] = topks[0] = 0
+        step()
+        start(0, [5, 5, 5], SamplingParams(max_new_tokens=20,
+                                           temperature=0.7, top_k=5))
+        step()
+        assert chain["calls"] == 4 + 9      # prefills and decode steps
+
+    @pytest.mark.parametrize("cache", CACHES)
+    def test_a_staged_bind_that_never_binds_is_sent_back(
+            self, engine_of, cache):
+        """An admission sends its row behind the prefill, before the
+        token fence.  Should the prefill raise there, the device holds
+        an active row the host never bound: the next step compares,
+        and sends the mirrors' row back."""
+        eng = engine_of(cache, max_slots=3)
+        sp = SamplingParams(max_new_tokens=20)
+        eng.start(0, self.PROMPTS[0], sp)
+        step_state_oracle.step(eng)
+        eng._stage_bind(2, 7, SamplingParams(max_new_tokens=20,
+                                             temperature=0.5, top_k=3))
+        device = jax.device_get(eng._step_state)
+        assert device["active"][2] and device["positions"][2] == 7
+        assert eng.free_slots() == [1, 2]       # the host bound nothing
+        out = step_state_oracle.step(eng)       # device == mirrors again
+        assert sorted(out) == [0]
+        assert not jax.device_get(eng._step_state["active"])[2]
+        self._built_once(eng)
+
+    def test_a_slow_dispatch_pokes_the_runtime_and_a_slow_host_does_not(
+            self, engine_of):
+        """A dispatch that took far longer than they do sends the
+        runtime small transfers behind the device's work (and says so
+        on the span); the usual time follows a faster call at once and
+        a slower host slowly, so a host that stays slow is poked for a
+        few steps and then left alone."""
+        eng = engine_of("dense")
+        eng.start(0, [1, 2, 3], SamplingParams(max_new_tokens=40))
+        eng.step()                      # the first dispatch builds
+        eng._dispatch_usual = None
+        args = {}
+        for took in (1.0e-3, 1.2e-3, 0.9e-3, 1.1e-3):
+            eng._dispatch_took = took
+            eng._wake_runtime(args)
+        assert eng._dispatch_usual == pytest.approx(0.91e-3, rel=0.02)
+        assert eng.runtime_pokes == 0 and "poked" not in args
+        eng._dispatch_took = 2.4e-3                 # the slow mode
+        eng._wake_runtime(args)
+        assert eng.runtime_pokes == 1 and args["poked"]
+        assert len(eng._pokes) == 8
+        jax.block_until_ready(eng._pokes)
+        for _ in range(40):                         # a host slower for good
+            eng._dispatch_took = 2.4e-3
+            eng._wake_runtime({})
+        assert eng.runtime_pokes < 20
+        assert eng._dispatch_usual > 1.4e-3
+        before = eng.runtime_pokes
+        out = step_state_oracle.step(eng)           # and steps go on
+        assert sorted(out) == [0]
+        assert eng.kv_stats()["runtime_pokes"] >= before
+
+    def test_uploads_follow_what_the_host_changed(self, engine_of):
+        """A steady step uploads nothing.  An admission sends what it
+        binds behind its prefill (``staged_uploads``) and leaves its
+        first step the sampled token alone; a finish costs the step
+        after it the arrays it changed; the table goes up when a row
+        crossed into a new block, once per ``kv_block`` positions a
+        row."""
+        from horovod_tpu.obs import trace
+
+        trace.configure(enabled=True)
+        trace.clear()
+        eng = engine_of("paged", max_slots=3)
+        sp = SamplingParams(max_new_tokens=30)
+        eng.start(0, [1, 2, 3], sp)             # positions 3 ..
+        eng.start(1, [4, 5, 6, 7, 8], sp)       # positions 5 ..
+        eng.step()
+        s0 = eng.kv_stats()
+        assert (s0["decode_steps"], s0["step_state_uploads"]) == (1, 1)
+        assert s0["staged_uploads"] == 4    # positions and active, twice
+        K = 12
+        for _ in range(K):
+            eng.step()
+        s1 = eng.kv_stats()
+        assert s1["decode_steps"] == 1 + K
+        assert s1["step_state_uploads"] == 1            # steady: none
+        # Two rows over 12 positions at 4 a block: three boundaries each.
+        assert 1 <= s1["table_uploads"] - s0["table_uploads"] <= 2 * (K // 4)
+        eng.start(2, [9, 9], sp)                        # an admission
+        eng.step()
+        assert eng.kv_stats()["step_state_uploads"] == 2
+        assert eng.kv_stats()["staged_uploads"] == 6
+        eng.release(0)                                  # a finish
+        eng.step()
+        eng.step()
+        assert eng.kv_stats()["step_state_uploads"] == 3
+        uploads = [s["args"]["uploads"] for s in trace.snapshot()
+                   if s["name"] == "hvd_tpu_engine_decode"]
+        trace.clear()
+        assert len(uploads) == 1 + K + 3
+        assert uploads[0] == 1                  # the tokens alone
+        assert uploads.count(0) >= K - 2 * (K // 4)
+        assert set(uploads[1:1 + K]) <= {0, 1}  # the table alone
+        # The admission's token; row 0 crossed a block with it.
+        assert uploads[1 + K] == 2
+        assert uploads[2 + K] == 3              # active, positions, table
+        assert uploads[-1] <= 1                 # steady again
+        # A cache without a table counts none.
+        dense = engine_of("dense")
+        dense.start(0, [1, 2, 3], sp)
+        dense.step()
+        dense.step()
+        assert "table_uploads" not in dense.kv_stats()
+        assert dense.kv_stats()["step_state_uploads"] == 1
 
 
 class TestBlockPoolUnit:
@@ -820,13 +1087,17 @@ class TestBatcher:
 
     def test_stop_token_ends_generation(self, model_and_params):
         model, params = model_and_params
-        ref = _greedy_reference(model, params, [7, 8], 8)
-        stop = ref[2]
+        prompt = [3, 14, 15, 92, 6]
+        ref = _greedy_reference(model, params, prompt, 8)
+        # A stop token the answer has not held before: one that stood
+        # earlier would end the generation there ([7, 8] answers 42
+        # eight times over).
+        at = next(i for i in range(2, len(ref)) if ref[i] not in ref[:i])
         b = _batcher(model_and_params)
-        r = b.submit([7, 8], SamplingParams(max_new_tokens=8,
-                                            stop_token=stop))
+        r = b.submit(prompt, SamplingParams(max_new_tokens=8,
+                                            stop_token=ref[at]))
         _pump(b, [r])
-        assert r.tokens == ref[:3]   # stop token included, then ends
+        assert r.tokens == ref[:at + 1]   # stop token included, then ends
 
     def test_boundary_length_prompt_rejected_at_submit(self,
                                                        model_and_params):

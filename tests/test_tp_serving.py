@@ -15,6 +15,7 @@ import numpy as np
 import pytest
 from jax.sharding import PartitionSpec as P
 
+import step_state_oracle
 from horovod_tpu.models.transformer import GPT, GPTConfig
 from horovod_tpu.plan import tp_owned_slice, tp_param_spec, tp_plan
 from horovod_tpu.serve import (
@@ -202,6 +203,29 @@ class TestTokenIdentityOracle:
 
     def test_speculative_batch_identity(self, model_and_params):
         self._spec_identity(model_and_params, (1, 2))
+
+    def test_step_state_lifecycle_identity(self, model_and_params):
+        """ISSUE 30: the slots' step state lives on the device,
+        replicated over the tensor mesh, and the decode program
+        advances it.  Through admissions, steady steps, a finish, a
+        release from another thread, a preemption and its resume the
+        state read back equals the host's mirrors after every step, at
+        both degrees; the tokens (greedy, temperature, top-k) and the
+        key are TP=1's, and no program is built twice."""
+        prompts = ([3, 1, 4, 1, 5], [9, 8, 7], list(range(10)))
+        outs = {}
+        for tp in (1, 2):
+            eng = _engine(model_and_params, tp=tp, seed=11)
+            served = step_state_oracle.drive_lifecycle(eng, prompts)
+            assert set(eng.trace_counts.values()) == {1}, eng.trace_counts
+            assert eng._decode_fn._cache_size() == 1
+            for leaf in eng._step_state.values():
+                assert leaf.committed
+                assert len(leaf.sharding.device_set) == tp
+                assert leaf.sharding.is_fully_replicated
+            outs[tp] = ([toks for _, _, toks in served],
+                        eng.export_rng().tolist())
+        assert outs[2] == outs[1], outs
 
     @pytest.mark.slow
     def test_speculative_batch_identity_tp4(self, model_and_params):

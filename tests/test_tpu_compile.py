@@ -214,12 +214,10 @@ def test_paged_decode_holds_no_pool_copy_on_v5e(topo, monkeypatch):
             a.shape, a.dtype, sharding=one), tree)
 
     n, cols = eng.max_slots, eng.blocks_per_slot + 1
-    i32, f32 = (described(jnp.zeros(n, dt))
-                for dt in (jnp.int32, jnp.float32))
     text = jax.jit(eng._decode_paged_impl, donate_argnums=(1,)).lower(
         described(params), described(eng._pools),
-        described(jnp.zeros((n, cols), jnp.int32)), i32, i32, f32, i32,
-        described(jax.random.PRNGKey(0))).compile().as_text()
+        described(jnp.zeros((n, cols), jnp.int32)),
+        described(eng._step_state)).compile().as_text()
     assert "hvd_tpu_paged_decode" in text
     shape = ",".join(str(d) for d in eng._pools[0]["k"].shape)
     copies = re.findall(r"^.*= \w+\[%s\]\S* copy\(.*$" % shape, text, re.M)
@@ -292,11 +290,13 @@ def test_state_decode_program_updates_its_states_in_place_on_v5e(
     eng = InferenceEngine.__new__(InferenceEngine)
     eng._model, eng.trace_counts = model, {"decode": 0}
     states = jax.eval_shape(lambda: init_state_cache(model.config, 8))
-    i32, f32, flag = (described(jnp.zeros(8, dt))
+    i32, f32, flag = (jnp.zeros(8, dt)
                       for dt in (jnp.int32, jnp.float32, jnp.bool_))
+    slots = {"tokens": i32, "positions": i32, "active": flag, "temps": f32,
+             "topks": i32, "key": jax.random.PRNGKey(0)}
     compiled = jax.jit(eng._decode_state_impl, donate_argnums=(1,)).lower(
-        described(params), described(states), i32, i32, flag, f32, i32,
-        described(jax.random.PRNGKey(0))).compile()
+        described(params), described(states),
+        described(slots)).compile()
     text = compiled.as_text()
     assert text.count("hvd_tpu_retention_step") >= 2
     memory = compiled.memory_analysis()
