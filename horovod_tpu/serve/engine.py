@@ -67,7 +67,9 @@ up, in ``serve/router.py`` over process sets.
 
 Sampling is greedy / temperature / top-k, resolved **per slot** inside
 the one decode program (a ``where`` lattice, not a recompile), so mixed
-sampling configs batch together.
+sampling configs batch together.  What only sampled rows need — the
+ranking of the vocabulary, the draw — sits behind a ``lax.cond`` on
+whether a row asks for it: a step of greedy rows is an argmax.
 """
 
 from __future__ import annotations
@@ -123,18 +125,35 @@ class SamplingParams:
     spec: bool = False
 
 
-def _sample(logits, rng, temps, topks):
+def _sample(logits, rng, temps, topks, rows=None):
     """Per-row sampling over ``[B, V]`` float32 logits: greedy rows
     (``temp <= 0``) take argmax; the rest draw from temperature-scaled
     logits restricted to each row's top-k (k per row — ranks against a
-    per-row threshold instead of a static ``lax.top_k`` width)."""
-    greedy = jnp.argmax(logits, axis=-1)
-    ranks = jnp.argsort(jnp.argsort(-logits, axis=-1), axis=-1)
-    k = jnp.where(topks > 0, topks, logits.shape[-1])[:, None]
-    masked = jnp.where(ranks < k, logits, -jnp.inf)
-    scaled = masked / jnp.maximum(temps, 1e-6)[:, None]
-    sampled = jax.random.categorical(rng, scaled, axis=-1)
-    return jnp.where(temps <= 0.0, greedy, sampled).astype(jnp.int32)
+    per-row threshold instead of a static ``lax.top_k`` width).
+
+    The program pays for what the rows in front of it ask for, and
+    decides on the device (``lax.cond``, one program): with no sampled
+    row among ``rows`` (all of them when None) it is the argmax alone;
+    the two sorts of the vocabulary that rank it run only where a
+    sampled row restricts its draw (``k = V`` masks nothing).  A row
+    outside ``rows`` may come back with either token: its caller drops
+    it.  ``rng`` is the caller's, split whatever the rows ask."""
+    greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    asks = temps > 0.0 if rows is None else rows & (temps > 0.0)
+
+    def draw():
+        def ranked():
+            ranks = jnp.argsort(jnp.argsort(-logits, axis=-1), axis=-1)
+            k = jnp.where(topks > 0, topks, logits.shape[-1])[:, None]
+            return jnp.where(ranks < k, logits, -jnp.inf)
+
+        masked = jax.lax.cond(jnp.any(asks & (topks > 0)), ranked,
+                              lambda: logits)
+        scaled = masked / jnp.maximum(temps, 1e-6)[:, None]
+        sampled = jax.random.categorical(rng, scaled, axis=-1)
+        return jnp.where(temps <= 0.0, greedy, sampled).astype(jnp.int32)
+
+    return jax.lax.cond(jnp.any(asks), draw, lambda: greedy)
 
 
 def _advance(step, logits):
@@ -142,12 +161,13 @@ def _advance(step, logits):
     of all three decode programs): draw the chain's next subkey, sample
     every row's token from ``logits [B, 1, V]``, and make it the row's
     next input at the next position.  Rows without a request keep
-    theirs; ``active``, ``temps`` and ``topks`` are not returned, so
+    theirs, and cannot make the step sample with a temperature left
+    behind; ``active``, ``temps`` and ``topks`` are not returned, so
     the program holds no copy of them."""
     key, sub = jax.random.split(step["key"])
-    nxt = _sample(logits[:, -1].astype(jnp.float32), sub, step["temps"],
-                  step["topks"])
     active = step["active"]
+    nxt = _sample(logits[:, -1].astype(jnp.float32), sub, step["temps"],
+                  step["topks"], rows=active)
     return {"key": key,
             "tokens": jnp.where(active, nxt, step["tokens"]),
             "positions": step["positions"] + active.astype(jnp.int32)}
@@ -317,6 +337,7 @@ class InferenceEngine:
         self._upload_slots(self._slot_arrays(self._slot_snapshot()))
         self._slots_sent = 0
         self.decode_steps = 0
+        self.sampling_steps = 0
         self.step_state_uploads = 0    # the constructor's is not counted
         self.staged_uploads = 0
         self.runtime_pokes = 0
@@ -1105,6 +1126,10 @@ class InferenceEngine:
                 self._params, self._caches, self._step_state)
         self._step_state = dict(self._step_state, **advanced)
         self.decode_steps += 1
+        # Whether the program's sampling branch ran, from the mirror.
+        sampling = int((act & (temps > 0)).any())
+        self.sampling_steps += sampling
+        span_args["sampling"] = sampling
         span_args["uploads"] = uploads
         self._wake_runtime(span_args)
         # The fence: the state's tokens are what the step sampled.
@@ -1560,6 +1585,8 @@ class InferenceEngine:
         speculative ones (merged into the batcher's snapshot and the
         serving bench artifact).  For every cache how often a step had
         to upload: ``decode_steps`` (plain decode steps),
+        ``sampling_steps`` (those that held a request with a
+        temperature, so that the program ranked or drew at all),
         ``step_state_uploads`` (those that sent some of the slots'
         arrays again: a bind or a clear since the last step),
         ``staged_uploads`` (arrays a prefill sent ahead of its bind,
@@ -1582,6 +1609,7 @@ class InferenceEngine:
         along) and ``state_resets`` (prefills that began a slot's state
         from zeros)."""
         out: Dict = {"decode_steps": self.decode_steps,
+                     "sampling_steps": self.sampling_steps,
                      "step_state_uploads": self.step_state_uploads,
                      "staged_uploads": self.staged_uploads,
                      "runtime_pokes": self.runtime_pokes}

@@ -51,12 +51,19 @@ def release_mid_flight(engine, slot):
     return out
 
 
-def drive_lifecycle(engine, prompts):
+# What ``drive_lifecycle`` makes: decode steps in all, and those that
+# hold its first request alone.
+LIFECYCLE_STEPS = 14
+LIFECYCLE_STEPS_FIRST_ALONE = 2
+
+
+def drive_lifecycle(engine, prompts, first=SamplingParams(max_new_tokens=30)):
     """Admissions, steady steps, a finish, a release from another
     thread mid-flight, a preemption and its resume, with the device's
     state compared to the mirrors after every step.  Every request's
-    ``(prompt, sampling, tokens)`` comes back, in admission order."""
-    sampling = [SamplingParams(max_new_tokens=30),
+    ``(prompt, sampling, tokens)`` comes back, in admission order:
+    ``first``'s (greedy unless given), then two that sample."""
+    sampling = [first,
                 SamplingParams(max_new_tokens=30, temperature=0.9,
                                top_k=20),
                 SamplingParams(max_new_tokens=30, temperature=1.2)]

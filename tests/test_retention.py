@@ -402,8 +402,8 @@ def test_the_default_engine_of_a_retention_model_holds_a_state(model,
     stats = engine.kv_stats()
     d, K, L = 16, 2, 2
     assert stats == {
-        "decode_steps": 0, "step_state_uploads": 0, "staged_uploads": 0,
-        "runtime_pokes": 0,
+        "decode_steps": 0, "sampling_steps": 0, "step_state_uploads": 0,
+        "staged_uploads": 0, "runtime_pokes": 0,
         "state_bytes": 8 * L * K * (d // 2 + 1) * d * (d + 1) * 4,
         "state_slots_touched": 8, "state_resets": 0}
     assert engine.prefix_probe(_tokens(5)) == 0

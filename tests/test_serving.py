@@ -531,6 +531,125 @@ def retention_model_and_params():
 
 
 CACHES = ("dense", "paged", "state")
+LIFECYCLE_PROMPTS = ([3, 1, 4, 1, 5], [9, 8, 7], list(range(10)))
+
+
+@pytest.fixture
+def engine_of(model_and_params, retention_model_and_params):
+    def build(cache, **kw):
+        kw.setdefault("seed", 11)
+        if cache == "state":
+            return _engine(retention_model_and_params, **kw)
+        if cache == "paged":
+            kw.setdefault("kv_block", 4)
+        return _engine(model_and_params, kv_cache=cache, **kw)
+
+    return build
+
+
+def _sample_before_pr35(logits, rng, temps, topks):
+    """``serve/engine.py::_sample`` as it stood before it branched on
+    what its rows ask for: every row ranked and drawn, then chosen."""
+    greedy = jnp.argmax(logits, axis=-1)
+    ranks = jnp.argsort(jnp.argsort(-logits, axis=-1), axis=-1)
+    k = jnp.where(topks > 0, topks, logits.shape[-1])[:, None]
+    masked = jnp.where(ranks < k, logits, -jnp.inf)
+    scaled = masked / jnp.maximum(temps, 1e-6)[:, None]
+    sampled = jax.random.categorical(rng, scaled, axis=-1)
+    return jnp.where(temps <= 0.0, greedy, sampled).astype(jnp.int32)
+
+
+class TestSampleBranches:
+    """ISSUE 35: ``_sample`` ranks the vocabulary and draws only where
+    a row that counts asks for it, decided on the device; what a
+    sampled row gets is what it got, for the same key."""
+
+    # temperatures of four rows, and which rows hold a request
+    BATCHES = {
+        "greedy": ([0.0, 0.0, 0.0, 0.0], None),
+        "sampled": ([0.7, 1.0, 1.3, 0.2], None),
+        "mixed": ([0.0, 0.9, 0.0, 1.2], None),
+        "mixed_with_an_idle_row": ([0.0, 0.9, 0.6, 0.0],
+                                   [True, True, False, True]),
+        "idle_row_with_a_temperature": ([0.0, 0.0, 0.8, 0.0],
+                                        [True, True, False, True]),
+    }
+
+    @pytest.mark.parametrize("ties", [False, True],
+                             ids=["distinct", "ties"])
+    @pytest.mark.parametrize("top_k", [0, 1, 20, VOCAB])
+    @pytest.mark.parametrize("batch", list(BATCHES))
+    def test_tokens_are_the_unbranched_formulas(self, batch, top_k, ties):
+        from horovod_tpu.serve.engine import _sample
+
+        temps, rows = self.BATCHES[batch]
+        temps = jnp.asarray(temps, jnp.float32)
+        # Row 3 always draws from the full vocabulary.
+        topks = jnp.asarray([top_k, top_k, top_k, 0], jnp.int32)
+        counted = np.ones(4, bool) if rows is None else np.asarray(rows)
+        logits = jax.random.normal(jax.random.PRNGKey(5), (4, VOCAB)) * 2
+        if ties:
+            logits = jnp.round(logits)       # a dozen values a row
+        new, old = jax.jit(_sample), jax.jit(_sample_before_pr35)
+        for seed in range(4):
+            rng = jax.random.PRNGKey(seed)
+            got = np.asarray(new(logits, rng, temps, topks,
+                                 None if rows is None else jnp.asarray(rows)))
+            want = np.asarray(old(logits, rng, temps, topks))
+            np.testing.assert_array_equal(got[counted], want[counted])
+            assert got.dtype == np.int32
+            if not (counted & (np.asarray(temps) > 0)).any():
+                # No row that counts samples: nothing was drawn at all.
+                np.testing.assert_array_equal(
+                    got, np.argmax(np.asarray(logits), axis=-1))
+
+    @pytest.mark.parametrize("cache", CACHES)
+    def test_sampled_tokens_do_not_depend_on_greedy_batchmates(
+            self, engine_of, cache):
+        """The key is split once a call whatever the rows ask.  The
+        lifecycle's first request is greedy in one engine and samples in
+        the other, so the calls it makes alone take the argmax branch in
+        the one and draw in the other; the two requests admitted beside
+        it get the same tokens in both.  ``sampling_steps`` counts the
+        steps that held a request with a temperature."""
+        runs = []
+        for first in (SamplingParams(max_new_tokens=30),
+                      SamplingParams(max_new_tokens=30, temperature=0.7,
+                                     top_k=3)):
+            eng = engine_of(cache)
+            runs.append(step_state_oracle.drive_lifecycle(
+                eng, LIFECYCLE_PROMPTS, first))
+            stats = eng.kv_stats()
+            assert stats["decode_steps"] == step_state_oracle.LIFECYCLE_STEPS
+            greedy_alone = (step_state_oracle.LIFECYCLE_STEPS_FIRST_ALONE
+                            if first.temperature <= 0 else 0)
+            assert stats["sampling_steps"] == (
+                stats["decode_steps"] - greedy_alone)
+            assert eng.trace_counts["decode"] == 1
+        (_, _, a0), (_, _, b0), (_, _, c0) = runs[0]
+        (_, _, a1), (_, _, b1), (_, _, c1) = runs[1]
+        assert (b0, c0) == (b1, c1)
+        assert a0 != a1 and len(b0) > 5 and len(c0) > 5
+
+    def test_the_decode_span_says_whether_the_step_sampled(
+            self, model_and_params):
+        from horovod_tpu.obs import trace
+
+        trace.configure(enabled=True)
+        trace.clear()
+        eng = _engine(model_and_params, seed=3)
+        eng.start(0, [1, 2, 3], SamplingParams(max_new_tokens=20))
+        eng.step()
+        eng.start(1, [4, 5], SamplingParams(max_new_tokens=20,
+                                            temperature=0.8))
+        eng.step()
+        eng.release(1)      # its temperature goes with it
+        eng.step()
+        said = [s["args"]["sampling"] for s in trace.snapshot()
+                if s["name"] == "hvd_tpu_engine_decode"]
+        trace.clear()
+        assert said == [0, 1, 0]
+        assert eng.kv_stats()["sampling_steps"] == 1
 
 
 class TestStepState:
@@ -539,19 +658,7 @@ class TestStepState:
     changed, and the key is one chain split inside the programs, in
     the order the parent split it on the host."""
 
-    PROMPTS = ([3, 1, 4, 1, 5], [9, 8, 7], list(range(10)))
-
-    @pytest.fixture
-    def engine_of(self, model_and_params, retention_model_and_params):
-        def build(cache, **kw):
-            kw.setdefault("seed", 11)
-            if cache == "state":
-                return _engine(retention_model_and_params, **kw)
-            if cache == "paged":
-                kw.setdefault("kv_block", 4)
-            return _engine(model_and_params, kv_cache=cache, **kw)
-
-        return build
+    PROMPTS = LIFECYCLE_PROMPTS
 
     @staticmethod
     def _built_once(eng):
@@ -610,7 +717,7 @@ class TestStepState:
         cache-free full forwards, reproduces the engine's tokens for
         greedy, temperature and top-k requests across admissions, and
         after every call the engine's key is the chain's."""
-        from horovod_tpu.serve.engine import _sample
+        _sample = _sample_before_pr35
 
         model, params = (retention_model_and_params if cache == "state"
                          else model_and_params)

@@ -18,6 +18,7 @@ it but never read back without the chip.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -177,10 +178,44 @@ def test_paged_decode_kernel_compiles_for_v5e(topo):
     assert "hvd_tpu_paged_decode" in text
 
 
-def test_paged_decode_holds_no_pool_copy_on_v5e(topo, monkeypatch):
+def _described(topo, tree):
+    one = SingleDeviceSharding(topo.devices[0])
+    return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+        a.shape, a.dtype, sharding=one), tree)
+
+
+def _paged_decode_program(topo, monkeypatch, n_layer):
     """The serving decode program at GPT-2 XL's attention widths (25
-    heads of 64, 8 slots, 1025 blocks of 16), pools donated, with the
-    step's kernel as the chip gets it: the chip's compiler must update
+    heads of 64, 8 slots, 1025 blocks of 16; a small vocabulary and
+    feed-forward), pools donated, with the step's kernel as the chip
+    gets it: its text as compiled for the described chip, and the
+    engine it was lowered from."""
+    from horovod_tpu.models.transformer import GPT, GPTConfig
+    from horovod_tpu.ops import paged_attention
+    from horovod_tpu.serve import InferenceEngine
+
+    decode = paged_attention.paged_decode
+    monkeypatch.setattr(paged_attention, "paged_decode",
+                        lambda *a: decode(*a, interpret=False))
+    model = GPT(GPTConfig(vocab_size=512, n_layer=n_layer, n_head=25,
+                          d_model=1600, d_ff=256, max_seq_len=1024))
+    params = model.init(jax.random.PRNGKey(0),
+                        jnp.zeros((1, 8), jnp.int32))["params"]
+    eng = InferenceEngine(model, params, max_slots=8,
+                          prefill_buckets=(64,), max_seq_len=1024,
+                          kv_cache="paged", kv_block=16, kv_blocks=1025)
+    n, cols = eng.max_slots, eng.blocks_per_slot + 1
+    text = jax.jit(eng._decode_paged_impl, donate_argnums=(1,)).lower(
+        _described(topo, params), _described(topo, eng._pools),
+        _described(topo, jnp.zeros((n, cols), jnp.int32)),
+        _described(topo, eng._step_state)).compile().as_text()
+    assert "hvd_tpu_paged_decode" in text
+    return text, eng
+
+
+def test_paged_decode_holds_no_pool_copy_on_v5e(topo, monkeypatch):
+    """The serving decode program (``_paged_decode_program``, one
+    layer): the chip's compiler must update
     every KV pool in place, and nothing of the gathered view's shape is
     left in the program.  The CPU's compiler (tests/test_serving.py)
     sees the order of the write and the read; only this one sees the
@@ -191,34 +226,7 @@ def test_paged_decode_holds_no_pool_copy_on_v5e(topo, monkeypatch):
     in serving (PERF.md, PR 25).  The view — the gather ``[8, 65, 16,
     row]`` and its re-layout as ``[8, 1040, 25, 64]`` — was 23 of the
     34 ms a decode step took after that (PERF.md, PR 27)."""
-    import re
-
-    from horovod_tpu.models.transformer import GPT, GPTConfig
-    from horovod_tpu.ops import paged_attention
-    from horovod_tpu.serve import InferenceEngine
-
-    decode = paged_attention.paged_decode
-    monkeypatch.setattr(paged_attention, "paged_decode",
-                        lambda *a: decode(*a, interpret=False))
-    model = GPT(GPTConfig(vocab_size=512, n_layer=1, n_head=25,
-                          d_model=1600, d_ff=256, max_seq_len=1024))
-    params = model.init(jax.random.PRNGKey(0),
-                        jnp.zeros((1, 8), jnp.int32))["params"]
-    eng = InferenceEngine(model, params, max_slots=8,
-                          prefill_buckets=(64,), max_seq_len=1024,
-                          kv_cache="paged", kv_block=16, kv_blocks=1025)
-    one = SingleDeviceSharding(topo.devices[0])
-
-    def described(tree):
-        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
-            a.shape, a.dtype, sharding=one), tree)
-
-    n, cols = eng.max_slots, eng.blocks_per_slot + 1
-    text = jax.jit(eng._decode_paged_impl, donate_argnums=(1,)).lower(
-        described(params), described(eng._pools),
-        described(jnp.zeros((n, cols), jnp.int32)),
-        described(eng._step_state)).compile().as_text()
-    assert "hvd_tpu_paged_decode" in text
+    text, eng = _paged_decode_program(topo, monkeypatch, n_layer=1)
     shape = ",".join(str(d) for d in eng._pools[0]["k"].shape)
     copies = re.findall(r"^.*= \w+\[%s\]\S* copy\(.*$" % shape, text, re.M)
     assert not copies, copies[:2]
@@ -257,14 +265,12 @@ def test_retention_step_kernel_compiles_for_v5e(topo):
     assert memory.temp_size_in_bytes < state_bytes // 16
 
 
-def test_state_decode_program_updates_its_states_in_place_on_v5e(
-        topo, monkeypatch):
+def _state_decode_program(topo, monkeypatch):
     """The engine's decode program over a retention state, at the
     benchmark's ``brumby-14b`` head widths (40 query / 8 KV heads of
     128, 8 slots; two layers, a small vocabulary and feed-forward), with
-    the step's kernel as the chip gets it: both layers' states are
-    donated and updated in place, and no temporary is of a state's
-    size."""
+    the step's kernel as the chip gets it, both layers' states donated:
+    compiled for the described chip, and the states' shapes."""
     from horovod_tpu.models.transformer import (GPT, GPTConfig,
                                                 init_state_cache)
     from horovod_tpu.ops import retention
@@ -281,12 +287,6 @@ def test_state_decode_program_updates_its_states_in_place_on_v5e(
     params = jax.eval_shape(
         lambda: model.init(jax.random.PRNGKey(0),
                            jnp.zeros((1, 8), jnp.int32))["params"])
-    one = SingleDeviceSharding(topo.devices[0])
-
-    def described(tree):
-        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
-            a.shape, a.dtype, sharding=one), tree)
-
     eng = InferenceEngine.__new__(InferenceEngine)
     eng._model, eng.trace_counts = model, {"decode": 0}
     states = jax.eval_shape(lambda: init_state_cache(model.config, 8))
@@ -295,12 +295,71 @@ def test_state_decode_program_updates_its_states_in_place_on_v5e(
     slots = {"tokens": i32, "positions": i32, "active": flag, "temps": f32,
              "topks": i32, "key": jax.random.PRNGKey(0)}
     compiled = jax.jit(eng._decode_state_impl, donate_argnums=(1,)).lower(
-        described(params), described(states),
-        described(slots)).compile()
-    text = compiled.as_text()
-    assert text.count("hvd_tpu_retention_step") >= 2
+        _described(topo, params), _described(topo, states),
+        _described(topo, slots)).compile()
+    assert compiled.as_text().count("hvd_tpu_retention_step") >= 2
+    return compiled, states
+
+
+def test_state_decode_program_updates_its_states_in_place_on_v5e(
+        topo, monkeypatch):
+    """The decode program over a retention state
+    (``_state_decode_program``): both layers' states are updated in
+    place, and no temporary is of a state's size."""
+    compiled, states = _state_decode_program(topo, monkeypatch)
     memory = compiled.memory_analysis()
     state_bytes = sum(int(np.prod(x.shape)) * 4
                       for x in jax.tree.leaves(states))
     assert memory.alias_size_in_bytes >= state_bytes
     assert memory.temp_size_in_bytes < state_bytes // 16
+
+
+def _computations(text):
+    """A compiled program's text as ``{computation: its lines}``, and
+    the entry computation's name."""
+    bodies, entry, name = {}, None, None
+    for line in text.splitlines():
+        head = re.match(r"(ENTRY )?%(\S+) \(.*\{$", line)
+        if head:
+            name = head.group(2)
+            bodies[name] = []
+            entry = name if head.group(1) else entry
+        elif line.startswith("}"):
+            name = None
+        elif name is not None:
+            bodies[name].append(line)
+    return bodies, entry
+
+
+@pytest.mark.parametrize("cache", ["paged", "state"])
+def test_decode_ranks_the_vocabulary_only_inside_a_conditional_on_v5e(
+        topo, monkeypatch, cache):
+    """Sampling's two sorts of the vocabulary were 13 % of a decode
+    step in ``brumby14b-serve-reason`` and 8 % in ``gpt2xl-serve-chat``,
+    whose requests are all greedy (PERF.md, PR 35).  ``_sample`` now
+    branches on what the rows ask for, and the chip's compiler has to
+    keep the branch: a ``conditional`` turned into a ``select`` would
+    run both sides.  So no computation that the entry reaches without
+    passing into a conditional's branch may hold a ``sort``; the sorts
+    are still there, for the rows that ask."""
+    if cache == "paged":
+        text, _ = _paged_decode_program(topo, monkeypatch, n_layer=2)
+    else:
+        text = _state_decode_program(topo, monkeypatch)[0].as_text()
+    bodies, entry = _computations(text)
+    # Computations named on a line that is not a conditional (a
+    # fusion's, a loop's, a comparator's): entered whenever it runs.
+    called = {name: set(re.findall(r"%([\w.-]+)", " ".join(
+        line for line in lines if " conditional(" not in line)))
+        & set(bodies) for name, lines in bodies.items()}
+    unconditional, queue = set(), [entry]
+    while queue:
+        name = queue.pop()
+        if name not in unconditional:
+            unconditional.add(name)
+            queue.extend(called[name])
+    sorts = {name for name, lines in bodies.items()
+             if any(" sort(" in line for line in lines)}
+    assert sorts, "the sampling branch lost its ranking"
+    assert not sorts & unconditional, sorts & unconditional
+    assert any(" conditional(" in line for line in bodies[entry])
