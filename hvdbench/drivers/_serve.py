@@ -15,10 +15,11 @@ from __future__ import annotations
 
 import gc
 import json
+import math
 import time
 from typing import Dict, List
 
-from hvdbench import check, device, generator
+from hvdbench import check, device, generator, stats
 from hvdbench.window import StepRecord
 
 SPANS = ("engine_prefill", "engine_decode")
@@ -81,12 +82,26 @@ class GcWatch:
     def report(self, t0: float, t1: float) -> dict:
         inside = [(s, g) for t, s, g in self.pauses if t0 < t <= t1]
         return {"collections": len(inside),
+                "full_collections": sum(g == 2 for _, g in inside),
                 "seconds": sum(s for s, _ in inside),
                 "longest": sorted(((round(s, 4), g) for s, g in inside),
                                   reverse=True)[:3]}
 
 
+def freeze_heap(ctx) -> None:
+    """What a server does once it has started: collect, then move every
+    object that start-up and warm-up left into the permanent
+    generation.  A full collection inside the window then walks what
+    the stream made and not the model's and JAX's half a million
+    objects (0.10-0.12 s, once in every process's first window)."""
+    gc.collect()
+    gc.freeze()
+    ctx.setup_split["frozen_objects"] = gc.get_freeze_count()
+
+
 class ServeHarness:
+    extra_facts: dict = {}    # what a kind's own harness adds to ``facts``
+
     def __init__(self, ctx):
         import jax
 
@@ -135,6 +150,8 @@ class ServeHarness:
         self.live: Dict[int, Tracked] = {}
         self.done: List[Tracked] = []
         self.steps: List[StepRecord] = []
+        # stream index -> (prefill bucket, clock after the admitting step)
+        self.admissions: Dict[int, tuple] = {}
         self.failed = 0
         self.deadline_s = float(ctx.traffic["deadline_s"])
 
@@ -200,6 +217,8 @@ class ServeHarness:
             if n > tr.seen:
                 if tr.seen == 0:
                     admitted.append(index)
+                    self.admissions[index] = (self.engine.bucket_for(
+                        len(tr.spec.prompt)), t_after)
                     prompt_tokens += len(tr.spec.prompt)
                     tr.token_times.append(tr.req.first_token_at)
                     tr.token_times.extend([t_after] * (n - 1))
@@ -241,6 +260,77 @@ class ServeHarness:
                                    s.prefill_s, s.decode_s)]
             for s in longest], "gc": self.gc_watch.report(t0, t1)}
 
+    def gap_populations(self, everyone, t0: float, t1: float) -> dict:
+        """On an earlier line: the window's token gaps by what the step
+        that ended each one did, so that a reader sees whether the 95th
+        percentile lies inside one population or between two.  A step
+        that admitted a prompt stalls the other rows by a prefill of
+        that bucket; the step after it binds the new row; of the rest
+        the program's own ``hvd_tpu_engine_decode`` spans (the ring)
+        say which uploaded something (the block table, as a rule)."""
+        uploads = self._decode_uploads()
+        label, after_bind = {}, False
+        for s in self.steps:
+            if s.admitted:
+                label[s.t_after] = "stalled_by_prefill_%d" % max(
+                    self.admissions[i][0] for i in s.admitted)
+            elif after_bind:
+                label[s.t_after] = "post_bind"
+            elif uploads.get(s.t_after):
+                label[s.t_after] = "upload"
+            else:
+                label[s.t_after] = "steady"
+            after_bind = bool(s.admitted)
+        gaps = []
+        for tr in everyone:
+            times = tr.token_times
+            for k in range(1, len(times)):
+                if t0 < times[k] <= t1:
+                    # An admitted row's own second token comes a decode
+                    # step after its first, inside the admitting step.
+                    own = k == 1 and times[1] == self.admissions[
+                        tr.spec.index][1]
+                    gaps.append(((times[k] - times[k - 1]) * 1e3,
+                                 "own_first_decode" if own
+                                 else label.get(times[k], "unknown")))
+        if not gaps:
+            return {}
+        gaps.sort()
+        out = {}
+        for name in sorted({g[1] for g in gaps}):
+            mine = [g[0] for g in gaps if g[1] == name]
+            out[name] = {"share": round(len(mine) / len(gaps), 4),
+                         "p50_ms": round(stats.median(mine), 3),
+                         "p95_ms": round(stats.percentile(mine, 95), 3)}
+        rank = max(1, math.ceil(0.95 * len(gaps)))
+        around = [g[1] for g in gaps[max(0, rank - 1 - len(gaps) // 100):
+                                     rank + len(gaps) // 100]]
+        return {"gaps": len(gaps), "by_population": out,
+                "p95_ms": gaps[rank - 1][0], "p95_in": gaps[rank - 1][1],
+                "p94_to_p96": {n: around.count(n) for n in sorted(set(around))},
+                "uploads_read": bool(uploads)}
+
+    def _decode_uploads(self) -> Dict[float, int]:
+        """``t_after`` of each step -> ``args.uploads`` of the program's
+        decode span inside it; empty where the ring cannot be read."""
+        try:
+            from horovod_tpu.obs import trace
+
+            spans = sorted((s["start_us"], int(s["args"].get("uploads", 0)))
+                           for s in trace.snapshot()
+                           if s["name"] == "hvd_tpu_engine_decode")
+            out, i = {}, 0
+            for step in self.steps:
+                lo, hi = trace.mono_us(step.t_before), trace.mono_us(
+                    step.t_after)
+                while i < len(spans) and spans[i][0] < lo:
+                    i += 1
+                if i < len(spans) and spans[i][0] <= hi:
+                    out[step.t_after] = spans[i][1]
+            return out
+        except Exception:     # a diagnostic never takes the run down
+            return {}
+
     def close_and_check(self) -> List[dict]:
         """Free the program's state, then hold a seeded sample of the
         finished requests, the longest among them, to the reference."""
@@ -263,6 +353,10 @@ class ServeHarness:
         self.engine = self.batcher = None
         for tr in self.done + list(self.live.values()):
             tr.req = None
+        # What ``freeze_heap`` put out of the collector's reach comes
+        # back into it, or a cycle through the engine would keep its
+        # device memory under the reference.
+        gc.unfreeze()
         gc.collect()
 
         import jax

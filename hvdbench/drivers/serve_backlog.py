@@ -11,7 +11,7 @@ import statistics
 import time
 
 from hvdbench import device, generator, window
-from hvdbench.drivers._serve import SPANS, ServeHarness
+from hvdbench.drivers._serve import SPANS, ServeHarness, freeze_heap
 
 
 def run(ctx) -> dict:
@@ -22,6 +22,7 @@ def run(ctx) -> dict:
     ahead = int(traffic["backlog_blocks"])
     h = ServeHarness(ctx)
     h.warm([p for p, _ in generator.block_multiset(traffic)])
+    freeze_heap(ctx)
 
     submitted_blocks = 0
 

@@ -3,7 +3,11 @@ each request when it is due (one arrival inside every ``1 / rate``
 slot), whatever the system is doing; the main thread drives
 ``batcher.step()``.  Every latency is timed from when the request was
 due.  A pre-roll fills the slots before the window opens; the window
-closes on the clock and nothing is drained."""
+closes on the clock and nothing is drained.
+
+``run`` takes the harness class: ``serve-open-state`` hands it its own
+and shares this loop.  Whatever the harness, the heap is frozen between
+warm-up and the stream (``_serve.freeze_heap``)."""
 
 from __future__ import annotations
 
@@ -13,7 +17,7 @@ import threading
 import time
 
 from hvdbench import device, generator, stats, window
-from hvdbench.drivers._serve import SPANS, ServeHarness
+from hvdbench.drivers._serve import SPANS, ServeHarness, freeze_heap
 
 
 def _feeder(h, traffic, seed, vocab, t_stream0, stop, out) -> None:
@@ -36,11 +40,12 @@ def _feeder(h, traffic, seed, vocab, t_stream0, stop, out) -> None:
         block += 1
 
 
-def run(ctx) -> dict:
+def run(ctx, harness=ServeHarness) -> dict:
     traffic, seed = ctx.traffic, ctx.seed
     vocab = ctx.config["vocab_size"]
-    h = ServeHarness(ctx)
+    h = harness(ctx)
     h.warm([p for p, _ in generator.block_multiset(traffic)])
+    freeze_heap(ctx)
 
     stop = threading.Event()
     arrivals: "queue.SimpleQueue" = queue.SimpleQueue()
@@ -100,6 +105,8 @@ def run(ctx) -> dict:
     due_in = [tr for tr in everyone if t0 < tr.due <= t1]
     late = [(tr.submitted - tr.due) * 1e3 for tr in due_in]
     print(json.dumps({"host_pauses": h.host_pauses(t0, t1)}), flush=True)
+    print(json.dumps({"gap_populations": h.gap_populations(
+        everyone, t0, t1)}), flush=True)
     compilations = ((counter.count - open_snap[0])
                     + sum(h.engine.trace_counts.values()) - open_snap[1])
     t_half = (t0 + t1) / 2
@@ -139,6 +146,7 @@ def run(ctx) -> dict:
     attempted = len(due_in)
     checks = h.close_and_check()
     facts["trace_counts"] = h.trace_counts
+    facts.update(h.extra_facts)
     return {
         "t_window_open": t_open,
         "attempted": attempted, "failed": failed,

@@ -22,10 +22,9 @@ Three things differ, all in the harness and none in the loop:
   (``state_bytes``, ``state_slots_touched``, ``state_resets``), where the
   per-layer readers of the retention metrics find it.
 
-``serve_open.run`` names its harness class, so this module runs it with
-the class exchanged for the length of the call, rather than repeat its
-loop; PERF.md section 7 asks a ``benchmark`` issue to give ``run`` a
-harness argument instead.
+``serve_open.run`` takes the harness class as an argument, so the loop,
+the freeze of the heap after warm-up and the metrics are one piece of
+code for both kinds.
 """
 
 from __future__ import annotations
@@ -38,8 +37,6 @@ from hvdbench.drivers._serve import ServeHarness
 
 
 class StateHarness(ServeHarness):
-    made = None     # the harness of the run in flight
-
     def __init__(self, ctx):
         super().__init__(ctx)
         stated = ctx.config["run"]["batcher"].get("max_new_tokens")
@@ -58,8 +55,6 @@ class StateHarness(ServeHarness):
                 f"traffic kind serve-open-state drives a state cache; the "
                 f"engine built for {ctx.config['name']!r} holds "
                 f"{self.engine.kv_mode!r}")
-        self.state_stats = {}
-        StateHarness.made = self
 
     def warm(self, prompt_lens) -> None:
         t = time.monotonic()
@@ -79,17 +74,9 @@ class StateHarness(ServeHarness):
         self.ctx.setup_split["warm_up_s"] = time.monotonic() - t
 
     def close_and_check(self):
-        self.state_stats = dict(self.engine.kv_stats())
+        self.extra_facts = {"state": dict(self.engine.kv_stats())}
         return super().close_and_check()
 
 
 def run(ctx) -> dict:
-    theirs = serve_open.ServeHarness
-    serve_open.ServeHarness = StateHarness
-    try:
-        result = serve_open.run(ctx)
-    finally:
-        serve_open.ServeHarness = theirs
-        harness, StateHarness.made = StateHarness.made, None
-    result["facts"]["state"] = harness.state_stats
-    return result
+    return serve_open.run(ctx, harness=StateHarness)

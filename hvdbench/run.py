@@ -5,9 +5,10 @@
 The cell is an entry of ``workloads`` in ``BENCHMARK.json``; its
 configuration, traffic mix, model family, reference, traffic kind and
 per-layer readers are files found by name (hvdbench/README.md).  The
-last line of standard output is the one JSON object the contract fixes;
-everything else (the set-up split, each number compared beside its
-limit, sample counts) goes on earlier lines.  Without the TPU chips the
+last line of standard output is the one JSON object the contract fixes,
+ending in ``compared``: each number that decided ``correct`` beside its
+limit, which are also the last lines of standard error; everything else
+(the set-up split, sample counts, step times) goes on earlier lines.  Without the TPU chips the
 cell asks for, the run raises and prints no result.
 """
 
@@ -144,6 +145,10 @@ def run_cell(bench: dict, cell: dict, config: dict, traffic: dict, *,
     line["device"] = record
     if rehearsal:
         line["rehearsal"] = True
+    # Last: each number compared, beside its limit.
+    line["compared"] = {e["check"]: {"value": e["value"],
+                                     "limit": e["limit"]}
+                        for e in result["checks"]}
     return line
 
 
@@ -166,6 +171,9 @@ def main(argv=None) -> None:
     line = run_cell(bench, cell, config, traffic, seed=args.seed,
                     seconds=args.seconds, trace=bool(args.trace))
     refuse_rehearsal(line)
+    for name, c in line["compared"].items():
+        print(f"compared {name}: {c['value']!r} limit {c['limit']!r}",
+              file=sys.stderr, flush=True)
     print(json.dumps(line), flush=True)
 
 

@@ -51,12 +51,52 @@ def test_quartile_spread_is_pythons_quantiles():
         (q3 - q1) / statistics.median(xs))
 
 
+def test_strict_spread_leaves_out_the_run_farthest_from_the_median():
+    from hvdbench.tools import spread
+
+    xs = [100.0, 100.2, 99.9, 100.1, 100.3, 104.0]
+    assert spread.strict_spread(xs) == pytest.approx(
+        (100.3 - 99.9) / statistics.median(xs))
+    assert spread.strict_spread(xs) >= stats.quartile_spread(xs[:5])
+    # The rule of README step 5: mean spread / 0.4, up to the next
+    # 0.005, never under 0.01.
+    assert spread.bound_for(0.0010) == 0.01
+    assert spread.bound_for(0.0040) == 0.01
+    assert spread.bound_for(0.0041) == pytest.approx(0.015)
+    assert spread.bound_for(0.0100) == pytest.approx(0.025)
+
+
+def test_spread_reads_a_runs_earlier_lines_by_their_key():
+    from hvdbench.tools import spread
+
+    said = spread.earlier_lines(
+        'noise\n{"facts": {"queue_at_close": 1}}\n{"host_pauses": {"gc": '
+        '{"full_collections": 0}}}\n{"correct": true, "failed": 0}\n')
+    assert said["facts"]["queue_at_close"] == 1
+    assert said["host_pauses"]["gc"]["full_collections"] == 0
+    assert "correct" not in said      # the result line is not one of them
+
+
+def test_a_rate_holds_by_the_rule_the_traffic_files_state():
+    from hvdbench.tools import sweep_rate
+
+    held = {"waiting_at_close": 1, "in_flight_at_close": 8,
+            "ttft_p50_ms_by_half": [31.6, 31.2],
+            "ttft_max_ms_by_half": [80.0, 213.0]}
+    assert sweep_rate.why_not(held, 8) == []
+    assert sweep_rate.why_not(dict(held, waiting_at_close=8,
+                                   in_flight_at_close=16), 8)
+    assert sweep_rate.why_not(dict(held, in_flight_at_close=9), 8)
+    assert sweep_rate.why_not(dict(held, ttft_p50_ms_by_half=[400, 658]), 8)
+    assert sweep_rate.why_not(dict(held, ttft_max_ms_by_half=[90, 1089]), 8)
+
+
 # --- the stratified generator ------------------------------------------------
 
 SEEDS = (0, 1, 7, 2**31 + 12345, 2**32 + 5)
 
 
-@pytest.mark.parametrize("name", ["score", "chat-steady"])
+@pytest.mark.parametrize("name", ["score", "chat-loaded"])
 def test_every_seed_offers_the_same_multiset_in_another_order(name):
     traffic = load("traffic", name)
     want = collections.Counter(generator.block_multiset(traffic))
@@ -90,8 +130,21 @@ def test_score_mix_is_the_one_the_issue_describes():
     assert max(p + o for p, o in pairs) < 1024
 
 
+def test_chat_loaded_offers_the_retired_chat_mix_to_the_letter():
+    """``chat-loaded`` took ``chat-steady``'s place at eight times its
+    rate (PR 36); the lengths are the retired file's, pair for pair."""
+    traffic = load("traffic", "chat-loaded")
+    assert generator.block_multiset(traffic) == [
+        (25, 19), (45, 37), (64, 62), (87, 26), (115, 43), (155, 86),
+        (222, 31), (398, 51)]
+    assert traffic["rate_per_s"] > 4 and traffic["kind"] == "serve-open"
+    assert (traffic["preroll_s"], traffic["deadline_s"],
+            traffic["trace_seconds"], traffic["check_requests"]) == (
+                10, 0, 10, 6)
+
+
 def test_chat_mix_uses_all_three_buckets():
-    pairs = generator.block_multiset(load("traffic", "chat-steady"))
+    pairs = generator.block_multiset(load("traffic", "chat-loaded"))
     prompts = [p for p, _ in pairs]
     assert sum(p <= 64 for p in prompts) >= 2
     assert sum(64 < p <= 256 for p in prompts) >= 4
@@ -102,7 +155,7 @@ def test_chat_mix_uses_all_three_buckets():
 
 @pytest.mark.parametrize("seed", SEEDS)
 def test_open_loop_has_exactly_one_arrival_in_each_slot(seed):
-    traffic = load("traffic", "chat-steady")
+    traffic = load("traffic", "chat-loaded")
     rate, n = traffic["rate_per_s"], traffic["block"]
     dues = [r.due_s for b in range(4)
             for r in generator.request_block(traffic, seed, b, 50257)]

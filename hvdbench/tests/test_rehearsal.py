@@ -28,7 +28,7 @@ from hvdbench.reference import gpt2 as ref  # noqa: E402
 from hvdbench.tests import tiny  # noqa: E402
 
 CELLS = {"train": "gpt2m-train-1chip", "serve-backlog": "gpt2xl-serve-score",
-         "serve-open": "gpt2xl-serve-chat"}
+         "serve-open": "gpt2xl-serve-chat-loaded"}
 
 
 def rehearse(cell_name, *, trace=False, seconds=1.5, seed=2**31 + 11):
@@ -56,6 +56,29 @@ def test_rehearsal_ends_in_a_well_formed_line_that_is_no_measurement(kind):
     json.dumps(line)
     with pytest.raises(RuntimeError, match="rehearsal"):
         run.refuse_rehearsal(line)
+
+
+def test_the_heap_is_frozen_when_the_window_opens(monkeypatch):
+    """``serve_open.run`` freezes Python's heap between warm-up and the
+    stream, so no window holds a walk over what start-up left."""
+    import gc
+
+    from hvdbench.drivers import _serve
+
+    seen = []
+    real = _serve.ServeHarness.submit
+
+    def submit(self, spec, due):
+        if not seen:    # once: the count walks the whole frozen list
+            seen.append(gc.get_freeze_count())
+        return real(self, spec, due)
+
+    monkeypatch.setattr(_serve.ServeHarness, "submit", submit)
+    before = gc.get_freeze_count()      # what imports froze: a few hundred
+    line = rehearse(CELLS["serve-open"])
+    assert line["correct"] is True
+    assert seen and seen[0] > before + 10_000   # at the first arrival
+    assert gc.get_freeze_count() == before    # and given back for the check
 
 
 def test_traced_rehearsal_reports_per_layer_metrics_only():

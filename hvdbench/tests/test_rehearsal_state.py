@@ -71,6 +71,30 @@ def test_rehearsal_ends_in_a_well_formed_line_that_is_no_measurement():
         run.refuse_rehearsal(line)
 
 
+def test_the_heap_is_frozen_when_the_window_opens(monkeypatch):
+    """The kind hands ``serve_open.run`` its harness and shares the
+    freeze between warm-up and the stream."""
+    import gc
+
+    from hvdbench.drivers import serve_open_state
+
+    seen = []
+    real = serve_open_state.StateHarness.submit
+
+    def submit(self, spec, due):
+        if not seen:    # once: the count walks the whole frozen list
+            seen.append((type(self).__name__, gc.get_freeze_count()))
+        return real(self, spec, due)
+
+    monkeypatch.setattr(serve_open_state.StateHarness, "submit", submit)
+    before = gc.get_freeze_count()      # what imports froze: a few hundred
+    line = rehearse()
+    assert line["correct"] is True
+    assert seen and seen[0][0] == "StateHarness"
+    assert seen[0][1] > before + 10_000     # at the first arrival
+    assert gc.get_freeze_count() == before
+
+
 def test_traced_rehearsal_reports_what_needs_no_device_trace():
     bench = tiny()[0]
     line = rehearse(trace=True)
@@ -89,12 +113,12 @@ def test_traced_rehearsal_reports_what_needs_no_device_trace():
 
 
 def test_the_kind_refuses_an_engine_that_holds_blocks():
-    bench, cell, config, traffic = run.load_cell("gpt2xl-serve-chat")
+    bench, cell, config, traffic = run.load_cell("gpt2xl-serve-chat-loaded")
     from hvdbench.tests import tiny as tiny_gpt2
 
     with pytest.raises(RuntimeError, match="drives a state cache"):
         run.run_cell(bench, cell, tiny_gpt2.config("gpt2-xl"),
-                     dict(tiny_gpt2.traffic("chat-steady"),
+                     dict(tiny_gpt2.traffic("chat-loaded"),
                           kind="serve-open-state"),
                      seed=3, seconds=1.0, trace=False, rehearsal=True,
                      t_start=time.monotonic())
