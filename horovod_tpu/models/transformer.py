@@ -17,6 +17,7 @@ bfloat16 activations by default: the MXU-native dtype.
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import Any, Optional, Tuple
 
 import flax.linen as nn
@@ -49,16 +50,45 @@ class GPTConfig:
     # the parameter tree and the programs this model always had.
     norm: str = "layernorm"            # 'layernorm' | 'rmsnorm'
     norm_eps: float = 1e-6
-    positions: str = "learned"         # 'learned': a table of max_seq_len rows | 'rope': no table
+    positions: str = "learned"         # 'learned': a table of max_seq_len rows | 'rope': no table | 'none': no positional signal
     rope_theta: float = 10000.0
     n_kv_head: int = 0                 # 0 = n_head: one KV head per query head
     head_dim: int = 0                  # 0 = d_model // n_head
     qk_norm: bool = False              # RMS norm of q and k per head, before positions
-    mlp: str = "gelu"                  # 'gelu' | 'swiglu' (gated SiLU)
+    mlp: str = "gelu"                  # 'gelu' | 'swiglu' (gated SiLU) | 'relu2' (squared ReLU, not gated)
     # What mixes tokens in a layer: 'attention' (softmax over a K/V
     # cache) or 'retention' (ops/retention.py: a fixed-size state).
     # One word for every layer, or one per layer.
     mixer: Any = "attention"
+    # What a layer is: 'block' (the two-part block: the mixer above,
+    # then a feed-forward, on one residual) or ONE sub-layer on a
+    # residual of its own, ``x + f(norm(x))``: a mixer alone
+    # ('attention'; 'ssm', ops/ssm.py) or a feed-forward alone
+    # ('experts', parallel/moe.py::DroplessExperts).  One word for every
+    # layer, or one per layer.
+    layers: Any = "block"
+    # 'ssm' layers (Mamba-2): heads of ssm_head_dim, B and C shared by
+    # groups of heads, a state of ssm_state numbers per head channel, a
+    # causal depth-wise convolution of ssm_conv taps in front.
+    ssm_heads: int = 0
+    ssm_head_dim: int = 64
+    ssm_groups: int = 1
+    ssm_state: int = 128
+    ssm_conv: int = 4
+    ssm_chunk: int = 128
+    # 'experts' layers: the router scores expert_count experts and
+    # takes expert_top_k; this chip holds the contiguous range
+    # expert_held = (offset, count) of them (None = all).
+    expert_count: int = 0
+    expert_top_k: int = 1
+    expert_d_ff: int = 0
+    expert_shared_d_ff: int = 0        # 0 = no shared expert
+    expert_scale: float = 1.0
+    expert_held: Optional[Tuple[int, int]] = None
+    # The kinds of layer (of ``layers``) that are recomputed in the
+    # backward pass instead of keeping their activations (a
+    # configuration states them where a step would not fit).
+    remat_layers: Tuple[str, ...] = ()
     # Tensor-parallel serving (docs/tp_serving.md): a 1-D ``tensor``
     # mesh makes one decode replica span ``tp`` chips.  Placement is
     # column-parallel only (qkv/up kernels sharded on the output dim,
@@ -89,8 +119,32 @@ class GPTConfig:
                 f"got {self.mixer!r}")
         return kinds
 
+    @property
+    def layer_kinds(self) -> Tuple[str, ...]:
+        """What every layer is."""
+        kinds = ((self.layers,) * self.n_layer
+                 if isinstance(self.layers, str) else tuple(self.layers))
+        if len(kinds) != self.n_layer or set(kinds) - set(LAYERS):
+            raise ValueError(
+                f"layers must be one of {LAYERS} or {self.n_layer} of "
+                f"them, got {self.layers!r}")
+        return kinds
+
 
 MIXERS = ("attention", "retention")
+LAYERS = ("block", "attention", "ssm", "experts")
+
+
+def _refuse_serving(config: GPTConfig) -> None:
+    """A model with a state-space or an expert layer is trained, not
+    served yet."""
+    missing = set(config.layer_kinds) & {"ssm", "experts"}
+    if missing:
+        raise NotImplementedError(
+            f"serving a model with {sorted(missing)} layers is not built: "
+            f"a state-space layer needs a state cache beside the K/V cache "
+            f"in one cache manager, and the engine has no expert layer "
+            f"(serve/engine.py)")
 
 
 def cache_kinds(config: GPTConfig) -> Tuple[str, ...]:
@@ -99,8 +153,9 @@ def cache_kinds(config: GPTConfig) -> Tuple[str, ...]:
     far: :func:`init_kv_cache`, or the engine's paged pools) or
     ``'state'`` (a fixed-size retention state: :func:`init_state_cache`).
     The serving engine chooses its cache from this."""
-    return tuple("kv" if m == "attention" else "state"
-                 for m in config.mixers)
+    _refuse_serving(config)
+    return tuple("kv" if "attention" in (kind, m) else "state"
+                 for kind, m in zip(config.layer_kinds, config.mixers))
 
 
 def init_state_cache(config: GPTConfig, batch_size: int):
@@ -411,6 +466,87 @@ def _gate_bias(key, shape, dtype=jnp.float32):
     return jnp.linspace(2.0, 6.0, shape[0]).astype(dtype)
 
 
+def _dt_bias(key, shape, dtype=jnp.float32):
+    """Mamba-2's own start for the step size: ``softplus(bias)`` is
+    log-uniform over [0.001, 0.1]."""
+    lo, hi = math.log(1e-3), math.log(1e-1)
+    dt = jnp.maximum(jnp.exp(jax.random.uniform(key, shape) * (hi - lo)
+                             + lo), 1e-4)
+    return (dt + jnp.log(-jnp.expm1(-dt))).astype(dtype)
+
+
+def _a_log(key, shape, dtype=jnp.float32):
+    """``A = -exp(A_log)`` starts uniform over [-16, -1]."""
+    return jnp.log(jax.random.uniform(key, shape, minval=1.0,
+                                      maxval=16.0)).astype(dtype)
+
+
+def _conv_taps(key, shape, dtype=jnp.float32):
+    """Uniform over [-1/sqrt(taps), 1/sqrt(taps)], a depth-wise
+    convolution's usual start."""
+    bound = shape[0] ** -0.5
+    return jax.random.uniform(key, shape, dtype, -bound, bound)
+
+
+class Mamba2(nn.Module):
+    """A Mamba-2 mixer (``ops/ssm.py`` has the scan and its two forms).
+    ``in_proj`` gives a gate ``z``, the scan's inputs ``x, B, C`` in one
+    stretch and a step size ``dt`` a head; the stretch goes through a
+    causal depth-wise convolution of ``ssm_conv`` taps (with a bias) and
+    SiLU; ``dt = softplus(dt + dt_bias)``, ``A = -exp(A_log)``; the
+    scan's result times ``silu(z)`` goes through an RMS norm over each
+    of the ``ssm_groups`` groups of channels (one learned scale) and
+    ``out_proj``.  ``mamba(x)`` is the training forward, from a zero
+    state."""
+
+    config: GPTConfig
+
+    @nn.compact
+    def __call__(self, x):
+        from ..ops import ssm
+
+        cfg = self.config
+        B, T, _ = x.shape
+        H, P, G, N = (cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_groups,
+                      cfg.ssm_state)
+        inner, taps = H * P, cfg.ssm_conv
+        z, xbc, dt = jnp.split(
+            _dense(cfg, 2 * inner + 2 * G * N + H, "in_proj")(x),
+            [inner, 2 * inner + 2 * G * N], axis=-1)
+        # Tap k of the convolution reads the token taps - 1 - k back.
+        conv_w = self.param(
+            "conv_kernel", _conv_taps,
+            (taps, xbc.shape[-1]), cfg.param_dtype).astype(jnp.float32)
+        conv_b = self.param("conv_bias", nn.initializers.zeros,
+                            (xbc.shape[-1],), cfg.param_dtype)
+        padded = jnp.pad(xbc.astype(jnp.float32),
+                         ((0, 0), (taps - 1, 0), (0, 0)))
+        xbc = nn.silu(sum(padded[:, k:k + T] * conv_w[k]
+                          for k in range(taps))
+                      + conv_b.astype(jnp.float32)).astype(cfg.dtype)
+        xs, Bm, Cm = jnp.split(xbc, [inner, inner + G * N], axis=-1)
+        dt = jax.nn.softplus(
+            dt.astype(jnp.float32)
+            + self.param("dt_bias", _dt_bias, (H,), jnp.float32))
+        A = -jnp.exp(self.param("A_log", _a_log, (H,), jnp.float32))
+        D = self.param("D", nn.initializers.ones, (H,), jnp.float32)
+        # The scope holds the scan alone, forward and transpose; the
+        # projections, the convolution and the norm are outside it.
+        with jax.named_scope("hvd_tpu_ssm_scan"):
+            y, _ = ssm.ssm_chunked(
+                xs.reshape(B, T, H, P), dt, A, Bm.reshape(B, T, G, N),
+                Cm.reshape(B, T, G, N), D, chunk=cfg.ssm_chunk)
+        y = (y.reshape(B, T, inner) * nn.silu(z.astype(jnp.float32))
+             ).reshape(B, T, G, inner // G)
+        y = y * jax.lax.rsqrt(
+            jnp.mean(jnp.square(y), axis=-1, keepdims=True) + cfg.norm_eps)
+        scale = self.param("norm_scale", nn.initializers.ones, (inner,),
+                           cfg.param_dtype)
+        y = (y.reshape(B, T, inner) * scale.astype(jnp.float32)
+             ).astype(cfg.dtype)
+        return _dense(cfg, cfg.d_model, "out_proj")(y)
+
+
 class MlpBlock(nn.Module):
     config: GPTConfig
 
@@ -423,6 +559,10 @@ class MlpBlock(nn.Module):
             # yet (plan.tp_param_spec knows no ``gate``).
             h = nn.silu(_dense(cfg, cfg.d_ff, "gate")(x)) \
                 * _dense(cfg, cfg.d_ff, "up")(x)
+            return _dense(cfg, cfg.d_model, "down")(h)
+        if cfg.mlp == "relu2":
+            # Squared ReLU, not gated: down(relu(up(x)) ** 2).
+            h = jnp.square(nn.relu(_dense(cfg, cfg.d_ff, "up")(x)))
             return _dense(cfg, cfg.d_model, "down")(h)
         if cfg.mlp != "gelu":
             raise ValueError(f"Unknown mlp {cfg.mlp!r}")
@@ -443,12 +583,29 @@ class Block(nn.Module):
     mesh: Optional[Mesh] = None
     use_moe: bool = False
     mixer: str = "attention"
+    kind: str = "block"
 
     @nn.compact
     def __call__(self, x, cache=None, positions=None):
         cfg = self.config
-        attn_in = _norm(cfg, "ln1")(x)
-        if self.mixer == "retention":
+        if self.kind in ("ssm", "experts"):
+            # One sub-layer on its own residual, and nothing to cache.
+            if self.kind == "ssm":
+                part = Mamba2(cfg, name="ssm")
+            else:
+                from ..parallel.moe import DroplessExperts
+
+                part = DroplessExperts(
+                    d_model=cfg.d_model, d_ff=cfg.expert_d_ff,
+                    n_experts=cfg.expert_count, top_k=cfg.expert_top_k,
+                    shared_d_ff=cfg.expert_shared_d_ff,
+                    scale=cfg.expert_scale, held=cfg.expert_held,
+                    dtype=cfg.dtype, param_dtype=cfg.param_dtype,
+                    name="experts")
+            return x + part(_norm(cfg, "ln")(x))
+        alone = self.kind == "attention"
+        attn_in = _norm(cfg, "ln" if alone else "ln1")(x)
+        if self.mixer == "retention" and not alone:
             attn = Retention(cfg, name="retn")
         else:
             attn = Attention(cfg, self.mesh, name="attn")
@@ -458,6 +615,8 @@ class Block(nn.Module):
         else:
             a = attn(attn_in, positions=positions)
         x = x + a
+        if alone:
+            return x if cache is None else (x, new_cache)
         if self.use_moe:
             from ..parallel.moe import MoEMlp
 
@@ -497,8 +656,9 @@ class GPT(nn.Module):
                  kv_caches=None, positions=None, logit_rows=None):
         cfg = self.config
         B, T = tokens.shape
-        mixers = cfg.mixers
+        mixers, kinds = cfg.mixers, cfg.layer_kinds
         if kv_caches is not None:
+            _refuse_serving(cfg)
             if cfg.attention in ("ring", "ulysses"):
                 # Sequence-sharded training layouts have no KV-cache
                 # analogue; decode is a per-replica workload.
@@ -520,16 +680,21 @@ class GPT(nn.Module):
                 x = tok_emb + pos_emb[positions].astype(cfg.dtype)
             else:
                 x = tok_emb + pos_emb[None, :T].astype(cfg.dtype)
-        elif cfg.positions == "rope":
-            x = tok_emb     # the mixers rotate q and k; there is no table
+        elif cfg.positions in ("rope", "none"):
+            # 'rope': the mixers rotate q and k; 'none': the order of the
+            # tokens reaches the model through its causal layers alone.
+            # Neither has a table.
+            x = tok_emb
         else:
             raise ValueError(f"Unknown positions {cfg.positions!r}")
         new_caches = []
         for i in range(cfg.n_layer):
             use_moe = (cfg.moe_experts > 0
                        and (i + 1) % max(1, cfg.moe_every) == 0)
-            block = Block(cfg, self.mesh, use_moe=use_moe, mixer=mixers[i],
-                          name=f"block_{i}")
+            block = (nn.remat(Block) if kinds[i] in cfg.remat_layers
+                     else Block)(
+                cfg, self.mesh, use_moe=use_moe, mixer=mixers[i],
+                kind=kinds[i], name=f"block_{i}")
             if kv_caches is not None:
                 x, c = block(x, cache=kv_caches[i], positions=positions)
                 new_caches.append(c)

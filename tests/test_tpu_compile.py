@@ -363,3 +363,58 @@ def test_decode_ranks_the_vocabulary_only_inside_a_conditional_on_v5e(
     assert sorts, "the sampling branch lost its ranking"
     assert not sorts & unconditional, sorts & unconditional
     assert any(" conditional(" in line for line in bodies[entry])
+
+
+# --- the hybrid family's two new paths at the published widths ---------------
+
+def test_dropless_experts_run_as_grouped_kernels_on_v5e(topo):
+    """8 held experts of 2688 x 1856, 8,192 tokens x top 6: the grouped
+    products of the forward pass and both of their transposes are
+    Mosaic kernels under the experts' scope, once for the 12,288
+    sorted rows that four times an even load fills and once, in the
+    other branch of the one conditional, for all 49,152."""
+    from horovod_tpu.parallel.moe import DroplessExperts
+
+    one = SingleDeviceSharding(topo.devices[0])
+    layer = DroplessExperts(d_model=2688, d_ff=1856, n_experts=128, top_k=6,
+                            shared_d_ff=3712, scale=2.5, held=(0, 8),
+                            interpret=False)
+    x = jax.ShapeDtypeStruct((1, 8192, 2688), jnp.bfloat16, sharding=one)
+    params = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one),
+        jax.eval_shape(layer.init, jax.random.PRNGKey(0), x)["params"])
+
+    def loss(p, x):
+        return layer.apply({"params": p}, x).astype(jnp.float32).sum()
+
+    text = _compile(jax.grad(loss, argnums=(0, 1)), params, x)
+    kernels = re.findall(r"= (\S+) custom-call\([^\n]*tpu_custom_call"
+                         r"[^\n]*hvd_tpu_moe_experts", text)
+    # up and down, each forward, towards the rows and towards the kernel.
+    assert len(kernels) == 12, kernels
+    assert sum("[12288," in k for k in kernels) == 4
+    assert sum("[49152," in k for k in kernels) == 4
+    assert " conditional(" in text and "hvd_tpu_moe_route" in text
+
+
+def test_chunked_scan_and_its_backward_fit_on_v5e(topo):
+    """One layer's scan at 8,192 tokens, 64 heads of 64, 8 groups, a
+    state of 128, chunks of 128, with its backward: what one recomputed
+    layer needs beside the step's 8 GB of weights and moments."""
+    from horovod_tpu.ops import ssm
+
+    one = SingleDeviceSharding(topo.devices[0])
+
+    def sds(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    def loss(x, dt, A, Bm, Cm, D):
+        with jax.named_scope("hvd_tpu_ssm_scan"):
+            return ssm.ssm_chunked(x, dt, A, Bm, Cm, D)[0].sum()
+
+    compiled = jax.jit(jax.grad(loss, argnums=tuple(range(6)))).lower(
+        sds((1, 8192, 64, 64)), sds((1, 8192, 64), jnp.float32),
+        sds((64,), jnp.float32), sds((1, 8192, 8, 128)),
+        sds((1, 8192, 8, 128)), sds((64,), jnp.float32)).compile()
+    assert "hvd_tpu_ssm_scan" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 3 * 2**30
