@@ -77,7 +77,7 @@ class BertSelfAttention(nn.Module):
             from ..ops import pallas_attention
 
             # Kernel rule (see ops/pallas_attention): T < 128 runs as a
-            # single clamped block; larger T must divide the 128 block.
+            # single clamped block; larger T must be a multiple of 128.
             out = pallas_attention.flash_attention(q, k, v, causal=False) \
                 if T % min(128, T) == 0 else \
                 full_attention(q, k, v, causal=False)

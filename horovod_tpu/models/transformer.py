@@ -388,12 +388,12 @@ class Attention(nn.Module):
             from ..ops import pallas_attention
 
             if cfg.causal:
-                # Handles any T by padding up to the kernel block size.
+                # Handles any T by padding up to a multiple of 128.
                 out = pallas_attention.flash_attention_padded(q, k, v)
             else:
                 if T % min(128, T):
                     # T < 128 runs as a single clamped block; larger T
-                    # must divide the 128 block.  Non-causal padding
+                    # must be a multiple of 128.  Non-causal padding
                     # would need key masking in the kernel, so fail with
                     # guidance instead of a shape error deep inside the
                     # wrapper.

@@ -2,27 +2,54 @@
 
 No reference analogue — Horovod ships no kernels (SURVEY.md §2.9: no
 attention/sequence machinery at all); this is part of the TPU rebuild's
-first-class long-context support.  The forward pass is a Pallas kernel
-(per `/opt/skills/guides/pallas_guide.md` patterns): grid
-``(batch·head, q-block, k-block)`` with K/V streamed block-by-block
-through VMEM (usage is O(block·d), not O(T·d)) and the flash
-streaming-softmax state (running max / numerator / denominator, float32)
-carried across the k-block grid steps in VMEM scratch; causal blocks
-skip their compute via ``pl.when``.  The backward pass is the standard
-flash recompute — chunked over K blocks with ``lax.scan`` so memory
-stays O(T·block) — in plain jnp, where XLA already emits MXU-optimal
-matmuls.
+first-class long-context support.
+
+**The forward** is one Pallas kernel a call, and :func:`plan` says from
+the shapes alone what that call does — the same function the call
+builds its grid from.  A grid step holds one block of ``block_q`` query
+rows against the *whole* key range of its heads: K and V of the head
+group stay resident in VMEM while the group's query blocks go by (their
+block index does not change, so they are copied once a group), and the
+step walks the live keys itself, ``block_k`` at a time, in a
+``fori_loop`` that carries the streaming-softmax state (running max,
+denominator, numerator; float32).  With ``causal`` the walk ends at the
+diagonal: a block above it costs no step, no copy and no arithmetic,
+the chunks wholly below it skip the mask, and the last chunk — what is
+left up to the diagonal, of a width that is static in the block's place
+within its chunk — masks the block's own keys alone.  A sequence of one
+chunk (GPT-2's 1,024 positions) has no walk and no state: each step is
+a plain softmax over its live keys.  Both products take their operands
+in the inputs' dtype (bfloat16 into the MXU for bfloat16 inputs,
+float32 for float32) and accumulate in float32; the scale meets the
+float32 scores, and max, exponent, sums and ``lse`` stay float32.
+
+Where a head fills whole vectors of 128 lanes, or ``128 / d`` heads
+side by side do (two of GPT-2's 64), the kernel reads ``[B, T, H * D]``
+as it stands — no transpose on either side of the call — and a step
+takes one such group of lanes.  The heads of a group are stacked along
+the rows with the other heads' lanes zeroed (the block-diagonal query
+of ``ops/paged_attention.py``): one product with the keys scores them
+all, no head is sliced out of a vector, and the wasted products are
+zeros the MXU would have idled through anyway at a contraction of 64.
+Any other head size goes in as ``[B * H, T, D]``, one head a step.
+
+**The backward** is the standard flash recompute in plain jnp, chunked
+over keys with ``lax.scan`` so memory stays O(T · chunk).  Its chunk is
+its own (128 keys, whatever the forward chose) and its five products a
+chunk run on float32 operands, which is not what the MXU is fastest at
+(ROADMAP S2).
 
 Used by ``models.transformer`` (``attention='flash'``, which pads odd
-causal lengths up to the block size).  Off-TPU the same kernel runs in
-the Pallas interpreter (tests); it does not silently fall back to
+causal lengths up to a multiple of 128).  Off-TPU the same kernel runs
+in the Pallas interpreter (tests); it does not silently fall back to
 another implementation.
 """
 
 from __future__ import annotations
 
 import functools
-from typing import Optional
+import math
+from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -33,92 +60,252 @@ from jax.experimental.pallas import tpu as pltpu
 from .pallas_common import _LANES, resolve_interpret, round_up
 
 _NEG_INF = -1e30
+# Rows a grid step scores at once (its heads stacked) and keys a chunk
+# of its walk: the largest that divide the lengths, up to these.
+_MAX_ROWS = 512
+_MAX_BLOCK_K = 1024
+# The backward's key chunk; not the forward's block.
+_BWD_BLOCK_K = 128
+_VMEM_FLOOR = 16 << 20        # v5e's default scoped limit
+_VMEM_CEILING = 96 << 20      # of 128 MiB
 
 
-def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
-                m_ref, num_ref, den_ref, *,
-                scale: float, causal: bool, block_q: int, block_k: int):
-    """One (batch·head, q-block, k-block) grid step."""
-    kj = pl.program_id(2)
-    nk = pl.num_programs(2)
-    qi = pl.program_id(1)
-    q_start = qi * block_q
+class Plan(NamedTuple):
+    """What one forward call does; static in the shapes."""
+    block_q: int
+    block_k: int
+    heads: int              # heads a grid step, stacked along the rows
+    lanes: int              # lanes a grid step: heads * d, or d
+    lane_packed: bool       # [B, T, H * D] as it stands, or [B * H, T, D]
+    grid: Tuple[int, int, int]
+    steps: int              # grid steps a call
+    dead_steps: int         # ... of which do no arithmetic
+    chunks: int             # key chunks walked a call, over all steps
+    masked_chunks: int      # ... of which build the causal mask
+    vmem_limit_bytes: int
 
-    @pl.when(kj == 0)
-    def _init():
-        m_ref[:] = jnp.full_like(m_ref, _NEG_INF)
-        num_ref[:] = jnp.zeros_like(num_ref)
-        den_ref[:] = jnp.zeros_like(den_ref)
 
-    # Causal: blocks whose first key position exceeds the last query
-    # position contribute nothing — skip their compute entirely.
-    live = (not causal) or (kj * block_k <= q_start + block_q - 1)
+def _chosen_block(n: int, unit: int, cap: int) -> int:
+    """A block for a length of ``n``: ``n`` itself up to ``unit``;
+    beyond, the largest multiple of ``unit`` up to ``cap`` that divides
+    ``n``."""
+    if n <= unit:
+        return n
+    if n % unit:
+        raise ValueError(
+            f"sequence lengths must be multiples of the block sizes: {n} "
+            f"is over {unit} and no multiple of it; pad, or use "
+            f"flash_attention_padded for causal self-attention")
+    return max(b for b in range(unit, max(cap, unit) + 1, unit)
+               if n % b == 0)
 
-    @pl.when(live)
-    def _accumulate():
-        q = q_ref[0].astype(jnp.float32) * scale          # [bq, d]
-        k_blk = k_ref[0].astype(jnp.float32)              # [bk, d]
-        v_blk = v_ref[0].astype(jnp.float32)
-        s = jax.lax.dot_general(
+
+def plan(b: int, h: int, t: int, tk: int, d: int, dtype, causal: bool,
+         block_q: Optional[int] = None,
+         block_k: Optional[int] = None) -> Plan:
+    """The forward call for ``q [b, t, h, d]`` against ``tk`` keys.
+    ``block_q`` / ``block_k`` override the choice."""
+    if d % _LANES == 0:
+        heads, lane_packed = 1, True
+    elif _LANES % d == 0 and (h * d) % _LANES == 0:
+        heads, lane_packed = _LANES // d, True
+    else:
+        heads, lane_packed = 1, False
+    lanes = heads * d
+    if block_q is None:
+        block_q = _chosen_block(t, _LANES, _MAX_ROWS // heads)
+    if block_k is None:
+        # A causal walk ends on a block's edge: whole blocks of queries.
+        block_k = _chosen_block(tk, block_q if causal else _LANES,
+                                _MAX_BLOCK_K)
+    block_q, block_k = min(block_q, t), min(block_k, tk)
+    if t % block_q or tk % block_k:
+        raise ValueError(
+            f"sequence lengths ({t}, {tk}) must be multiples of the block "
+            f"sizes ({block_q}, {block_k}); pad, or use "
+            f"flash_attention_padded for causal self-attention")
+    if causal and t != tk:
+        raise ValueError("causal flash attention requires Tq == Tk")
+    if causal and block_q % block_k and block_k % block_q:
+        raise ValueError(
+            f"causal flash attention needs one of block_q, block_k "
+            f"({block_q}, {block_k}) to divide the other")
+    n_q = t // block_q
+    # Lane groups of a row of [B, T, H * D], or every head a row of its own.
+    rows_of_q, groups = (b, h * d // lanes) if lane_packed else (b * h, 1)
+    grid = (rows_of_q, groups, n_q)
+    if causal:
+        walked = sum(i * block_q // block_k + 1 for i in range(n_q))
+        masked = n_q
+    else:
+        walked, masked = n_q * (tk // block_k), 0
+    isz = jnp.dtype(dtype).itemsize
+    rows = heads * block_q
+    # Double-buffered blocks (q, o; K, V whole; lse a lane-padded row a
+    # query) and the step's own values (scores, probabilities and their
+    # rounded copy; stacked queries, numerator).
+    blocks = 2 * (2 * block_q * lanes * isz + 2 * tk * lanes * isz
+                  + rows * _LANES * 4)
+    values = rows * block_k * (8 + isz) + rows * lanes * (8 + isz)
+    vmem = min(max(blocks + 2 * values, _VMEM_FLOOR), _VMEM_CEILING)
+    per_block = rows_of_q * groups
+    return Plan(block_q, block_k, heads, lanes, lane_packed, grid,
+                per_block * n_q, 0, per_block * walked, per_block * masked,
+                vmem)
+
+
+def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *,
+                scale: float, causal: bool, block_q: int, block_k: int,
+                heads: int):
+    """One grid step: ``block_q`` queries of ``heads`` heads against
+    their live keys.  ``scale`` is positive."""
+    bq, bk = block_q, block_k
+    lanes = q_ref.shape[2]
+    d = lanes // heads
+    tk = k_ref.shape[1]
+    q = q_ref[0]                                          # [bq, lanes]
+    if heads > 1:
+        # Stack the heads along the rows, each with the others' lanes
+        # zeroed: one product with the keys then scores every head.
+        lane = lax.broadcasted_iota(jnp.int32, q.shape, 1)
+        q = jnp.concatenate(
+            [jnp.where((lane >= g * d) & (lane < (g + 1) * d), q,
+                       jnp.zeros_like(q)) for g in range(heads)], axis=0)
+    rows = heads * bq
+    # exp(scale * (s - m)) as one multiply and a power of two: the
+    # scale meets the float32 scores inside the exponent, and the max
+    # is taken of the raw scores (the same row for a positive scale).
+    log2e_scale = scale * math.log2(math.e)
+
+    def chunk(start, width, carry, own=None):
+        """The keys ``[start, start + width)`` into the streaming
+        softmax.  ``own``: the chunk ends with the block's own ``bq``
+        keys, which take this mask; every key before them is live."""
+        keys = pl.ds(start, width)
+        k_blk = k_ref[0, keys, :]                         # [width, lanes]
+        v_blk = v_ref[0, keys, :]
+        s = lax.dot_general(
             q, k_blk, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)           # [bq, bk]
-        if causal:
-            qpos = q_start + lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            kpos = kj * block_k + lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(qpos >= kpos, s, _NEG_INF)
-        m = m_ref[:, 0]                                   # [bq]
-        m_new = jnp.maximum(m, jnp.max(s, axis=-1))
-        p = jnp.exp(s - m_new[:, None])
-        corr = jnp.exp(m - m_new)
-        num_ref[:] = num_ref[:] * corr[:, None] + jax.lax.dot_general(
-            p, v_blk, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        den_ref[:] = den_ref[:] * corr[:, None] + jnp.sum(
-            p, axis=-1)[:, None]
-        m_ref[:] = jnp.broadcast_to(m_new[:, None], m_ref.shape)
+            preferred_element_type=jnp.float32)           # [rows, width]
+        if own is not None:
+            last = jnp.where(own, s[:, width - bq:], _NEG_INF)
+            s = last if width == bq else jnp.concatenate(
+                [s[:, :width - bq], last], axis=1)
+        m_new = jnp.max(s, axis=-1, keepdims=True)
+        if carry is not None:
+            m, den, num = carry
+            m_new = jnp.maximum(m, m_new)
+        p = jnp.exp2((s - m_new) * log2e_scale)
+        den_c = jnp.sum(p, axis=-1, keepdims=True)
+        num_c = lax.dot_general(
+            p.astype(v_blk.dtype), v_blk, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)           # [rows, lanes]
+        if carry is None:
+            return m_new, den_c, num_c
+        corr = jnp.exp2((m - m_new) * log2e_scale)
+        return m_new, den * corr + den_c, num * corr + num_c
 
-    @pl.when(kj == nk - 1)
-    def _finalize():
-        den = den_ref[:, 0]
-        o_ref[0] = (num_ref[:] / den[:, None]).astype(o_ref.dtype)
-        lse_ref[0, :, 0] = m_ref[:, 0] + jnp.log(den)
+    def finish(carry):
+        m, den, num = carry
+        out = num * (1.0 / den)
+        lse = m * scale + jnp.log(den)
+        o = out[:bq]
+        if heads > 1:
+            lane = lax.broadcasted_iota(jnp.int32, o.shape, 1)
+            for g in range(1, heads):
+                o = jnp.where(lane >= g * d, out[g * bq:(g + 1) * bq], o)
+        o_ref[0] = o.astype(o_ref.dtype)
+        for g in range(heads):
+            lse_ref[0, g] = lse[g * bq:(g + 1) * bq]
+
+    def walk(first, count, carry):
+        return lax.fori_loop(
+            first, count,
+            lambda j, c: chunk(pl.multiple_of(j * bk, bk), bk, c), carry)
+
+    if not causal:
+        finish(walk(1, tk // bk, chunk(0, bk, None)))
+        return
+    # Causal.  The keys before the block's first query go by in chunks
+    # of bk with no mask, and the walk ends with one chunk that holds
+    # what is left up to the diagonal, the block's own keys last.  Its
+    # width is static, one of bk / bq cases by the block's place in its
+    # chunk; a sequence of one chunk is a plain softmax with no walk.
+    i = pl.program_id(2)
+    n_q = tk // bq
+    cases = min(max(1, bk // bq), n_q)
+    own = lax.broadcasted_iota(jnp.int32, (bq, bq), 0) \
+        >= lax.broadcasted_iota(jnp.int32, (bq, bq), 1)
+    own = jnp.concatenate([own] * heads, axis=0)
+    if n_q == cases:
+        start, carry = 0, None
+    else:
+        below = (i * bq) // bk
+        start = pl.multiple_of(below * bk, bk)
+        carry = walk(0, below, (
+            jnp.full((rows, 1), _NEG_INF, jnp.float32),
+            jnp.zeros((rows, 1), jnp.float32),
+            jnp.zeros((rows, lanes), jnp.float32)))
+    for c in range(cases):
+        def last(c=c):
+            finish(chunk(start, (c + 1) * bq, carry, own))
+        if cases == 1:
+            last()
+        else:
+            pl.when(i % cases == c)(last)
 
 
-def _flash_fwd(q3, k3, v3, *, scale, causal, block_q, block_k, interpret):
-    bh, t, d = q3.shape
-    tk = k3.shape[1]
-    grid = (bh, t // block_q, tk // block_k)
-    o, lse = pl.pallas_call(
+def _pack(x):
+    """``[B, T, H, D]`` as ``[B * H, T, D]``."""
+    b, t, h, d = x.shape
+    return x.transpose(0, 2, 1, 3).reshape(b * h, t, d)
+
+
+def _unpack(x3, b):
+    bh, t, d = x3.shape
+    return x3.reshape(b, bh // b, t, d).transpose(0, 2, 1, 3)
+
+
+def _flash_fwd(q, k, v, *, scale, causal, block_q, block_k, interpret):
+    """``q, k, v [B, T, H, D]`` to ``(o [B, T, H, D], lse [B, H, T])``."""
+    b, t, h, d = q.shape
+    tk = k.shape[1]
+    p = plan(b, h, t, tk, d, q.dtype, causal, block_q, block_k)
+    if p.lane_packed:
+        q3, k3, v3 = (x.reshape(b, x.shape[1], h * d) for x in (q, k, v))
+    else:
+        q3, k3, v3 = _pack(q), _pack(k), _pack(v)
+    n, _, width = q3.shape
+    bq, lanes = p.block_q, p.lanes
+    o3, lse = pl.pallas_call(
         functools.partial(_fwd_kernel, scale=scale, causal=causal,
-                          block_q=block_q, block_k=block_k),
-        grid=grid,
+                          block_q=bq, block_k=p.block_k, heads=p.heads),
+        grid=p.grid,
         in_specs=[
-            pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0)),
+            pl.BlockSpec((1, bq, lanes), lambda n, g, i: (n, i, g)),
+            # The group's keys and values whole: the index does not
+            # move with the query block, so they are copied once.
+            pl.BlockSpec((1, tk, lanes), lambda n, g, i: (n, 0, g)),
+            pl.BlockSpec((1, tk, lanes), lambda n, g, i: (n, 0, g)),
         ],
         out_specs=[
-            pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
+            pl.BlockSpec((1, bq, lanes), lambda n, g, i: (n, i, g)),
             # lse rides a trailing unit dim: TPU lowering requires the
             # last two block dims be (multiple-of-8, multiple-of-128) or
             # equal to the array dims; (block_q, 1) satisfies that where
-            # a rank-2 (1, block_q) block would not.
-            pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, i, 0)),
+            # a (1, block_q) block would not.
+            pl.BlockSpec((1, p.heads, bq, 1), lambda n, g, i: (n, g, i, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((bh, t, d), q3.dtype),
-            jax.ShapeDtypeStruct((bh, t, 1), jnp.float32),
+            jax.ShapeDtypeStruct((n, t, width), q.dtype),
+            jax.ShapeDtypeStruct((n, width // d, t, 1), jnp.float32),
         ],
-        scratch_shapes=[
-            pltpu.VMEM((block_q, _LANES), jnp.float32),   # running max
-            pltpu.VMEM((block_q, d), jnp.float32),        # numerator
-            pltpu.VMEM((block_q, _LANES), jnp.float32),   # denominator
-        ],
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=p.vmem_limit_bytes),
         interpret=interpret,
     )(q3, k3, v3)
-    return o, lse[..., 0]
+    o = o3.reshape(b, t, h, d) if p.lane_packed else _unpack(o3, b)
+    return o, lse.reshape(b, h, t)
 
 
 def _flash_bwd(q3, k3, v3, o3, lse, do3, *, scale, causal, block_k,
@@ -166,39 +353,47 @@ def _flash_bwd(q3, k3, v3, o3, lse, do3, *, scale, causal, block_k,
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
-def _flash3_lse(q3, k3, v3, scale, causal, block_q, block_k, interpret):
-    return _flash_fwd(q3, k3, v3, scale=scale, causal=causal,
+def _flash_lse(q, k, v, scale, causal, block_q, block_k, interpret):
+    return _flash_fwd(q, k, v, scale=scale, causal=causal,
                       block_q=block_q, block_k=block_k, interpret=interpret)
 
 
-def _flash3_lse_fwd(q3, k3, v3, scale, causal, block_q, block_k, interpret):
-    o, lse = _flash_fwd(q3, k3, v3, scale=scale, causal=causal,
-                        block_q=block_q, block_k=block_k, interpret=interpret)
-    return (o, lse), (q3, k3, v3, o, lse)
+def _flash_lse_fwd(q, k, v, *static):
+    o, lse = _flash_lse(q, k, v, *static)
+    return (o, lse), (q, k, v, o, lse)
 
 
-def _flash3_lse_bwd(scale, causal, block_q, block_k, interpret, res, cts):
-    q3, k3, v3, o3, lse = res
-    do3, dlse = cts
-    return _flash_bwd(q3, k3, v3, o3, lse, do3, scale=scale, causal=causal,
-                      block_k=block_k, dlse=dlse)
+def _flash_lse_bwd(scale, causal, block_q, block_k, interpret, res, cts):
+    q, k, v, o, lse = res
+    do, dlse = cts
+    b, t, h, _ = q.shape
+    tk = k.shape[1]
+    chunk = tk if tk <= _BWD_BLOCK_K else math.gcd(tk, _BWD_BLOCK_K)
+    grads = _flash_bwd(_pack(q), _pack(k), _pack(v), _pack(o),
+                       lse.reshape(b * h, t), _pack(do), scale=scale,
+                       causal=causal, block_k=chunk,
+                       dlse=dlse.reshape(b * h, t))
+    return tuple(_unpack(g, b) for g in grads)
 
 
-_flash3_lse.defvjp(_flash3_lse_fwd, _flash3_lse_bwd)
+_flash_lse.defvjp(_flash_lse_fwd, _flash_lse_bwd)
 
 
 def flash_attention(q, k, v, *, causal: bool = False,
                     scale: Optional[float] = None,
-                    block_q: int = 128, block_k: int = 128,
+                    block_q: Optional[int] = None,
+                    block_k: Optional[int] = None,
                     interpret: Optional[bool] = None):
     """Flash attention; same contract as
     :func:`horovod_tpu.parallel.ring_attention.full_attention`:
     q/k/v ``[B, T, H, D]`` → ``[B, T, H, D]``, differentiable.
 
-    Sequence lengths must divide the block sizes; for causal self-
-    attention :func:`flash_attention_padded` accepts any length.
-    ``interpret`` defaults to True off-TPU so the same kernel runs under
-    the CPU test mesh.
+    The block sizes are chosen from the shapes (:func:`plan`);
+    ``block_q`` / ``block_k`` override the choice.  A sequence length
+    is at most 128 or a multiple of 128 (of the blocks given); for
+    causal self-attention :func:`flash_attention_padded` accepts any
+    length.  ``interpret`` defaults to True off-TPU so the same kernel
+    runs under the CPU test mesh.
     """
     # The kernel emits lse unconditionally; dropping it here gives it a
     # zero cotangent, which folds into the backward as a no-op.
@@ -210,7 +405,8 @@ def flash_attention(q, k, v, *, causal: bool = False,
 
 def flash_attention_with_lse(q, k, v, *, causal: bool = False,
                              scale: Optional[float] = None,
-                             block_q: int = 128, block_k: int = 128,
+                             block_q: Optional[int] = None,
+                             block_k: Optional[int] = None,
                              interpret: Optional[bool] = None):
     """Like :func:`flash_attention` but also returns the per-row
     logsumexp ``[B, H, T]`` (float32) — the merge key that lets callers
@@ -218,47 +414,39 @@ def flash_attention_with_lse(q, k, v, *, causal: bool = False,
     per-block engine).  Differentiable in both outputs."""
     if q.ndim != 4:
         raise ValueError(f"expected [B, T, H, D] inputs, got {q.shape}")
-    b, t, h, d = q.shape
-    tk = k.shape[1]
     if scale is None:
-        scale = d ** -0.5
-    block_q = min(block_q, t)
-    block_k = min(block_k, tk)
-    if t % block_q or tk % block_k:
-        raise ValueError(
-            f"sequence lengths ({t}, {tk}) must be multiples of the block "
-            f"sizes ({block_q}, {block_k}); pad, or use "
-            f"flash_attention_padded for causal self-attention")
-    if causal and t != tk:
-        raise ValueError("causal flash attention requires Tq == Tk")
-    interpret = resolve_interpret(interpret)
+        scale = q.shape[-1] ** -0.5
+    if scale < 0:
+        # The kernel takes the row's max of the raw scores.
+        q, scale = -q, -scale
+    return _flash_lse(q, k, v, float(scale), bool(causal), block_q, block_k,
+                      bool(resolve_interpret(interpret)))
 
-    def pack(x):
-        tb = x.shape[1]
-        return x.transpose(0, 2, 1, 3).reshape(b * h, tb, d)
 
-    o3, lse3 = _flash3_lse(pack(q), pack(k), pack(v), float(scale),
-                           bool(causal), int(block_q), int(block_k),
-                           bool(interpret))
-    o = o3.reshape(b, h, t, d).transpose(0, 2, 1, 3)
-    return o, lse3.reshape(b, h, t)
+def padded_length(t: int, block_q: Optional[int] = None,
+                  block_k: Optional[int] = None) -> int:
+    """The length :func:`flash_attention_padded` runs a sequence of
+    ``t`` at: the next multiple of 128 (of the larger block, where
+    blocks are given), or of 8 below one block."""
+    blk = max(block_q or _LANES, block_k or _LANES)
+    return round_up(t, blk if t >= blk else 8)
 
 
 def flash_attention_padded(q, k, v, *, scale: Optional[float] = None,
-                           block_q: int = 128, block_k: int = 128,
+                           block_q: Optional[int] = None,
+                           block_k: Optional[int] = None,
                            interpret: Optional[bool] = None):
     """Causal self-attention for arbitrary sequence length: pads T up to
-    a block multiple, runs the kernel, slices back.  Safe exactly
-    because the attention is causal — padded key positions sit after
-    every real query position, so the mask removes them."""
+    a multiple of 128 (of the larger block, where blocks are given; of 8
+    below one block), runs the kernel, slices back.  The blocks are then
+    chosen to divide the padded length, so no length does more work for
+    the larger blocks.  Safe exactly because the attention is causal —
+    padded key positions sit after every real query position, so the
+    mask removes them."""
     b, t, h, d = q.shape
     if k.shape[1] != t:
         raise ValueError("flash_attention_padded is self-attention only")
-    blk = max(block_q, block_k)
-    if t >= blk:
-        tp = round_up(t, blk)            # round up to a block multiple
-    else:
-        tp = round_up(t, 8)              # short seq: one 8-aligned block
+    tp = padded_length(t, block_q, block_k)
     pad = tp - t
     cfg = dict(causal=True, scale=scale, block_q=block_q, block_k=block_k,
                interpret=interpret)

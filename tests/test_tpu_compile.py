@@ -53,9 +53,9 @@ def _compile(fn, *args):
     return text
 
 
-def _qkv(topo, t=T, b=B):
+def _qkv(topo, t=T, b=B, h=H, d=D):
     one = SingleDeviceSharding(topo.devices[0])
-    return (jax.ShapeDtypeStruct((b, t, H, D), jnp.bfloat16, sharding=one),
+    return (jax.ShapeDtypeStruct((b, t, h, d), jnp.bfloat16, sharding=one),
             ) * 3
 
 
@@ -80,13 +80,60 @@ def _flash_lse(q, k, v):
                                        interpret=False)
 
 
-@pytest.mark.parametrize("fn,t", [
-    (_flash_fwd, T), (_flash_fwd_bwd, T), (_flash_padded, 1000),
-    (_flash_padded, 37), (_flash_lse, T),
+# The attention layer of nemotron3nano-train-1chip: one row of 8,192
+# tokens, 32 heads of 128 (its 2 KV heads repeated).
+NEMOTRON = dict(b=1, h=32, d=128)
+
+
+@pytest.mark.parametrize("fn,t,shape", [
+    (_flash_fwd, T, {}), (_flash_fwd_bwd, T, {}), (_flash_padded, 1000, {}),
+    (_flash_padded, 37, {}), (_flash_lse, T, {}),
+    (_flash_fwd, 8192, NEMOTRON), (_flash_fwd_bwd, 8192, NEMOTRON),
 ], ids=["flash_fwd", "flash_fwd_bwd", "flash_padded_odd_t",
-        "flash_padded_short_t", "flash_with_lse_noncausal"])
-def test_flash_attention_compiles_for_v5e(topo, fn, t):
-    _compile(fn, *_qkv(topo, t))
+        "flash_padded_short_t", "flash_with_lse_noncausal",
+        "flash_fwd_nemotron", "flash_fwd_bwd_nemotron"])
+def test_flash_attention_compiles_for_v5e(topo, fn, t, shape):
+    _compile(fn, *_qkv(topo, t, **shape))
+
+
+def test_benchmark_still_finds_the_flash_kernel_on_v5e(topo, monkeypatch):
+    """``flash_fwd_roofline.train`` finds the forward kernel by the text
+    of its event, with the pattern that ``hvdbench/configs/
+    gpt2-medium.json`` states (read here, never edited): the ``attn``
+    custom-call whose result is ``(o, lse)``.  GPT-2 medium's forward at
+    the cell's 8 x 1024 tokens, compiled for the chip, has to hold one
+    such line a layer."""
+    import json
+
+    from horovod_tpu.models import GPT, GPTConfig
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "hvdbench", "configs",
+                           "gpt2-medium.json")) as f:
+        config = json.load(f)
+    run = config["run"]
+    # Off the chip the kernel would take the interpreter.
+    monkeypatch.setattr(pa, "resolve_interpret", lambda interpret: False)
+    model = GPT(GPTConfig(
+        vocab_size=config["vocab_size"], n_layer=config["n_layer"],
+        n_head=config["n_head"], d_model=config["n_embd"],
+        d_ff=config["n_inner"], max_seq_len=config["n_positions"],
+        attention=run["attention"],
+        dtype=jnp.dtype(run["activation_dtype"]),
+        param_dtype=jnp.dtype(run["param_dtype"])))
+    one = SingleDeviceSharding(topo.devices[0])
+    tokens = jax.ShapeDtypeStruct(
+        (run["rows_per_chip"], config["n_positions"]), jnp.int32,
+        sharding=one)
+    params = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one),
+        jax.eval_shape(model.init, jax.random.PRNGKey(0), tokens)["params"])
+    text = _compile(lambda p, t: model.apply({"params": p}, t), params,
+                    tokens)
+    pattern = re.compile(run["kernels"]["flash_fwd"]["match"])
+    found = [line for line in map(str.strip, text.splitlines())
+             if pattern.search(line)]
+    assert len(found) == config["n_layer"], found[:2]
 
 
 @pytest.mark.parametrize("block", [128, 256, 512])
