@@ -26,8 +26,10 @@ checked against their single sources of truth:
   derived wiring instead of failing loudly.
 * **Span names** (``span-name`` / ``span-doc-drift``).  Literal span
   names passed to the tracing layer (``trace.span("…")`` /
-  ``trace.record_span("…")`` / ``trace.instant("…")`` and the
-  compiled-program ``trace.scope("…")`` on any trace-module receiver, plus the ``_record_phase(req, "…", …)``
+  ``trace.record_span("…")`` / ``trace.instant("…")``, the
+  profiler-only ``trace.annotate("…")`` and the compiled-program
+  ``trace.scope("…")`` on any trace-module receiver, plus the
+  ``_record_phase(req, "…", …)``
   span-forwarding helper convention) must carry the ``hvd_tpu_`` prefix
   and have a
   row in the ``docs/tracing.md`` span catalog — ``trace_merge``'s
@@ -246,7 +248,7 @@ class MetricNameChecker(Checker):
 class SpanNameChecker(Checker):
     checks = ("span-name", "span-doc-drift")
 
-    _FUNCS = ("span", "record_span", "instant", "scope")
+    _FUNCS = ("span", "record_span", "instant", "scope", "annotate")
     _FORWARDER = "_record_phase"
 
     def __init__(self, cfg: LintConfig) -> None:

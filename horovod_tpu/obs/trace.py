@@ -42,7 +42,8 @@ With no profiler session live that is one flag test; with one live the
 span lands on the ``/host:CPU`` plane of the same ``.xplane.pb`` as the
 device's ``XLA Ops``, so the program's spans and the device's
 operations share the profiler's clock by construction.
-:func:`record_span` (after-the-fact spans) stays ring-only.
+:func:`record_span` (after-the-fact spans) stays ring-only, and
+:func:`annotate` (a phase inside a span) is the annotation alone.
 :func:`scope` is the same idea inside a compiled program: a
 ``jax.named_scope`` that names a region of the step in every
 operation's metadata (docs/tracing.md).
@@ -65,8 +66,9 @@ from typing import Any, Dict, Iterable, List, Optional, Sequence, Tuple
 
 __all__ = [
     "enabled", "configure", "span", "record_span", "instant", "current",
-    "new_context", "use_context", "process_rank", "scope",
-    "now_us", "mono_us", "inject", "extract", "snapshot", "clear",
+    "new_context", "use_context", "process_rank", "scope", "annotate",
+    "now_us", "mono_us", "inject", "extract", "snapshot", "recent",
+    "clear",
     "estimate_clock_offset", "merge_traces", "unresolved_parents",
     "critical_path", "trace_ids", "dump_merged",
 ]
@@ -255,6 +257,21 @@ def _annotation(name: str, args: Optional[Dict[str, Any]]):
         return contextlib.nullcontext()
 
 
+def annotate(name: str):
+    """A phase inside a span, for the profiler alone: a
+    ``TraceAnnotation`` and nothing else — no ids, no ring entry, no
+    Timeline slice.  A decode step's dispatch and fence are entered so:
+    with a profiler session live they lie on ``/host:CPU`` inside their
+    span, on the clock of ``XLA Ops``; the ring holds their lengths as
+    the span's args, where three more entries a step would wash a
+    window's opening out of it (docs/tracing.md).  The annotation
+    begins where it is made, not where it is entered: make it in the
+    ``with`` statement, the call's arguments built beforehand."""
+    if not enabled():
+        return contextlib.nullcontext()
+    return _annotation(name, None)
+
+
 def scope(name: str):
     """A named region INSIDE a compiled program: ``jax.named_scope``,
     so every operation traced in the block carries ``name`` in its
@@ -414,6 +431,25 @@ def snapshot(clear: bool = False) -> List[Dict[str, Any]]:
         out = [dict(r) for r in _ring]
         if clear:
             _ring.clear()
+    return out
+
+
+def recent(name: str, last: int,
+           since_us: float = 0.0) -> List[Dict[str, Any]]:
+    """The newest ``last`` spans called ``name`` that began at or after
+    ``since_us``, oldest first.  The ring is walked from its newest end
+    until they are found and only they are copied: ``snapshot()``
+    copies 16,384 records, some 10 ms under the lock that every span's
+    exit takes, which a reader polled beside a serving step
+    (``InferenceEngine.kv_stats``) must not hold for so long."""
+    out: List[Dict[str, Any]] = []
+    with _lock:
+        for rec in reversed(_ring):
+            if len(out) == last:
+                break
+            if rec["name"] == name and rec["start_us"] >= since_us:
+                out.append(dict(rec))
+    out.reverse()
     return out
 
 

@@ -78,7 +78,7 @@ import collections
 import dataclasses
 import threading
 import time
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -87,7 +87,9 @@ from jax.sharding import NamedSharding, PartitionSpec
 
 from ..models.transformer import (GPT, cache_kinds, init_kv_cache,
                                   init_state_cache)
+from ..obs import flight as flight_mod
 from ..obs import trace as trace_mod
+from ..obs.metrics import percentile
 from ..ops.pallas_common import _LANES, round_up
 from ..utils.logging import get_logger
 from .kv import BlockPool, TRASH_BLOCK
@@ -171,6 +173,19 @@ def _advance(step, logits):
     return {"key": key,
             "tokens": jnp.where(active, nxt, step["tokens"]),
             "positions": step["positions"] + active.astype(jnp.int32)}
+
+
+# A phase of a decode step (``prepare``, ``dispatch``, ``fence``) or of
+# a prefill (``dispatch``, ``fence``) that took more than
+# ``_STALL_TIMES`` its usual length and ``_STALL_US`` microseconds over
+# it makes the step a stalled one.  A fence holds the device's own
+# work, 10-25 ms of a decode step: both together catch a fence of
+# 120 ms and no step that is merely slow (PERF.md section 7).
+_STALL_TIMES = 4
+_STALL_US = 50_000
+_DECODE_PHASES = ("prepare", "dispatch", "fence")
+# ``kv_stats()`` reads the dispatch and the fence of this many steps.
+_RECENT_STEPS = 1024
 
 
 # What ``InferenceEngine._wake_runtime`` sends: never written to.
@@ -341,9 +356,18 @@ class InferenceEngine:
         self.step_state_uploads = 0    # the constructor's is not counted
         self.staged_uploads = 0
         self.runtime_pokes = 0
-        self._dispatch_took = 0.0
-        self._dispatch_usual = None
         self._pokes = ()
+        # Where a decode step's host time went (docs/serving.md "The
+        # step protocol"): the dispatch call's two readings of the span
+        # clock, each phase's usual length in microseconds (``_follow``;
+        # a prefill's by its bucket; batcher thread only), and the
+        # steps and prefills that a phase held up.  ``kv_stats()`` reads
+        # the spans this engine has put into the ring since now.
+        self._dispatched = (0, 0)
+        self._usual: Dict[Any, float] = {}
+        self.stalled_steps = collections.Counter()
+        self.stalled_prefills = collections.Counter()
+        self._born_us = trace_mod.now_us()
         # Weight hot-swap state (serve/swap.py; docs/hot_swap.md): the
         # running version (the checkpoint step the params came from —
         # 0 for boot weights that never touched the store) and the
@@ -765,16 +789,19 @@ class InferenceEngine:
             padded = np.zeros((1, L), np.int32)
             padded[0, :ns] = np.asarray(seq[pos:pos + ns], np.int32)
             span_args["bucket"] = L     # the last chunk's
-            token, self._states, self._rng = self._prefill_fns[L](
-                self._params, self._states, jnp.asarray(padded),
-                jnp.int32(pos), jnp.int32(ns), jnp.int32(slot),
-                self._rng, jnp.float32(sampling.temperature),
-                jnp.int32(sampling.top_k))
+            args = (self._params, self._states, jnp.asarray(padded),
+                    jnp.int32(pos), jnp.int32(ns), jnp.int32(slot),
+                    self._rng, jnp.float32(sampling.temperature),
+                    jnp.int32(sampling.top_k))
+            with trace_mod.annotate("hvd_tpu_prefill_dispatch"):
+                token, self._states, self._rng = self._timed(
+                    span_args, "dispatch_us", self._prefill_fns[L], *args)
             pos += ns
         self.state_resets += 1
         if stage:
             self._stage_bind(slot, n, sampling)
-        return int(token)
+        with trace_mod.annotate("hvd_tpu_prefill_fence"):
+            return self._timed(span_args, "fence_us", int, token)
 
     # --- compiled programs: speculative tier --------------------------------
 
@@ -1008,11 +1035,16 @@ class InferenceEngine:
         token.  One compiled program per (bucket, slot-batch) shape —
         on the paged tier the bucket covers only the non-resident
         suffix.  The whole call is one ``hvd_tpu_engine_prefill``
-        span."""
+        span: ``args.dispatch_us`` and ``args.fence_us`` say how long
+        the program's dispatch and the wait for its token took,
+        ``args.stalled`` which of them held a stalled prefill."""
         span_args = {"slot": int(slot), "prompt_len": len(prompt),
                      "cache": self.kv_mode}
         with trace_mod.span("hvd_tpu_engine_prefill", args=span_args):
-            return self._start(slot, prompt, sampling, span_args)
+            token = self._start(slot, prompt, sampling, span_args)
+            if trace_mod.enabled():
+                self._note_prefill(span_args)
+            return token
 
     def _start(self, slot: int, prompt: Sequence[int],
                sampling: SamplingParams, span_args: dict) -> int:
@@ -1036,14 +1068,17 @@ class InferenceEngine:
             padded[0, :ns] = np.asarray(prompt[hit:], np.int32)
             fn = self._prefill_fns[L]
             span_args.update(bucket=L, prefix_hit=hit)
-            token, self._pools, self._rng = fn(
-                self._params, self._pools,
-                jnp.asarray(self._table[slot]), jnp.asarray(padded),
-                jnp.int32(hit), jnp.int32(ns), self._rng,
-                jnp.float32(sampling.temperature),
-                jnp.int32(sampling.top_k))
+            args = (self._params, self._pools,
+                    jnp.asarray(self._table[slot]), jnp.asarray(padded),
+                    jnp.int32(hit), jnp.int32(ns), self._rng,
+                    jnp.float32(sampling.temperature),
+                    jnp.int32(sampling.top_k))
+            with trace_mod.annotate("hvd_tpu_prefill_dispatch"):
+                token, self._pools, self._rng = self._timed(
+                    span_args, "dispatch_us", fn, *args)
             self._stage_bind(slot, n, sampling)
-            token = int(token)
+            with trace_mod.annotate("hvd_tpu_prefill_fence"):
+                token = self._timed(span_args, "fence_us", int, token)
             self._kv.index_prompt(slot, prompt)
         else:
             hit = 0
@@ -1052,13 +1087,16 @@ class InferenceEngine:
             padded[0, :n] = np.asarray(prompt, np.int32)
             fn = self._prefill_fns[L]
             span_args.update(bucket=L, prefix_hit=0)
-            token, self._caches, self._rng = fn(
-                self._params, self._caches, jnp.asarray(padded),
-                jnp.int32(n), jnp.int32(slot), self._rng,
-                jnp.float32(sampling.temperature),
-                jnp.int32(sampling.top_k))
+            args = (self._params, self._caches, jnp.asarray(padded),
+                    jnp.int32(n), jnp.int32(slot), self._rng,
+                    jnp.float32(sampling.temperature),
+                    jnp.int32(sampling.top_k))
+            with trace_mod.annotate("hvd_tpu_prefill_dispatch"):
+                token, self._caches, self._rng = self._timed(
+                    span_args, "dispatch_us", fn, *args)
             self._stage_bind(slot, n, sampling)
-            token = int(token)
+            with trace_mod.annotate("hvd_tpu_prefill_fence"):
+                token = self._timed(span_args, "fence_us", int, token)
         if self._drafter is not None:
             # The drafter recomputes the full prompt (its dense cache
             # shares nothing) — it is the small model by construction.
@@ -1071,6 +1109,18 @@ class InferenceEngine:
         self._bind_slot(slot, n, token, sampling, hit)
         return token
 
+    @staticmethod
+    def _timed(span_args: dict, key: str, fn, *args):
+        """``fn(*args)``, the call's microseconds on the span clock
+        added to ``span_args[key]``: a prefill's dispatch (summed over
+        the chunks of a prompt that goes in several) and the fence on
+        its token."""
+        t = time.monotonic_ns()
+        out = fn(*args)
+        span_args[key] = (span_args.get(key, 0.0)
+                          + (time.monotonic_ns() - t) / 1e3)
+        return out
+
     def step(self) -> Dict[int, List[int]]:
         """One decode step for every active slot → ``{slot: [tokens]}``
         (one token per slot on the plain path; up to ``spec_k + 1``
@@ -1078,7 +1128,9 @@ class InferenceEngine:
         and write into the trash block.  A step with an active slot is
         one ``hvd_tpu_engine_decode`` span: the host's table building,
         whatever it had to upload (``args.uploads``), the dispatch, the
-        device's work and the token fence."""
+        device's work and the token fence — ``args.prepare_us``,
+        ``args.dispatch_us`` and ``args.fence_us`` say how long each
+        took, ``args.stalled`` which of them held a stalled step."""
         snap = self._slot_snapshot()
         active = [int(s) for s in np.nonzero(snap[0])[0]]
         if not active:
@@ -1093,6 +1145,11 @@ class InferenceEngine:
         if self._drafter is not None and any(
                 spec[s] and temps[s] <= 0 for s in active):
             return self._step_spec(active, snap)
+        # With tracing on the step says where its time went; off, the
+        # dispatch alone is timed (``_wake_runtime`` needs it).
+        traced = trace_mod.enabled()
+        if traced:
+            began = time.monotonic_ns()
         # A steady step uploads nothing: the device holds the slots'
         # state as the last step left it, and the table as last sent.
         uploads = 0
@@ -1131,9 +1188,22 @@ class InferenceEngine:
         self.sampling_steps += sampling
         span_args["sampling"] = sampling
         span_args["uploads"] = uploads
-        self._wake_runtime(span_args)
+        at, returned = self._dispatched
+        dispatch_us = (returned - at) / 1e3
+        dispatch_usual = self._follow("dispatch", dispatch_us)
+        self._wake_runtime(span_args, dispatch_us, dispatch_usual)
         # The fence: the state's tokens are what the step sampled.
-        nxt = np.asarray(advanced["tokens"])
+        if traced:
+            with trace_mod.annotate("hvd_tpu_decode_fence"):
+                t = time.monotonic_ns()
+                nxt = np.asarray(advanced["tokens"])
+                fence_us = (time.monotonic_ns() - t) / 1e3
+            self._note_phases(
+                span_args, {"prepare": (at - began) / 1e3,
+                            "dispatch": dispatch_us, "fence": fence_us},
+                dispatch_usual)
+        else:
+            nxt = np.asarray(advanced["tokens"])
         held = self._device_slots
         self._device_slots = dict(
             held, tokens=nxt, positions=held["positions"] + held["active"])
@@ -1145,13 +1215,77 @@ class InferenceEngine:
         return out
 
     def _dispatch_decode(self, *args):
-        """The decode program's dispatch, and how long the call took."""
-        t = time.perf_counter()
-        out = self._decode_fn(*args)
-        self._dispatch_took = time.perf_counter() - t
+        """The decode program's dispatch, and when the call was made
+        and returned on the span clock (``_dispatched``)."""
+        with trace_mod.annotate("hvd_tpu_decode_dispatch"):
+            at = time.monotonic_ns()
+            out = self._decode_fn(*args)
+            self._dispatched = (at, time.monotonic_ns())
         return out
 
-    def _wake_runtime(self, span_args: dict) -> None:
+    def _follow(self, phase, took_us: float) -> Optional[float]:
+        """Moves ``phase``'s usual length towards this reading — down
+        at once, up by a twentieth and by no more than if the reading
+        were twice the usual, so one long step hardly moves it and a
+        host that has become slower for good is followed within some
+        tens — and returns what it was before (None the first time)."""
+        usual = self._usual.get(phase)
+        if usual is None or took_us < usual:
+            self._usual[phase] = took_us
+        else:
+            self._usual[phase] = usual + 0.05 * (
+                min(took_us, 2 * usual) - usual)
+        return usual
+
+    def _note_phases(self, span_args: dict, took: Dict[str, float],
+                     dispatch_usual: Optional[float]) -> None:
+        """A traced step's phases (microseconds; the dispatch's usual
+        length as it was before this step) onto its span, and the step
+        held to the stall rule."""
+        for phase, us in took.items():
+            span_args[phase + "_us"] = us
+        usual = {"prepare": self._follow("prepare", took["prepare"]),
+                 "dispatch": dispatch_usual,
+                 "fence": self._follow("fence", took["fence"])}
+        self._note_stall(span_args, took, usual, self.stalled_steps,
+                         "slow_decode_step", active=span_args["active"],
+                         uploads=span_args["uploads"])
+
+    def _note_prefill(self, span_args: dict) -> None:
+        """A traced prefill's dispatch and fence held to the stall rule
+        of a decode step's, each bucket against its own usual lengths:
+        the fence holds the device's prefill of that many positions.
+        (A pause of 110 ms is named where the phase usually takes
+        under 37.)"""
+        took = {phase: span_args[phase + "_us"]
+                for phase in ("dispatch", "fence")}
+        usual = {phase: self._follow((phase, span_args["bucket"]), us)
+                 for phase, us in took.items()}
+        self._note_stall(span_args, took, usual, self.stalled_prefills,
+                         "slow_prefill", bucket=span_args["bucket"],
+                         prompt_len=span_args["prompt_len"])
+
+    @staticmethod
+    def _note_stall(span_args: dict, took: Dict[str, float],
+                    usual: Dict[str, Optional[float]],
+                    count: collections.Counter, event: str,
+                    **what) -> None:
+        """The stall rule (``_STALL_TIMES``, ``_STALL_US``): the first
+        phase that took that much longer than its usual length names
+        the span a stalled one — ``args.stalled``, ``count`` and one
+        ``event`` in the flight ring."""
+        for phase, us in took.items():
+            was = usual[phase]
+            if (was is not None and us > _STALL_TIMES * was
+                    and us - was >= _STALL_US):
+                span_args["stalled"] = phase
+                count[phase] += 1
+                flight_mod.record(event, phase=phase, us=round(us, 1),
+                                  usual_us=round(was, 1), **what)
+                break
+
+    def _wake_runtime(self, span_args: dict, took_us: float,
+                      usual_us: Optional[float]) -> None:
         """After a dispatch that took far longer than they do.  On some
         hosts the runtime, while little goes through it, answers every
         call about a millisecond late — the dispatch, a transfer, the
@@ -1160,15 +1294,10 @@ class InferenceEngine:
         each dispatch left within three (PERF.md section 6, PR 30).  So
         send it a few small transfers now, between the dispatch and
         the fence: the device computes, the host would only wait, and
-        the step is no longer for them.  ``_dispatch_usual`` follows
-        the call's time down at once and up slowly, so a host that has
-        become slower for good stops being poked."""
-        took, usual = self._dispatch_took, self._dispatch_usual
-        if usual is None or took < usual:
-            self._dispatch_usual = took
-            return
-        self._dispatch_usual = usual + 0.05 * (min(took, 2 * usual) - usual)
-        if took > 1.8 * usual:
+        the step is no longer for them.  The dispatch's usual length
+        follows the call's down at once and up slowly (``_follow``), so
+        a host that has become slower for good stops being poked."""
+        if usual_us is not None and took_us > 1.8 * usual_us:
             self._pokes = [self._to_device(_POKE) for _ in range(_POKES)]
             self.runtime_pokes += 1
             span_args["poked"] = True
@@ -1593,7 +1722,19 @@ class InferenceEngine:
         while the device computed), ``runtime_pokes`` (steps whose
         dispatch was slow enough to send the runtime small transfers
         behind the device's work) and, where there is a block table,
-        ``table_uploads`` (times it was sent: it had changed).  The
+        ``table_uploads`` (times it was sent: it had changed).  Where a
+        step's host time went, with tracing on: ``dispatch_ms_p50`` /
+        ``_p99`` and ``fence_ms_p50`` / ``_p99`` (the decode program's
+        dispatch call; the wait for its tokens, which holds the
+        device's own step), read here from the ``hvd_tpu_engine_decode``
+        spans that the process's span ring holds of this engine's last
+        1,024 steps, and ``stalled_steps`` with ``stalled_prepare``,
+        ``stalled_dispatch`` and ``stalled_fence``: steps in which that
+        phase took over four times its usual length and 50 ms more
+        than it (each is also a ``slow_decode_step`` event in the
+        flight ring); ``stalled_prefills``: prefills whose dispatch or
+        fence did, against the usual of their bucket
+        (``slow_prefill``).  The
         paged pool's blocks, hits and
         evictions, and how far its decode steps walked the block table:
         ``paged_decode_steps`` (decode steps whose attention walked
@@ -1612,7 +1753,19 @@ class InferenceEngine:
                      "sampling_steps": self.sampling_steps,
                      "step_state_uploads": self.step_state_uploads,
                      "staged_uploads": self.staged_uploads,
-                     "runtime_pokes": self.runtime_pokes}
+                     "runtime_pokes": self.runtime_pokes,
+                     "stalled_steps": sum(self.stalled_steps.values()),
+                     "stalled_prefills": sum(
+                         self.stalled_prefills.values())}
+        for phase in _DECODE_PHASES:
+            out["stalled_" + phase] = self.stalled_steps[phase]
+        recent = [s["args"] for s in trace_mod.recent(
+            "hvd_tpu_engine_decode", _RECENT_STEPS, self._born_us)
+                  if "dispatch_us" in s["args"]]
+        for phase in ("dispatch", "fence"):
+            ms = [a[phase + "_us"] / 1e3 for a in recent]
+            out[phase + "_ms_p50"] = percentile(ms, 50)
+            out[phase + "_ms_p99"] = percentile(ms, 99)
         if self._kv is not None:
             out.update(self._kv.stats())
             out["table_uploads"] = self.table_uploads

@@ -582,6 +582,25 @@ def test_span_doc_drift(tmp_path):
     assert checks_of(fs) == ["span-doc-drift"]
 
 
+def test_span_rules_cover_annotate(tmp_path):
+    # A phase entered for the profiler alone is named like a span.
+    src = (
+        "from ..obs import trace as trace_mod\n\n"
+        "def step():\n"
+        '    with trace_mod.annotate("hvd_tpu_phase_documented"):\n'
+        "        pass\n"
+        '    with trace_mod.annotate("hvd_tpu_phase_new"):\n'
+        "        pass\n"
+        '    with trace_mod.annotate("bare_phase"):\n'
+        "        pass\n"
+    )
+    fs = lint(tmp_path, {"m.py": src}, [SpanNameChecker],
+              docs={"tracing.md": "hvd_tpu_phase_documented"})
+    assert checks_of(fs) == ["span-doc-drift", "span-name"]
+    assert "hvd_tpu_phase_new" in fs[0].message
+    assert "bare_phase" in fs[1].message
+
+
 def test_span_rules_ignore_non_trace_receivers(tmp_path):
     # Timeline-style .span()/.record() lookalikes on other receivers
     # carry free-form names and are not held to span rules.

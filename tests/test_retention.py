@@ -404,6 +404,9 @@ def test_the_default_engine_of_a_retention_model_holds_a_state(model,
     assert stats == {
         "decode_steps": 0, "sampling_steps": 0, "step_state_uploads": 0,
         "staged_uploads": 0, "runtime_pokes": 0,
+        "stalled_steps": 0, "stalled_prepare": 0, "stalled_dispatch": 0,
+        "stalled_fence": 0, "stalled_prefills": 0, "dispatch_ms_p50": None,
+        "dispatch_ms_p99": None, "fence_ms_p50": None, "fence_ms_p99": None,
         "state_bytes": 8 * L * K * (d // 2 + 1) * d * (d + 1) * 4,
         "state_slots_touched": 8, "state_resets": 0}
     assert engine.prefix_probe(_tokens(5)) == 0
@@ -472,9 +475,11 @@ def test_the_prefill_span_names_its_cache(model, params):
     engine.start(1, _tokens(9), SamplingParams())
     spans = [s for s in trace.snapshot()
              if s["name"] == "hvd_tpu_engine_prefill"]
-    assert spans[-1]["args"] == {"slot": 1, "prompt_len": 9,
-                                 "cache": "state", "prefix_hit": 0,
-                                 "bucket": 16}
+    args = dict(spans[-1]["args"])
+    # How long the prefill's dispatch and the fence on its token took.
+    assert args.pop("dispatch_us") > 0 and args.pop("fence_us") > 0
+    assert args == {"slot": 1, "prompt_len": 9, "cache": "state",
+                    "prefix_hit": 0, "bucket": 16}
 
 
 def test_the_scopes_are_in_the_compiled_programs(model, params):

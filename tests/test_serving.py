@@ -812,23 +812,25 @@ class TestStepState:
         eng = engine_of("dense")
         eng.start(0, [1, 2, 3], SamplingParams(max_new_tokens=40))
         eng.step()                      # the first dispatch builds
-        eng._dispatch_usual = None
+        del eng._usual["dispatch"]
         args = {}
-        for took in (1.0e-3, 1.2e-3, 0.9e-3, 1.1e-3):
-            eng._dispatch_took = took
-            eng._wake_runtime(args)
-        assert eng._dispatch_usual == pytest.approx(0.91e-3, rel=0.02)
+
+        def dispatched(took_us):
+            eng._wake_runtime(args, took_us,
+                              eng._follow("dispatch", took_us))
+
+        for took in (1000.0, 1200.0, 900.0, 1100.0):
+            dispatched(took)
+        assert eng._usual["dispatch"] == pytest.approx(910.0, rel=0.02)
         assert eng.runtime_pokes == 0 and "poked" not in args
-        eng._dispatch_took = 2.4e-3                 # the slow mode
-        eng._wake_runtime(args)
+        dispatched(2400.0)                          # the slow mode
         assert eng.runtime_pokes == 1 and args["poked"]
         assert len(eng._pokes) == 8
         jax.block_until_ready(eng._pokes)
         for _ in range(40):                         # a host slower for good
-            eng._dispatch_took = 2.4e-3
-            eng._wake_runtime({})
+            dispatched(2400.0)
         assert eng.runtime_pokes < 20
-        assert eng._dispatch_usual > 1.4e-3
+        assert eng._usual["dispatch"] > 1400.0
         before = eng.runtime_pokes
         out = step_state_oracle.step(eng)           # and steps go on
         assert sorted(out) == [0]
@@ -887,6 +889,250 @@ class TestStepState:
         dense.step()
         assert "table_uploads" not in dense.kv_stats()
         assert dense.kv_stats()["step_state_uploads"] == 1
+
+
+class TestStepPhases:
+    """ISSUE 40: a decode step says where its host time went — the
+    dispatch call, the wait for the tokens and what came before, as
+    args of ``hvd_tpu_engine_decode`` on the span clock — and a step
+    that one of them held up names it."""
+
+    @pytest.fixture(autouse=True)
+    def _traced(self):
+        from horovod_tpu.obs import flight, trace
+
+        trace.configure(enabled=True)
+        flight.configure(enabled=True)
+        trace.clear()
+        yield
+        trace.configure(enabled=True)
+        trace.clear()
+
+    @staticmethod
+    def _spans(name):
+        from horovod_tpu.obs import trace
+
+        return [s for s in trace.snapshot() if s["name"] == name]
+
+    @pytest.mark.parametrize("cache", CACHES)
+    def test_decode_and_prefill_spans_carry_their_phases(
+            self, engine_of, cache):
+        eng = engine_of(cache)
+        eng.start(0, [1, 2, 3], SamplingParams(max_new_tokens=20))
+        for _ in range(5):
+            eng.step()
+        decode = self._spans("hvd_tpu_engine_decode")
+        assert len(decode) == 5
+        for span in decode:
+            args = span["args"]
+            took = [args["prepare_us"], args["dispatch_us"],
+                    args["fence_us"]]
+            assert min(took) >= 0
+            assert sum(took) <= span["dur_us"]
+            assert "stalled" not in args
+        (prefill,) = self._spans("hvd_tpu_engine_prefill")
+        args = prefill["args"]
+        assert args["dispatch_us"] >= 0 and args["fence_us"] >= 0
+        assert "prepare_us" not in args
+        assert args["dispatch_us"] + args["fence_us"] <= prefill["dur_us"]
+        stats = eng.kv_stats()
+        assert stats["stalled_steps"] == stats["stalled_dispatch"] == 0
+        assert stats["stalled_prefills"] == 0
+        assert 0 < stats["dispatch_ms_p50"] <= stats["dispatch_ms_p99"]
+        assert 0 < stats["fence_ms_p50"] <= stats["fence_ms_p99"]
+
+    def test_a_speculative_step_carries_none(self, model_and_params,
+                                             engine_of):
+        eng = engine_of("paged", drafter=model_and_params, spec_k=2)
+        eng.start(0, [1, 2, 3], SamplingParams(max_new_tokens=20,
+                                               spec=True))
+        eng.step()
+        assert eng.trace_counts["spec_verify"] == 1
+        (span,) = self._spans("hvd_tpu_engine_decode")
+        assert not {"prepare_us", "dispatch_us", "fence_us",
+                    "uploads"} & set(span["args"])
+
+    def test_annotate_is_for_the_profiler_alone(self):
+        import contextlib
+
+        from horovod_tpu.obs import trace
+
+        before = len(trace.snapshot())
+        with trace.annotate("hvd_tpu_decode_dispatch") as entered:
+            assert trace.current() is None      # no ids
+        assert not isinstance(entered, contextlib.nullcontext)
+        assert len(trace.snapshot()) == before  # no ring entry
+        trace.configure(enabled=False)
+        assert isinstance(trace.annotate("hvd_tpu_decode_dispatch"),
+                          contextlib.nullcontext)
+
+    def test_kv_stats_reads_its_own_steps_from_the_span_ring(
+            self, engine_of):
+        """The dispatch and fence percentiles are computed when asked
+        for, from the decode spans this engine has put into the
+        process's ring: an engine made later has none of them."""
+        eng = engine_of("dense")
+        eng.start(0, [1, 2, 3], SamplingParams(max_new_tokens=20))
+        for _ in range(4):
+            eng.step()
+        took = sorted(s["args"]["dispatch_us"] / 1e3
+                      for s in self._spans("hvd_tpu_engine_decode"))
+        stats = eng.kv_stats()
+        assert stats["dispatch_ms_p50"] in took[1:3]
+        assert stats["dispatch_ms_p99"] == took[-1]
+        later = engine_of("dense").kv_stats()
+        assert later["dispatch_ms_p50"] is later["fence_ms_p99"] is None
+        # The ring's newest end, oldest first, and only what is asked.
+        from horovod_tpu.obs import trace
+
+        spans = self._spans("hvd_tpu_engine_decode")
+        assert trace.recent("hvd_tpu_engine_decode", 2) == spans[-2:]
+        assert trace.recent("hvd_tpu_engine_decode", 9,
+                            since_us=spans[1]["start_us"]) == spans[1:]
+        assert trace.recent("hvd_tpu_no_such_span", 9) == []
+
+    def test_a_stalled_prefill_is_named_by_the_phase_that_held_it(
+            self, engine_of):
+        """A prefill's dispatch and fence are held to the same rule,
+        each bucket against its own usual lengths: the fence on the
+        third prompt of a bucket waits 80 ms longer."""
+        from horovod_tpu.obs import flight
+
+        eng = engine_of("dense", max_slots=4)
+        sp = SamplingParams(max_new_tokens=4)
+        seen = len([e for e in flight.events()
+                    if e["kind"] == "slow_prefill"])
+        for slot, n in enumerate((3, 4, 12)):     # buckets 8, 8, 16
+            eng.start(slot, list(range(1, n + 1)), sp)
+        spans = self._spans("hvd_tpu_engine_prefill")
+        assert not any("stalled" in s["args"] for s in spans)
+        bucket = spans[0]["args"]["bucket"]
+        usual = eng._usual[("fence", bucket)]
+        timed = eng._timed
+
+        def fence(token):
+            time.sleep(0.08)
+            return int(token)
+
+        eng._timed = lambda args, key, fn, *a: timed(
+            args, key, fence if key == "fence_us" else fn, *a)
+        eng.start(3, [5, 6, 7], sp)
+        args = self._spans("hvd_tpu_engine_prefill")[-1]["args"]
+        assert args["stalled"] == "fence" and args["fence_us"] >= 80_000
+        stats = eng.kv_stats()
+        assert stats["stalled_prefills"] == 1 and stats["stalled_steps"] == 0
+        (event,) = [e for e in flight.events()
+                    if e["kind"] == "slow_prefill"][seen:]
+        assert event["phase"] == "fence" and event["bucket"] == bucket
+        assert event["prompt_len"] == 3
+        assert event["usual_us"] <= usual * 1.05 + 1
+        assert eng._usual[("fence", bucket)] < 2 * usual + 1
+
+    def test_a_profiler_session_sees_the_phases_inside_their_spans(
+            self, engine_of, tmp_path):
+        """With a session live the four annotations lie on the host
+        plane inside the span they belong to, as long as the span's
+        args say; the ring gains nothing for them."""
+        import glob
+
+        from horovod_tpu.obs import trace
+
+        eng = engine_of("paged")
+        sp = SamplingParams(max_new_tokens=20)
+        eng.start(0, [1, 2, 3], sp)
+        eng.step()                      # programs built outside the trace
+        eng.release(0)
+        trace.clear()
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            eng.start(0, [1, 2, 3], sp)
+            eng.step()
+        finally:
+            jax.profiler.stop_trace()
+        ring = trace.snapshot()
+        assert [s["name"] for s in ring] == ["hvd_tpu_engine_prefill",
+                                             "hvd_tpu_engine_decode"]
+        (path,) = glob.glob(str(tmp_path / "plugins" / "profile" / "*"
+                                / "*.xplane.pb"))
+        data = jax.profiler.ProfileData.from_file(path)
+        (host,) = [p for p in data.planes if p.name == "/host:CPU"]
+        events = {ev.name: ev for line in host.lines for ev in line.events
+                  if ev.name.startswith("hvd_tpu_")}
+        for span, outer, phases in (
+                (ring[0], "hvd_tpu_engine_prefill", ("prefill_dispatch",
+                                                     "prefill_fence")),
+                (ring[1], "hvd_tpu_engine_decode", ("decode_dispatch",
+                                                    "decode_fence"))):
+            out = events[outer]
+            last_end = out.start_ns
+            for phase in phases:
+                ev = events["hvd_tpu_" + phase]
+                assert last_end <= ev.start_ns      # in order, apart
+                last_end = ev.start_ns + ev.duration_ns
+                assert last_end <= out.start_ns + out.duration_ns
+                said = span["args"][phase.split("_")[1] + "_us"]
+                assert ev.duration_ns / 1e3 == pytest.approx(
+                    said, rel=0.2, abs=200.0)
+
+    def test_tracing_off_times_the_dispatch_alone(self, engine_of):
+        from horovod_tpu.obs import trace
+
+        trace.configure(enabled=False)
+        eng = engine_of("dense")
+        eng.start(0, [1, 2, 3], SamplingParams(max_new_tokens=20))
+        for _ in range(3):
+            eng.step()
+        assert list(eng._usual) == ["dispatch"]     # _wake_runtime's
+        assert eng._usual["dispatch"] > 0
+        assert eng.kv_stats()["fence_ms_p50"] is None
+        assert not trace.snapshot()
+
+    def test_a_stalled_step_is_named_by_the_phase_that_held_it(
+            self, engine_of):
+        """The decode program's call sleeps once, after thirty steps,
+        for 60 ms: the rule asks for four times the usual and 50 ms
+        over it, and a sleep of 50 ms would meet the second only by as
+        much as it overslept."""
+        from horovod_tpu.obs import flight
+
+        eng = engine_of("dense")
+        sp = SamplingParams(max_new_tokens=20)
+        eng.start(0, [1, 2, 3], sp)
+        decode_fn, calls = eng._decode_fn, [0]
+
+        def decode(*args):
+            calls[0] += 1
+            if calls[0] == 31:
+                time.sleep(0.06)
+            return decode_fn(*args)
+
+        eng._decode_fn = decode
+        seen = [e for e in flight.events()
+                if e["kind"] == "slow_decode_step"]
+        for i in range(36):
+            if i and i % 12 == 0:       # the cache holds 32 positions
+                eng.release(0)
+                eng.start(0, [1, 2, 3], sp)
+            if i == 30:
+                usual = eng._usual["dispatch"]
+            eng.step()
+        spans = self._spans("hvd_tpu_engine_decode")
+        assert [i for i, s in enumerate(spans)
+                if "stalled" in s["args"]] == [30]
+        args = spans[30]["args"]
+        assert args["stalled"] == "dispatch"
+        assert args["dispatch_us"] >= 60_000
+        stats = eng.kv_stats()
+        assert stats["stalled_steps"] == stats["stalled_dispatch"] == 1
+        assert stats["stalled_fence"] == stats["stalled_prepare"] == 0
+        (event,) = [e for e in flight.events()
+                    if e["kind"] == "slow_decode_step"][len(seen):]
+        assert event["phase"] == "dispatch"
+        assert event["us"] == pytest.approx(args["dispatch_us"], abs=0.1)
+        assert event["usual_us"] == pytest.approx(usual, abs=0.1)
+        assert event["active"] == 1 and event["uploads"] == 0
+        # One long step moves the usual by a twentieth of itself.
+        assert eng._usual["dispatch"] < 2 * usual
 
 
 class TestBlockPoolUnit:
