@@ -33,11 +33,31 @@ all, no head is sliced out of a vector, and the wasted products are
 zeros the MXU would have idled through anyway at a contraction of 64.
 Any other head size goes in as ``[B * H, T, D]``, one head a step.
 
-**The backward** is the standard flash recompute in plain jnp, chunked
-over keys with ``lax.scan`` so memory stays O(T · chunk).  Its chunk is
-its own (128 keys, whatever the forward chose) and its five products a
-chunk run on float32 operands, which is not what the MXU is fastest at
-(ROADMAP S2).
+**The backward** is one Pallas kernel a call too (``hvd_tpu_flash_bwd``
+in a trace), the forward's picture turned over, and :func:`plan_bwd`
+says what it does.  A grid step holds one block of ``block_k`` *keys*
+of its heads, with their ``q``, ``dO``, ``lse`` and ``delta =
+rowsum(dO · O) − dlse`` whole and resident (one XLA fusion makes
+``delta`` in front of the call), and walks its live queries
+``block_q`` at a time.  Everything is held keys along the rows and queries along
+the lanes, so ``lse`` and ``delta`` meet the scores as rows and four of
+the five products need no transpose: ``sᵀ = k · qᵀ``, ``pᵀ = exp2((sᵀ ·
+scale − lse) · log2 e)``, ``dv += pᵀ · dO``, ``dpᵀ = v · dOᵀ``, ``dSᵀ =
+pᵀ · (dpᵀ − delta)``, ``dk += dSᵀ · q``, and ``dq += (dSᵀ)ᵀ · k``, the
+one product that contracts over the rows of both.  ``dk`` and ``dv``
+sum in float32 within the step and are rounded once when written;
+``dq`` sums in a float32 scratch across the group's key blocks (that
+grid axis is last and ``arbitrary``) and is written once; the scale
+meets ``dk`` and ``dq`` there.  With ``causal`` a block's walk starts at
+its diagonal: its own queries under the mask, then what is left of
+their chunk (a width that is static in the block's place within it),
+then whole chunks with no mask; no query before the block's first key
+is touched, and a sequence of one chunk has no loop.  The operands are
+the inputs' dtype, ``pᵀ`` and ``dSᵀ`` rounded to it on their way into
+the MXU; scores, exponent, ``delta`` and every sum are float32.  The
+layouts are the forward's: ``[B, T, H * D]`` as it stands where heads
+fill whole vectors, a head of a group taking its keys and values with
+the other heads' lanes zeroed (once a step), else ``[B * H, T, D]``.
 
 Used by ``models.transformer`` (``attention='flash'``, which pads odd
 causal lengths up to a multiple of 128).  Off-TPU the same kernel runs
@@ -64,14 +84,16 @@ _NEG_INF = -1e30
 # of its walk: the largest that divide the lengths, up to these.
 _MAX_ROWS = 512
 _MAX_BLOCK_K = 1024
-# The backward's key chunk; not the forward's block.
-_BWD_BLOCK_K = 128
 _VMEM_FLOOR = 16 << 20        # v5e's default scoped limit
 _VMEM_CEILING = 96 << 20      # of 128 MiB
 
 
 class Plan(NamedTuple):
-    """What one forward call does; static in the shapes."""
+    """What one call does, forward (:func:`plan`) or backward
+    (:func:`plan_bwd`); static in the shapes.  The forward holds
+    ``block_q`` queries a grid step and walks keys in chunks of
+    ``block_k``; the backward holds ``block_k`` keys a step and walks
+    queries in chunks of ``block_q``."""
     block_q: int
     block_k: int
     heads: int              # heads a grid step, stacked along the rows
@@ -80,7 +102,7 @@ class Plan(NamedTuple):
     grid: Tuple[int, int, int]
     steps: int              # grid steps a call
     dead_steps: int         # ... of which do no arithmetic
-    chunks: int             # key chunks walked a call, over all steps
+    chunks: int             # chunks walked a call, over all steps
     masked_chunks: int      # ... of which build the causal mask
     vmem_limit_bytes: int
 
@@ -100,17 +122,24 @@ def _chosen_block(n: int, unit: int, cap: int) -> int:
                if n % b == 0)
 
 
+def _lane_groups(b: int, h: int, d: int) -> Tuple[int, bool, int, int]:
+    """Heads a grid step takes; whether ``[B, T, H * D]`` is read as it
+    stands (where a head, or ``128 / d`` heads side by side, fill whole
+    vectors) or ``[B * H, T, D]``; the rows of that array and the lane
+    groups of a row: the grid's first two axes."""
+    if d % _LANES == 0:
+        return 1, True, b, h
+    if _LANES % d == 0 and (h * d) % _LANES == 0:
+        return _LANES // d, True, b, h * d // _LANES
+    return 1, False, b * h, 1
+
+
 def plan(b: int, h: int, t: int, tk: int, d: int, dtype, causal: bool,
          block_q: Optional[int] = None,
          block_k: Optional[int] = None) -> Plan:
     """The forward call for ``q [b, t, h, d]`` against ``tk`` keys.
     ``block_q`` / ``block_k`` override the choice."""
-    if d % _LANES == 0:
-        heads, lane_packed = 1, True
-    elif _LANES % d == 0 and (h * d) % _LANES == 0:
-        heads, lane_packed = _LANES // d, True
-    else:
-        heads, lane_packed = 1, False
+    heads, lane_packed, rows_of_q, groups = _lane_groups(b, h, d)
     lanes = heads * d
     if block_q is None:
         block_q = _chosen_block(t, _LANES, _MAX_ROWS // heads)
@@ -131,8 +160,6 @@ def plan(b: int, h: int, t: int, tk: int, d: int, dtype, causal: bool,
             f"causal flash attention needs one of block_q, block_k "
             f"({block_q}, {block_k}) to divide the other")
     n_q = t // block_q
-    # Lane groups of a row of [B, T, H * D], or every head a row of its own.
-    rows_of_q, groups = (b, h * d // lanes) if lane_packed else (b * h, 1)
     grid = (rows_of_q, groups, n_q)
     if causal:
         walked = sum(i * block_q // block_k + 1 for i in range(n_q))
@@ -151,6 +178,59 @@ def plan(b: int, h: int, t: int, tk: int, d: int, dtype, causal: bool,
     per_block = rows_of_q * groups
     return Plan(block_q, block_k, heads, lanes, lane_packed, grid,
                 per_block * n_q, 0, per_block * walked, per_block * masked,
+                vmem)
+
+
+def plan_bwd(b: int, h: int, t: int, tk: int, d: int, dtype, causal: bool,
+             block_q: Optional[int] = None,
+             block_k: Optional[int] = None) -> Plan:
+    """The backward call for ``q [b, t, h, d]`` against ``tk`` keys: the
+    forward's picture turned over.  A grid step holds ``block_k`` keys
+    and walks its live queries ``block_q`` at a time.  ``block_q`` /
+    ``block_k`` override the choice as they do the forward's; under
+    ``causal``, where a chunk has to be whole key blocks, the larger of
+    the two is the chunk and the smaller the key block."""
+    heads, lane_packed, rows_of_q, groups = _lane_groups(b, h, d)
+    lanes = heads * d
+    if causal and t != tk:
+        raise ValueError("causal flash attention requires Tq == Tk")
+    bk, cq = block_k, block_q
+    if causal and bk is not None and cq is not None:
+        bk, cq = min(bk, cq), max(bk, cq)
+    if bk is None:
+        bk = _chosen_block(tk, _LANES, _MAX_ROWS // heads)
+    bk = min(bk, tk)
+    if cq is None:
+        cq = _chosen_block(t, bk if causal else _LANES, _MAX_BLOCK_K)
+    cq = min(cq, t)
+    if t % cq or tk % bk or (causal and cq % bk):
+        raise ValueError(
+            f"sequence lengths ({t}, {tk}) must be multiples of the block "
+            f"sizes ({cq}, {bk}), and a causal chunk of whole key blocks; "
+            f"pad, or use flash_attention_padded for causal self-attention")
+    n_k = tk // bk
+    grid = (rows_of_q, groups, n_k)
+    if causal:
+        # Block j: its own queries under the mask, what is left of
+        # their chunk where that is anything, whole chunks to the end.
+        per = cq // bk
+        walked = sum(1 + ((j + 1) % per > 0) + (t // cq - j // per - 1)
+                     for j in range(n_k))
+        masked = n_k
+    else:
+        walked, masked = n_k * (t // cq), 0
+    isz = jnp.dtype(dtype).itemsize
+    # Double-buffered blocks (q, dO, dq whole; k, v, dk, dv; lse and
+    # delta a sublane-padded row a head), the float32 sums, and a
+    # head's values of a chunk (scores, p, dp, dS; p and dS rounded).
+    blocks = 2 * (3 * t * lanes * isz + 4 * bk * lanes * isz
+                  + 2 * heads * 8 * t * 4)
+    sums = (t + 2 * heads * bk) * lanes * 4
+    values = bk * cq * (16 + 2 * isz) + 3 * max(bk, cq) * lanes * 4
+    vmem = min(max(blocks + sums + 2 * values, _VMEM_FLOOR), _VMEM_CEILING)
+    per_block = rows_of_q * groups
+    return Plan(cq, bk, heads, lanes, lane_packed, grid,
+                per_block * n_k, 0, per_block * walked, per_block * masked,
                 vmem)
 
 
@@ -308,48 +388,168 @@ def _flash_fwd(q, k, v, *, scale, causal, block_q, block_k, interpret):
     return o, lse.reshape(b, h, t)
 
 
-def _flash_bwd(q3, k3, v3, o3, lse, do3, *, scale, causal, block_k,
-               dlse=None):
-    """Chunked flash backward (recompute), all float32 accumulation.
+def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                dq_ref, dk_ref, dv_ref, dq_acc, dk_acc, dv_acc, *,
+                scale: float, causal: bool, block_q: int, block_k: int,
+                heads: int):
+    """One grid step: ``block_k`` keys of ``heads`` heads against their
+    live queries.  Everything is held keys along the rows and queries
+    along the lanes, so ``lse`` and ``delta`` meet the scores as rows
+    and four of the five products need no transpose."""
+    bk, cq = block_k, block_q
+    t, lanes = q_ref.shape[1], q_ref.shape[2]
+    d = lanes // heads
+    j = pl.program_id(2)
+    k, v = k_ref[0], v_ref[0]                             # [bk, lanes]
+    lane = lax.broadcasted_iota(jnp.int32, k.shape, 1)
+    if heads > 1:
+        # A head's keys and values with the other heads' lanes zeroed:
+        # a product over all the lanes is then that head's alone.
+        mine = [(lane >= g * d) & (lane < (g + 1) * d) for g in range(heads)]
+        ks = [jnp.where(m, k, jnp.zeros_like(k)) for m in mine]
+        vs = [jnp.where(m, v, jnp.zeros_like(v)) for m in mine]
+    else:
+        ks, vs = [k], [v]
+    log2e = math.log2(math.e)
+    nt = (((1,), (1,)), ((), ()))        # a · bᵀ
+    tn = (((0,), (0,)), ((), ()))        # aᵀ · b
 
-    ``dlse``: cotangent of the logsumexp output (for the
-    :func:`flash_attention_with_lse` entry).  ∂lse_i/∂s_ik = p_ik, so it
-    folds into the same dS term as the softmax-jacobian diagonal:
-    dS = P · (dP − Δ + dlse)."""
-    bh, t, d = q3.shape
-    tk = k3.shape[1]
-    qf = q3.astype(jnp.float32)
-    dof = do3.astype(jnp.float32)
-    # D_i = rowsum(dO * O) — the softmax-jacobian diagonal term.
-    delta = jnp.sum(dof * o3.astype(jnp.float32), axis=-1)     # [bh, t]
-    if dlse is not None:
-        delta = delta - dlse.astype(jnp.float32)
-    nk = tk // block_k
-    k_blocks = k3.reshape(bh, nk, block_k, d).transpose(1, 0, 2, 3)
-    v_blocks = v3.reshape(bh, nk, block_k, d).transpose(1, 0, 2, 3)
+    def chunk(start, width, first=False, mask=None):
+        """The queries ``[start, start + width)`` into the step's sums.
+        ``first`` opens the key block's accumulators; ``mask`` (keys by
+        queries) is the diagonal block's."""
+        rows = pl.ds(start, width)
+        q, do = q_ref[0, rows, :], do_ref[0, rows, :]     # [width, lanes]
+        dq = None
+        for g in range(heads):
+            lse = lse_ref[0, g, :, rows] * log2e          # [1, width]
+            delta = delta_ref[0, g, :, rows]
+            s = lax.dot_general(ks[g], q, nt,
+                                preferred_element_type=jnp.float32)
+            p = jnp.exp2(s * (scale * log2e) - lse)       # [bk, width]
+            if mask is not None:
+                p = jnp.where(mask, p, 0.0)
+            dp = lax.dot_general(vs[g], do, nt,
+                                 preferred_element_type=jnp.float32)
+            # The scale meets dk and dq once, when they are written.
+            ds = (p * (dp - delta)).astype(q.dtype)
+            dv_g = jnp.dot(p.astype(do.dtype), do,
+                           preferred_element_type=jnp.float32)
+            dk_g = jnp.dot(ds, q, preferred_element_type=jnp.float32)
+            dq_g = lax.dot_general(ds, ks[g], tn,
+                                   preferred_element_type=jnp.float32)
+            dq = dq_g if dq is None else dq + dq_g
+            if first:
+                dv_acc[g], dk_acc[g] = dv_g, dk_g
+            else:
+                dv_acc[g] += dv_g
+                dk_acc[g] += dk_g
+        dq_acc[rows, :] += dq                             # [width, lanes]
 
-    qpos = lax.broadcasted_iota(jnp.int32, (t, block_k), 0)
-    koff = lax.broadcasted_iota(jnp.int32, (t, block_k), 1)
+    def walk(first):
+        """Whole chunks of ``cq`` queries from chunk ``first`` to the
+        end, none of them masked."""
+        if t > cq:
+            lax.fori_loop(
+                first, t // cq,
+                lambda i, _: chunk(pl.multiple_of(i * cq, cq), cq), None)
 
-    def body(dq, xs):
-        kj, k_blk, v_blk = xs
-        s = jnp.einsum("bqd,bkd->bqk", qf, k_blk.astype(jnp.float32)) * scale
-        if causal:
-            s = jnp.where(qpos >= kj * block_k + koff, s, _NEG_INF)
-        p = jnp.exp(s - lse[..., None])                         # [bh, t, bk]
-        dv_blk = jnp.einsum("bqk,bqd->bkd", p, dof)
-        dp = jnp.einsum("bqd,bkd->bqk", dof, v_blk.astype(jnp.float32))
-        ds = p * (dp - delta[..., None]) * scale
-        dk_blk = jnp.einsum("bqk,bqd->bkd", ds, qf)
-        dq = dq + jnp.einsum("bqk,bkd->bqd", ds, k_blk.astype(jnp.float32))
-        return dq, (dk_blk, dv_blk)
+    @pl.when(j == 0)
+    def _():
+        dq_acc[...] = jnp.zeros_like(dq_acc)
 
-    dq0 = jnp.zeros((bh, t, d), jnp.float32)
-    dq, (dk_b, dv_b) = lax.scan(
-        body, dq0, (jnp.arange(nk), k_blocks, v_blocks))
-    dk = dk_b.transpose(1, 0, 2, 3).reshape(bh, tk, d)
-    dv = dv_b.transpose(1, 0, 2, 3).reshape(bh, tk, d)
-    return (dq.astype(q3.dtype), dk.astype(k3.dtype), dv.astype(v3.dtype))
+    if not causal:
+        chunk(0, cq, first=True)
+        walk(1)
+    else:
+        # The block's own queries first, under the mask; then what is
+        # left of their chunk, of a width that is static in the block's
+        # place within it (one of cq / bk cases); then whole chunks to
+        # the end.  No query before the block's first key is touched.
+        n_k = t // bk
+        cases = cq // bk
+        keys = lax.broadcasted_iota(jnp.int32, (bk, bk), 0)
+        queries = lax.broadcasted_iota(jnp.int32, (bk, bk), 1)
+        # (A sequence of one block may be no multiple of the lanes:
+        # its start is static.)
+        own = 0 if n_k == 1 else pl.multiple_of(j * bk, bk)
+        chunk(own, bk, first=True, mask=queries >= keys)
+        for c in range(cases - 1):
+            pl.when(j % cases == c)(functools.partial(
+                chunk, pl.multiple_of((j + 1) * bk, bk),
+                (cases - 1 - c) * bk))
+        walk(j // cases + 1)
+
+    dk, dv = dk_acc[0], dv_acc[0]
+    for g in range(1, heads):
+        dk = jnp.where(lane >= g * d, dk_acc[g], dk)
+        dv = jnp.where(lane >= g * d, dv_acc[g], dv)
+    dk_ref[0] = (dk * scale).astype(dk_ref.dtype)
+    dv_ref[0] = dv.astype(dv_ref.dtype)
+
+    @pl.when(j == pl.num_programs(2) - 1)
+    def _():
+        dq_ref[0] = (dq_acc[...] * scale).astype(dq_ref.dtype)
+
+
+# Under jit, so that a model's layers share one tracing and one lowering
+# of the kernel: traced a layer, 24 layers added 11 s to a warm start of
+# the GPT-2 train step (PERF.md §6, PR 41).  The forward is not: its
+# custom-call would take the jitted function's name in the device trace
+# where the benchmark finds it by the calling module's (ROADMAP W6).
+@functools.partial(jax.jit, static_argnames=(
+    "scale", "causal", "block_q", "block_k", "interpret"))
+def _flash_bwd(q, k, v, o, lse, do, dlse, *, scale, causal, block_q,
+               block_k, interpret):
+    """The gradients of :func:`_flash_fwd`'s ``(o, lse)`` by recompute:
+    ``q, k, v, o, do [B, T, H, D]`` and ``lse, dlse [B, H, T]`` to
+    ``dq, dk, dv``.
+
+    ∂lse_i/∂s_ik = p_ik, so ``dlse`` folds into the same dS term as the
+    softmax-jacobian diagonal: dS = P · (dP − Δ), Δ = rowsum(dO · O) −
+    dlse."""
+    b, t, h, d = q.shape
+    tk = k.shape[1]
+    p = plan_bwd(b, h, t, tk, d, q.dtype, causal, block_q, block_k)
+    delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
+    delta = delta.transpose(0, 2, 1) - dlse.astype(jnp.float32)
+    if p.lane_packed:
+        q3, k3, v3, do3 = (x.reshape(b, x.shape[1], h * d)
+                           for x in (q, k, v, do))
+    else:
+        q3, k3, v3, do3 = _pack(q), _pack(k), _pack(v), _pack(do)
+    n, _, width = q3.shape
+    bk, lanes = p.block_k, p.lanes
+    whole = pl.BlockSpec((1, t, lanes), lambda n, g, j: (n, 0, g))
+    block = pl.BlockSpec((1, bk, lanes), lambda n, g, j: (n, j, g))
+    # lse and delta ride the lanes, a row a head.
+    row = pl.BlockSpec((1, p.heads, 1, t), lambda n, g, j: (n, g, 0, 0))
+    dq3, dk3, dv3 = pl.pallas_call(
+        functools.partial(_bwd_kernel, scale=scale, causal=causal,
+                          block_q=p.block_q, block_k=bk, heads=p.heads),
+        grid=p.grid,
+        # The queries' side of a group does not move with the key block:
+        # it is copied once a group, and dq leaves once.
+        in_specs=[whole, block, block, whole, row, row],
+        out_specs=[whole, block, block],
+        out_shape=[jax.ShapeDtypeStruct(x.shape, x.dtype)
+                   for x in (q3, k3, v3)],
+        scratch_shapes=[
+            pltpu.VMEM((t, lanes), jnp.float32),
+            pltpu.VMEM((p.heads, bk, lanes), jnp.float32),
+            pltpu.VMEM((p.heads, bk, lanes), jnp.float32),
+        ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=p.vmem_limit_bytes),
+        interpret=interpret,
+        name="hvd_tpu_flash_bwd",
+    )(q3, k3, v3, do3, lse.reshape(n, width // d, 1, t),
+      delta.reshape(n, width // d, 1, t))
+    if p.lane_packed:
+        return (dq3.reshape(q.shape), dk3.reshape(k.shape),
+                dv3.reshape(v.shape))
+    return _unpack(dq3, b), _unpack(dk3, b), _unpack(dv3, b)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
@@ -366,14 +566,8 @@ def _flash_lse_fwd(q, k, v, *static):
 def _flash_lse_bwd(scale, causal, block_q, block_k, interpret, res, cts):
     q, k, v, o, lse = res
     do, dlse = cts
-    b, t, h, _ = q.shape
-    tk = k.shape[1]
-    chunk = tk if tk <= _BWD_BLOCK_K else math.gcd(tk, _BWD_BLOCK_K)
-    grads = _flash_bwd(_pack(q), _pack(k), _pack(v), _pack(o),
-                       lse.reshape(b * h, t), _pack(do), scale=scale,
-                       causal=causal, block_k=chunk,
-                       dlse=dlse.reshape(b * h, t))
-    return tuple(_unpack(g, b) for g in grads)
+    return _flash_bwd(q, k, v, o, lse, do, dlse, scale=scale, causal=causal,
+                      block_q=block_q, block_k=block_k, interpret=interpret)
 
 
 _flash_lse.defvjp(_flash_lse_fwd, _flash_lse_bwd)

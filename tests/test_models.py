@@ -115,6 +115,29 @@ class TestGPT:
         np.testing.assert_allclose(np.asarray(lfl), np.asarray(lf),
                                    rtol=2e-4, atol=2e-4)
 
+    @pytest.mark.parametrize("causal", [True, False])
+    def test_flash_attention_grads_match_full(self, causal):
+        """Same weights, same gradients: the flash kernels, forward and
+        backward, under the model's own entry point (a padded causal
+        length; one clamped block when not causal)."""
+        import dataclasses
+
+        model_f, params, tokens = _tiny_gpt("full")
+        tokens = jnp.asarray(tokens)
+
+        def grads(attention):
+            model = GPT(dataclasses.replace(
+                model_f.config, attention=attention, causal=causal))
+            return jax.grad(lambda p: jnp.sum(
+                model.apply({"params": p}, tokens) ** 2))(params)
+
+        flat_f = jax.tree_util.tree_leaves_with_path(grads("full"))
+        flat_fl = jax.tree_util.tree_leaves(grads("flash"))
+        for (path, a), b in zip(flat_f, flat_fl):
+            np.testing.assert_allclose(
+                np.asarray(b), np.asarray(a), rtol=2e-3, atol=2e-3,
+                err_msg=jax.tree_util.keystr(path))
+
     def test_flash_noncausal_short_seq_ok(self):
         """T < 128 runs as one clamped block — must not be rejected by
         the non-causal guard (regression)."""

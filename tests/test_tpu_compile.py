@@ -17,6 +17,7 @@ whole suite (``conftest.py``): such an executable could be written to
 it but never read back without the chip.
 """
 
+import json
 import os
 import re
 
@@ -80,20 +81,70 @@ def _flash_lse(q, k, v):
                                        interpret=False)
 
 
+def _flash_lse_bwd(q, k, v):
+    # ... and its backward, with a cotangent for lse.
+    def loss(q, k, v):
+        o, lse = _flash_lse(q, k, v)
+        return o.astype(jnp.float32).sum() + lse.sum()
+
+    return jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+
+
+def _flash_padded_bwd(q, k, v):
+    def loss(q, k, v):
+        return _flash_padded(q, k, v).astype(jnp.float32).sum()
+
+    return jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+
+
 # The attention layer of nemotron3nano-train-1chip: one row of 8,192
 # tokens, 32 heads of 128 (its 2 KV heads repeated).
 NEMOTRON = dict(b=1, h=32, d=128)
+# GPT-2 XL's 25 heads of 64 are no whole vectors: [B * H, T, D], one
+# head a grid step.
+GPT2_XL = dict(b=4, h=25, d=64)
+
+
+def _gpt2_medium_config():
+    """The benchmark's configuration file, read and never edited."""
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "hvdbench", "configs",
+                           "gpt2-medium.json")) as f:
+        return json.load(f)
 
 
 @pytest.mark.parametrize("fn,t,shape", [
     (_flash_fwd, T, {}), (_flash_fwd_bwd, T, {}), (_flash_padded, 1000, {}),
     (_flash_padded, 37, {}), (_flash_lse, T, {}),
     (_flash_fwd, 8192, NEMOTRON), (_flash_fwd_bwd, 8192, NEMOTRON),
+    (_flash_fwd_bwd, T, GPT2_XL), (_flash_lse_bwd, T, {}),
+    (_flash_padded_bwd, 1000, {}), (_flash_padded_bwd, 37, {}),
 ], ids=["flash_fwd", "flash_fwd_bwd", "flash_padded_odd_t",
         "flash_padded_short_t", "flash_with_lse_noncausal",
-        "flash_fwd_nemotron", "flash_fwd_bwd_nemotron"])
+        "flash_fwd_nemotron", "flash_fwd_bwd_nemotron",
+        "flash_fwd_bwd_one_head_a_step", "flash_with_lse_noncausal_bwd",
+        "flash_padded_odd_t_bwd", "flash_padded_short_t_bwd"])
 def test_flash_attention_compiles_for_v5e(topo, fn, t, shape):
     _compile(fn, *_qkv(topo, t, **shape))
+
+
+def test_backward_kernel_has_its_name_and_is_no_forward_call(topo):
+    """One layer's forward and backward at the GPT-2 cell's shape: the
+    backward is the custom-call ``hvd_tpu_flash_bwd`` (the label
+    ``breakdown.device_ops`` lists it under), and the pattern by which
+    the benchmark counts *forward* calls finds the forward alone."""
+    text = _compile(_flash_fwd_bwd, *_qkv(topo))
+    calls = [line.strip() for line in text.splitlines()
+             if "tpu_custom_call" in line and " custom-call(" in line]
+    assert len(calls) == 2, calls
+    assert sum("hvd_tpu_flash_bwd" in c.split(" = ")[0] for c in calls) == 1
+    pattern = _gpt2_medium_config()["run"]["kernels"]["flash_fwd"]["match"]
+    # Inside a model the forward is ``%attn.N``, after the module that
+    # calls it; alone it has the kernel function's name.  The result
+    # type is what tells the two calls apart.
+    result = re.compile(pattern.split(" = ", 1)[1])
+    assert sum(bool(result.search(c.split(" = ", 1)[1]))
+               for c in calls) == 1
 
 
 def test_benchmark_still_finds_the_flash_kernel_on_v5e(topo, monkeypatch):
@@ -103,14 +154,9 @@ def test_benchmark_still_finds_the_flash_kernel_on_v5e(topo, monkeypatch):
     custom-call whose result is ``(o, lse)``.  GPT-2 medium's forward at
     the cell's 8 x 1024 tokens, compiled for the chip, has to hold one
     such line a layer."""
-    import json
-
     from horovod_tpu.models import GPT, GPTConfig
 
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    with open(os.path.join(root, "hvdbench", "configs",
-                           "gpt2-medium.json")) as f:
-        config = json.load(f)
+    config = _gpt2_medium_config()
     run = config["run"]
     # Off the chip the kernel would take the interpreter.
     monkeypatch.setattr(pa, "resolve_interpret", lambda interpret: False)
@@ -170,6 +216,16 @@ def test_fused_reducescatter_compiles_for_v5e(topo):
             x, axis="hvd", interpret=False),
         [P("hvd")], P("hvd"), ((4 * N_ELEMS,), jnp.float32))
     assert "all-to-all" in text
+
+
+def test_flash_fwd_bwd_compiles_under_the_data_parallel_mesh(topo):
+    """``gpt2m-train-dp4``'s attention: the batch split over the four
+    chips of the host by ``shard_map`` (``make_train_step``'s way), 8
+    rows a chip, forward and backward kernel in each chip's program."""
+    row = ((4 * B, T, H, D), jnp.bfloat16)
+    text = _mesh_case(topo, _flash_fwd_bwd, [P("hvd")] * 3,
+                      (P("hvd"),) * 3, row, row, row)
+    assert "hvd_tpu_flash_bwd" in text
 
 
 def test_fused_allgather_compiles_for_v5e(topo):
