@@ -55,6 +55,24 @@ class GPTConfig:
     n_kv_head: int = 0                 # 0 = n_head: one KV head per query head
     head_dim: int = 0                  # 0 = d_model // n_head
     qk_norm: bool = False              # RMS norm of q and k per head, before positions
+    # Attention layers of two kinds in one model.  ``attn`` says which a
+    # layer is, one word for every layer or one per layer: 'full' (a
+    # query sees every position before it) or 'window' (the last
+    # ``window`` of them, itself among them).  A window layer may have
+    # KV heads and a rotary base of its own (0 = the full layers') and a
+    # learned sink: one float32 logit a head that joins the softmax's
+    # denominator and nothing else.  Keys and queries are ``head_dim``
+    # wide, values ``v_head_dim`` (0 = the same); rotary positions turn
+    # the first ``rope_dim`` numbers of a head (0 = all of them) and
+    # the rest pass; values are scaled by ``value_scale``.
+    attn: Any = "full"
+    window: int = 0
+    window_kv_head: int = 0
+    window_rope_theta: float = 0.0
+    window_sinks: bool = False
+    v_head_dim: int = 0
+    rope_dim: int = 0
+    value_scale: float = 1.0
     mlp: str = "gelu"                  # 'gelu' | 'swiglu' (gated SiLU) | 'relu2' (squared ReLU, not gated)
     # What mixes tokens in a layer: 'attention' (softmax over a K/V
     # cache) or 'retention' (ops/retention.py: a fixed-size state).
@@ -67,6 +85,10 @@ class GPTConfig:
     # ('experts', parallel/moe.py::DroplessExperts).  One word for every
     # layer, or one per layer.
     layers: Any = "block"
+    # The feed-forward of a 'block' layer: 'mlp' (``mlp`` above, ``d_ff``
+    # wide) or 'experts' (the dropless expert layer in the block's
+    # second half).  One word for every layer, or one per layer.
+    ffn: Any = "mlp"
     # 'ssm' layers (Mamba-2): heads of ssm_head_dim, B and C shared by
     # groups of heads, a state of ssm_state numbers per head channel, a
     # causal depth-wise convolution of ssm_conv taps in front.
@@ -85,6 +107,7 @@ class GPTConfig:
     expert_shared_d_ff: int = 0        # 0 = no shared expert
     expert_scale: float = 1.0
     expert_held: Optional[Tuple[int, int]] = None
+    expert_mlp: str = "relu2"          # an expert: 'relu2' (squared ReLU, not gated) | 'swiglu' (gated SiLU)
     # The kinds of layer (of ``layers``) that are recomputed in the
     # backward pass instead of keeping their activations (a
     # configuration states them where a step would not fit).
@@ -107,6 +130,38 @@ class GPTConfig:
     @property
     def head_size(self) -> int:
         return self.head_dim or self.d_model // self.n_head
+
+    @property
+    def v_head_size(self) -> int:
+        return self.v_head_dim or self.head_size
+
+    def _per_layer(self, field: str, allowed) -> Tuple[str, ...]:
+        value = getattr(self, field)
+        kinds = ((value,) * self.n_layer if isinstance(value, str)
+                 else tuple(value))
+        if len(kinds) != self.n_layer or set(kinds) - set(allowed):
+            raise ValueError(
+                f"{field} must be one of {allowed} or {self.n_layer} of "
+                f"them, got {value!r}")
+        return kinds
+
+    @property
+    def attn_kinds(self) -> Tuple[str, ...]:
+        """Which attention every layer has (read where it has one)."""
+        kinds = self._per_layer("attn", ATTENTIONS)
+        if "window" in kinds and self.window < 1:
+            raise ValueError("a 'window' attention layer needs window >= 1")
+        return kinds
+
+    @property
+    def ffns(self) -> Tuple[str, ...]:
+        """The feed-forward of every layer (read where it is a block)."""
+        return self._per_layer("ffn", FFNS)
+
+    def kv_heads_of(self, kind: str) -> int:
+        """KV heads of an attention layer of ``kind``."""
+        return (self.window_kv_head if kind == "window" else 0) \
+            or self.kv_heads
 
     @property
     def mixers(self) -> Tuple[str, ...]:
@@ -133,29 +188,55 @@ class GPTConfig:
 
 MIXERS = ("attention", "retention")
 LAYERS = ("block", "attention", "ssm", "experts")
+ATTENTIONS = ("full", "window")
+FFNS = ("mlp", "experts")
 
 
 def _refuse_serving(config: GPTConfig) -> None:
-    """A model with a state-space or an expert layer is trained, not
-    served yet."""
-    missing = set(config.layer_kinds) & {"ssm", "experts"}
-    if missing:
+    """A model with a state-space layer is trained, not served yet."""
+    if "ssm" in config.layer_kinds:
         raise NotImplementedError(
-            f"serving a model with {sorted(missing)} layers is not built: "
-            f"a state-space layer needs a state cache beside the K/V cache "
-            f"in one cache manager, and the engine has no expert layer "
-            f"(serve/engine.py)")
+            "serving a model with 'ssm' layers is not built: a state-space "
+            "layer needs a state cache beside the K/V cache in one cache "
+            "manager (serve/engine.py)")
 
 
-def cache_kinds(config: GPTConfig) -> Tuple[str, ...]:
+@dataclasses.dataclass(frozen=True)
+class KVKind:
+    """What an attention layer keeps of a token, and of how many: the
+    widths of its key row and its value row (``KV heads x head width``
+    each), and its causal window (0: every position so far; ``w``: the
+    last ``w``, so that what the layer keeps does not grow with the
+    context).  The serving engine allocates one set of pools and one
+    block table for each distinct declaration."""
+
+    k_row: int
+    v_row: int
+    window: int = 0
+
+
+def cache_kinds(config: GPTConfig) -> Tuple[Any, ...]:
     """What each layer keeps between the tokens of a request, as the
-    model declares it: ``'kv'`` (keys and values of every position so
-    far: :func:`init_kv_cache`, or the engine's paged pools) or
-    ``'state'`` (a fixed-size retention state: :func:`init_state_cache`).
-    The serving engine chooses its cache from this."""
+    model declares it: a :class:`KVKind` (keys and values:
+    :func:`init_kv_cache`, or the engine's paged pools — of every
+    position so far, or of a window's), ``'state'`` (a fixed-size
+    retention state: :func:`init_state_cache`) or None (a layer that is
+    a feed-forward alone keeps nothing).  The serving engine builds its
+    cache from this."""
     _refuse_serving(config)
-    return tuple("kv" if "attention" in (kind, m) else "state"
-                 for kind, m in zip(config.layer_kinds, config.mixers))
+    out = []
+    for kind, mixer, attn in zip(config.layer_kinds, config.mixers,
+                                 config.attn_kinds):
+        if kind == "experts":
+            out.append(None)
+        elif "attention" in (kind, mixer):
+            heads = config.kv_heads_of(attn)
+            out.append(KVKind(
+                heads * config.head_size, heads * config.v_head_size,
+                config.window if attn == "window" else 0))
+        else:
+            out.append("state")
+    return tuple(out)
 
 
 def init_state_cache(config: GPTConfig, batch_size: int):
@@ -177,20 +258,29 @@ def init_state_cache(config: GPTConfig, batch_size: int):
 
 def init_kv_cache(config: GPTConfig, batch_size: int, max_len: int):
     """Preallocated per-layer KV cache for autoregressive decode
-    (serve/engine.py): one ``{"k", "v"}`` pair of ``[B, max_len, K, D]``
-    arrays per block (``K`` KV heads of size ``D``, both as the
-    configuration states them).  Allocated once per serving slot-batch so the
+    (serve/engine.py): per layer one ``{"k": [B, max_len, K, D_k], "v":
+    [B, max_len, K, D_v]}`` pair — ``K`` KV heads, keys ``D_k`` and
+    values ``D_v`` wide, each as the configuration states it *for that
+    layer* (:func:`cache_kinds`: a window layer may have other KV heads
+    than a full one; the dense rows keep every position of either, and
+    the window is a mask).  Allocated once per serving slot-batch so the
     decode hot path never reallocates; the engine's length buckets keep
     the set of compiled shapes small.
 
     The paged alternative (``horovod_tpu/serve/kv``) replaces the dense
-    per-slot rows with one ``[num_blocks, block, K * D]`` pool per
-    layer plus a per-slot block table; :class:`Attention` accepts either
-    layout (``{"k", "v"}`` vs ``{"k_pool", "v_pool", "table"}``)."""
-    shape = (batch_size, max_len, config.kv_heads, config.head_size)
-    return [{"k": jnp.zeros(shape, config.dtype),
-             "v": jnp.zeros(shape, config.dtype)}
-            for _ in range(config.n_layer)]
+    per-slot rows with a ``[num_blocks, block, K * D_k]`` and a
+    ``[num_blocks, block, K * D_v]`` pool per layer plus a per-slot
+    block table for each kind of layer; :class:`Attention` accepts
+    either layout (``{"k", "v"}`` vs ``{"k_pool", "v_pool", "table"}``)."""
+    out = []
+    for attn in config.attn_kinds:
+        heads = config.kv_heads_of(attn)
+        out.append({
+            "k": jnp.zeros((batch_size, max_len, heads, config.head_size),
+                           config.dtype),
+            "v": jnp.zeros((batch_size, max_len, heads, config.v_head_size),
+                           config.dtype)})
+    return out
 
 
 def _tp_shard(cfg: GPTConfig, x, *spec):
@@ -232,12 +322,16 @@ def _norm(cfg: GPTConfig, name: str):
     return nn.LayerNorm(epsilon=cfg.norm_eps, dtype=cfg.dtype, name=name)
 
 
-def _rope(x, positions, theta: float):
+def _rope(x, positions, theta: float, dim: int = 0):
     """Rotary positions on ``x [B, T, N, D]`` at absolute ``positions
     [B, T]``: the half-split convention (``x1, x2`` = the two halves of
     a head; ``x1 cos - x2 sin, x2 cos + x1 sin``), angles in float32
     from the position itself, so there is no table and no longest
-    sequence."""
+    sequence.  With ``dim`` the first ``dim`` numbers of a head are
+    turned (their two halves) and the rest pass."""
+    if dim and dim < x.shape[-1]:
+        return jnp.concatenate(
+            [_rope(x[..., :dim], positions, theta), x[..., dim:]], axis=-1)
     half = x.shape[-1] // 2
     inv = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
     ang = positions.astype(jnp.float32)[..., None, None] * inv
@@ -253,19 +347,21 @@ def _dense(cfg: GPTConfig, features: int, name: str):
                     param_dtype=cfg.param_dtype, name=name)
 
 
-def _positioned(cfg: GPTConfig, q, k, positions):
+def _positioned(cfg: GPTConfig, q, k, positions, theta: float = 0.0):
     """``q`` and ``k`` as the mixers see them: the per-head RMS norm
     (``qk_norm``; inside a mixer's ``@nn.compact`` call, so the two
     scales are that mixer's ``q_norm`` / ``k_norm``), then rotary
-    positions (``positions='rope'``)."""
+    positions (``positions='rope'``) at the base ``theta`` (0: the
+    configuration's ``rope_theta``)."""
     if cfg.qk_norm:
         q = RMSNorm(cfg, name="q_norm")(q)
         k = RMSNorm(cfg, name="k_norm")(k)
     if cfg.positions == "rope":
         if positions is None:
             positions = jnp.arange(q.shape[1], dtype=jnp.int32)[None]
-        q = _rope(q, positions, cfg.rope_theta)
-        k = _rope(k, positions, cfg.rope_theta)
+        theta = theta or cfg.rope_theta
+        q = _rope(q, positions, theta, cfg.rope_dim)
+        k = _rope(k, positions, theta, cfg.rope_dim)
     return q, k
 
 
@@ -275,18 +371,27 @@ class Attention(nn.Module):
     into the cache, attends over the cache it has written and returns
     ``(out, {"k", "v"})``, the updated cache: the dense rows, or, for a
     paged cache (``{"k_pool", "v_pool", "table"}``), the pools, written
-    through the block table."""
+    through the block table.  ``kind`` is the layer's word of
+    ``GPTConfig.attn``: a 'window' layer masks what lies ``window`` or
+    more positions back, may have KV heads and a rotary base of its
+    own, and a sink."""
 
     config: GPTConfig
     mesh: Optional[Mesh] = None
+    kind: str = "full"
 
     @nn.compact
     def __call__(self, x, cache=None, positions=None):
+        from ..ops import paged_attention
+
         cfg = self.config
         B, T, _ = x.shape
-        H, K, D = cfg.n_head, cfg.kv_heads, cfg.head_size
-        C = H * D
-        qkv = _dense(cfg, (H + 2 * K) * D, "qkv")(x)
+        windowed = self.kind == "window"
+        H, K = cfg.n_head, cfg.kv_heads_of(self.kind)
+        D, Dv = cfg.head_size, cfg.v_head_size
+        window = cfg.window if windowed else 0
+        C = H * Dv
+        qkv = _dense(cfg, H * D + K * D + K * Dv, "qkv")(x)
         q, k, v = jnp.split(qkv, [H * D, (H + K) * D], axis=-1)
         # Under TP the qkv kernel is column-sharded, so q/k/v arrive
         # head-sharded; pin the layout explicitly so the paged pool
@@ -296,9 +401,18 @@ class Attention(nn.Module):
                       None, None, cfg.tp_axis, None)
         k = _tp_shard(cfg, k.reshape(B, T, K, D),
                       None, None, cfg.tp_axis, None)
-        v = _tp_shard(cfg, v.reshape(B, T, K, D),
+        v = _tp_shard(cfg, v.reshape(B, T, K, Dv),
                       None, None, cfg.tp_axis, None)
-        q, k = _positioned(cfg, q, k, positions)
+        q, k = _positioned(cfg, q, k, positions,
+                           cfg.window_rope_theta if windowed else 0.0)
+        if cfg.value_scale != 1.0:
+            v = (v.astype(jnp.float32) * cfg.value_scale).astype(v.dtype)
+        sink = (self.param("sink", nn.initializers.zeros, (H,), jnp.float32)
+                if windowed and cfg.window_sinks else None)
+        # What the defaults never had: a window, a sink, values of
+        # another width than the keys.  Those layers have one
+        # arithmetic, ``ops/paged_attention.py::view_attention``.
+        special = bool(window) or sink is not None or Dv != D
         proj = _dense(cfg, cfg.d_model, "out")
 
         if cache is not None:
@@ -310,41 +424,66 @@ class Attention(nn.Module):
             # stale or padding and the ``<= position`` mask excludes
             # them: padding and intra-chunk causality need no other.
             #
-            # Dense ``{"k", "v"}``: per-slot ``[B, S, H, D]`` rows.
-            # Paged ``{"k_pool", "v_pool", "table"}``: one ``[num_blocks,
-            # block, row]`` pool per layer, a token's ``K * D`` numbers
-            # first in its row (the engine pads the row to whole vectors
-            # of 128 lanes), written through the per-row block table;
-            # invalid positions reach the trash block by the table's
-            # last column.  Write before read, and heads in one row:
+            # Dense ``{"k", "v"}``: per-slot ``[B, S, K, D]`` rows.
+            # Paged ``{"k_pool", "v_pool", "table"}``: per layer a pool
+            # of key rows and one of value rows, ``[num_blocks, block,
+            # row]``, a token's ``K * D_k`` (``K * D_v``) numbers first
+            # in its row (the engine pads the row to whole vectors of
+            # 128 lanes), written through the per-row block table of
+            # the layer's kind: the block of positions ``[i * block, (i
+            # + 1) * block)`` is column ``i mod ring`` of the row,
+            # ``ring`` the table's width less its last column — ``i``
+            # itself in a full layer's table, which is as wide as the
+            # longest request; a window layer's is a ring of ``window /
+            # block + 1`` blocks.  Invalid positions (the engine hands
+            # out the last of a full table's view; a ring's cache names
+            # the ``limit`` from which they are so) reach the trash
+            # block by the table's last column.  Write before read, and heads in one row:
             # each keeps a donated pool updated in place
             # (docs/serving.md).  What reads the pool is chosen by the
             # chunk's shape.  One token a row (a decode step): the
-            # table is walked to each row's length, in one kernel on
-            # the TPU (``ops/paged_attention.py::paged_decode``); under
-            # tensor parallelism the step stays on the view
+            # table is walked from each row's start to its length, in
+            # one kernel on the TPU
+            # (``ops/paged_attention.py::paged_decode``); under tensor
+            # parallelism the step stays on the view
             # (docs/tp_serving.md).  A chunk of several tokens
             # (prefill buckets, a prefix hit's suffix, speculative
             # verify): the gathered view — view row ``i`` is the token
             # at position ``i`` of the row's chain — which for them is
-            # compute-shaped.
-            from ..ops import paged_attention
-
+            # compute-shaped; where the cache says the chunk begins
+            # its rows (``"fresh"``: nothing of them is in the cache
+            # yet, which is all a ring is asked for), the chunk's own
+            # keys and values.
             paged = "k_pool" in cache
+            fresh = paged and cache.get("fresh", False) and T > 1
             if paged:
                 table = cache["table"]           # [B, n_cols] block ids
                 k_pool, v_pool = cache["k_pool"], cache["v_pool"]
-                block, row = k_pool.shape[1:]
-                blk = jnp.take_along_axis(table, positions // block, axis=1)
+                block = k_pool.shape[1]
+                index = positions // block
+                if window:
+                    # A ring.  What the engine hands out as invalid (at
+                    # or past ``limit``) goes to the trash column, and
+                    # so does what a chunk longer than the ring would
+                    # overwrite again: only the newest ``ring`` blocks
+                    # of a row reach the pool.
+                    ring = table.shape[1] - 1
+                    invalid = positions >= cache["limit"]
+                    newest = jnp.max(jnp.where(invalid, -1, index), axis=1,
+                                     keepdims=True)
+                    invalid |= index <= newest - ring
+                    index = jnp.where(invalid, ring, index % ring)
+                blk = jnp.take_along_axis(table, index, axis=1)
                 off = positions % block
 
-                def rows(x):
-                    x = x.reshape(B, T, K * D).astype(k_pool.dtype)
-                    return x if row == K * D else jnp.pad(
-                        x, ((0, 0), (0, 0), (0, row - K * D)))
+                def rows(x, pool):
+                    x = x.reshape(B, T, -1).astype(pool.dtype)
+                    pad = pool.shape[2] - x.shape[2]
+                    return x if not pad else jnp.pad(
+                        x, ((0, 0), (0, 0), (0, pad)))
 
-                k_new = k_pool.at[blk, off].set(rows(k))
-                v_new = v_pool.at[blk, off].set(rows(v))
+                k_new = k_pool.at[blk, off].set(rows(k, k_pool))
+                v_new = v_pool.at[blk, off].set(rows(v, v_pool))
             else:
                 at = jnp.arange(B)[:, None]
                 k_new = cache["k"].at[at, positions].set(
@@ -353,22 +492,46 @@ class Attention(nn.Module):
                     v.astype(cache["v"].dtype))
             if paged and T == 1 and cfg.tp_mesh is None:
                 # The scope holds the attention alone; the projections
-                # are the block's, outside it.
-                with jax.named_scope("hvd_tpu_paged_attention"):
+                # are the block's, outside it.  A model of one kind of
+                # layer keeps the one name; one of two says which.
+                scope = "hvd_tpu_paged_attention" + (
+                    "" if len(set(cfg.attn_kinds)) == 1 else "_" + self.kind)
+                with jax.named_scope(scope):
                     out = paged_attention.paged_decode(
-                        q[:, 0], k_new, v_new, table, positions[:, 0],
-                        K)[:, None]
+                        q[:, 0], k_new, v_new, table, positions[:, 0], K,
+                        **(dict(v_head_dim=Dv, window=window, sink=sink)
+                           if special else {}))[:, None]
+            elif fresh:
+                out = paged_attention.view_attention(
+                    q, k.astype(k_pool.dtype), v.astype(v_pool.dtype),
+                    positions, key_positions=positions, window=window,
+                    sink=sink)
             else:
+                if paged and window:
+                    raise NotImplementedError(
+                        "a chunk of several tokens that continues a row "
+                        "of a window layer's ring is not built: the "
+                        "engine prefills such a model from position 0")
                 k_all, v_all = ((paged_attention.gathered_view(
-                    x, table, K, D) for x in (k_new, v_new)) if paged
-                    else (k_new, v_new))
-                out = paged_attention.view_attention(q, k_all, v_all,
-                                                     positions)
+                    x, table, K, d) for x, d in ((k_new, D), (v_new, Dv)))
+                    if paged else (k_new, v_new))
+                out = paged_attention.view_attention(
+                    q, k_all, v_all, positions, window=window, sink=sink)
             # Gather-before-contract: the ``out`` kernel is replicated
             # under TP, so the head outputs all-gather here and every
             # shard computes the full projection — bitwise identical.
             merged = _tp_shard(cfg, out.reshape(B, T, C))
             return proj(merged), {"k": k_new, "v": v_new}
+        if special:
+            if cfg.attention != "full" or not cfg.causal:
+                raise ValueError(
+                    f"a layer with a window, a sink or values of another "
+                    f"width than its keys is causal attention='full', not "
+                    f"{cfg.attention!r}")
+            at = jnp.broadcast_to(jnp.arange(T, dtype=jnp.int32), (B, T))
+            out = paged_attention.view_attention(q, k, v, at, window=window,
+                                                 sink=sink)
+            return proj(_tp_shard(cfg, out.reshape(B, T, C)))
         if K != H:
             # Grouped KV heads: each is read by H / K query heads.
             k = jnp.repeat(k, H // K, axis=2)
@@ -578,37 +741,41 @@ class MlpBlock(nn.Module):
                         param_dtype=cfg.param_dtype, name="down")(x)
 
 
+def _experts(cfg: GPTConfig):
+    from ..parallel.moe import DroplessExperts
+
+    return DroplessExperts(
+        d_model=cfg.d_model, d_ff=cfg.expert_d_ff,
+        n_experts=cfg.expert_count, top_k=cfg.expert_top_k,
+        shared_d_ff=cfg.expert_shared_d_ff, scale=cfg.expert_scale,
+        held=cfg.expert_held, gated=cfg.expert_mlp == "swiglu",
+        dtype=cfg.dtype, param_dtype=cfg.param_dtype, name="experts")
+
+
 class Block(nn.Module):
     config: GPTConfig
     mesh: Optional[Mesh] = None
     use_moe: bool = False
     mixer: str = "attention"
     kind: str = "block"
+    attn: str = "full"
+    ffn: str = "mlp"
 
     @nn.compact
     def __call__(self, x, cache=None, positions=None):
         cfg = self.config
         if self.kind in ("ssm", "experts"):
             # One sub-layer on its own residual, and nothing to cache.
-            if self.kind == "ssm":
-                part = Mamba2(cfg, name="ssm")
-            else:
-                from ..parallel.moe import DroplessExperts
-
-                part = DroplessExperts(
-                    d_model=cfg.d_model, d_ff=cfg.expert_d_ff,
-                    n_experts=cfg.expert_count, top_k=cfg.expert_top_k,
-                    shared_d_ff=cfg.expert_shared_d_ff,
-                    scale=cfg.expert_scale, held=cfg.expert_held,
-                    dtype=cfg.dtype, param_dtype=cfg.param_dtype,
-                    name="experts")
-            return x + part(_norm(cfg, "ln")(x))
+            part = (Mamba2(cfg, name="ssm") if self.kind == "ssm"
+                    else _experts(cfg))
+            x = x + part(_norm(cfg, "ln")(x))
+            return x if cache is None else (x, None)
         alone = self.kind == "attention"
         attn_in = _norm(cfg, "ln" if alone else "ln1")(x)
         if self.mixer == "retention" and not alone:
             attn = Retention(cfg, name="retn")
         else:
-            attn = Attention(cfg, self.mesh, name="attn")
+            attn = Attention(cfg, self.mesh, self.attn, name="attn")
         new_cache = None
         if cache is not None:
             a, new_cache = attn(attn_in, cache=cache, positions=positions)
@@ -625,6 +792,8 @@ class Block(nn.Module):
                          capacity_factor=cfg.moe_capacity_factor,
                          dtype=cfg.dtype, param_dtype=cfg.param_dtype,
                          name="moe")
+        elif self.ffn == "experts":
+            ffn = _experts(cfg)
         else:
             ffn = MlpBlock(cfg, name="mlp")
         x = x + ffn(_norm(cfg, "ln2")(x))
@@ -657,6 +826,7 @@ class GPT(nn.Module):
         cfg = self.config
         B, T = tokens.shape
         mixers, kinds = cfg.mixers, cfg.layer_kinds
+        attns, ffns = cfg.attn_kinds, cfg.ffns
         if kv_caches is not None:
             _refuse_serving(cfg)
             if cfg.attention in ("ring", "ulysses"):
@@ -694,7 +864,8 @@ class GPT(nn.Module):
             block = (nn.remat(Block) if kinds[i] in cfg.remat_layers
                      else Block)(
                 cfg, self.mesh, use_moe=use_moe, mixer=mixers[i],
-                kind=kinds[i], name=f"block_{i}")
+                kind=kinds[i], attn=attns[i], ffn=ffns[i],
+                name=f"block_{i}")
             if kv_caches is not None:
                 x, c = block(x, cache=kv_caches[i], positions=positions)
                 new_caches.append(c)

@@ -280,7 +280,9 @@ def usual_rows(pairs: int, count: int, n_experts: int) -> int:
 class DroplessExperts(nn.Module):
     """``[B, T, C] -> [B, T, C]``: ``sum_e w_e expert_e(x)`` over the
     held ones of a token's ``top_k`` experts, plus the shared expert.
-    An expert is ``down(relu(up(x)) ** 2)``.  ``held = (offset, count)``
+    An expert is ``down(relu(up(x)) ** 2)`` (squared ReLU, not gated)
+    or, ``gated``, ``down(silu(gate(x)) * up(x))``; the shared expert
+    is of the same form.  ``held = (offset, count)``
     is the contiguous range of the ``n_experts`` that this chip holds
     (None = all): the router keeps every column, the stacked kernels
     hold ``count`` experts.  With ``shared_d_ff = 0`` there is no shared
@@ -291,7 +293,11 @@ class DroplessExperts(nn.Module):
     only, and those at one cost whatever the router sent (a step's time
     does not follow the router's mood) — and all ``S x K`` rows in a
     step whose router sent more than that here: no load drops a
-    pair."""
+    pair.  A decode step of the serving engine is the same path at a
+    small shape — 48 rows of 8 experts are 384 pairs, fewer than
+    ``usual_rows`` would keep, so every pair is a sorted row, most held
+    experts get none, and the grouped products read the weights of
+    those that got one."""
 
     d_model: int
     d_ff: int
@@ -300,6 +306,7 @@ class DroplessExperts(nn.Module):
     shared_d_ff: int = 0
     scale: float = 1.0
     held: Optional[Tuple[int, int]] = None
+    gated: bool = False
     dtype: Any = jnp.bfloat16
     param_dtype: Any = jnp.float32
     interpret: Optional[bool] = None   # ops/pallas_common.resolve_interpret
@@ -318,6 +325,8 @@ class DroplessExperts(nn.Module):
         xf = x.reshape(S, C)
         init = nn.initializers.normal(0.02)
         up = self.param("up", init, (count, C, self.d_ff), self.param_dtype)
+        gate = (self.param("gate", init, (count, C, self.d_ff),
+                           self.param_dtype) if self.gated else None)
         down = self.param("down", init, (count, self.d_ff, C),
                           self.param_dtype)
         bias = self.param("select_bias", nn.initializers.zeros, (E,),
@@ -361,7 +370,9 @@ class DroplessExperts(nn.Module):
                     sizes=(sizes.at[-1].add(n - total) if even_cost
                            else sizes),
                     interpret=resolve_interpret(self.interpret))
-                h = jnp.square(nn.relu(grouped(rows, up.astype(self.dtype))))
+                h = grouped(rows, up.astype(self.dtype))
+                h = (nn.silu(grouped(rows, gate.astype(self.dtype))) * h
+                     if self.gated else jnp.square(nn.relu(h)))
                 rows = grouped(h, down.astype(self.dtype))
             with jax.named_scope("hvd_tpu_moe_route"):
                 rows = jnp.where(live, rows, 0).astype(jnp.float32) * w
@@ -379,7 +390,9 @@ class DroplessExperts(nn.Module):
                 dense = functools.partial(
                     nn.Dense, use_bias=False, dtype=self.dtype,
                     param_dtype=self.param_dtype, kernel_init=init)
-                h = jnp.square(nn.relu(
-                    dense(self.shared_d_ff, name="shared_up")(xf)))
+                h = dense(self.shared_d_ff, name="shared_up")(xf)
+                h = (nn.silu(dense(self.shared_d_ff,
+                                   name="shared_gate")(xf)) * h
+                     if self.gated else jnp.square(nn.relu(h)))
                 out = out + dense(C, name="shared_down")(h)
         return out.reshape(B, T, C)

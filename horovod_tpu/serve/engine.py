@@ -23,8 +23,17 @@ of its layers keeps (``models.transformer.cache_kinds``): keys and
 values, which ``HVD_TPU_SERVE_KV`` lays out as **paged** or **dense**,
 or a fixed-size retention **state**:
 
-* **paged** (default) — one ``[num_blocks, block, H * D]`` pool per
-  layer plus a host-side block table (``serve/kv/``): requests map
+* **paged** (default) — a key pool and a value pool per layer,
+  ``[num_blocks, block, row]``, as wide as the layer's KV heads times
+  its key or value width, plus a host-side block table for each kind of
+  layer the model declares (``serve/kv/``): *full* layers keep every
+  position through the table as wide as the longest request; *window*
+  layers keep the last ``window`` positions of a slot in a ring of
+  ``window / block + 1`` blocks of pools of their own, so that their
+  bytes do not grow with the context (a model with window layers
+  shares no prefix, keeps no migration frame and is served without
+  speculation or tensor parallelism; a preempted request of it resumes
+  if it fits the largest bucket).  Requests map
   onto refcounted fixed-size token blocks, identical prompt prefixes
   share physical blocks (copy-on-write on first divergent write), and
   unreferenced prefix blocks are LRU-evicted under pressure.  The
@@ -85,14 +94,14 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec
 
-from ..models.transformer import (GPT, cache_kinds, init_kv_cache,
+from ..models.transformer import (GPT, KVKind, cache_kinds, init_kv_cache,
                                   init_state_cache)
 from ..obs import flight as flight_mod
 from ..obs import trace as trace_mod
 from ..obs.metrics import percentile
 from ..ops.pallas_common import _LANES, round_up
 from ..utils.logging import get_logger
-from .kv import BlockPool, TRASH_BLOCK
+from .kv import BlockPool, RingPool, TRASH_BLOCK
 
 logger = get_logger(__name__)
 
@@ -257,13 +266,22 @@ class InferenceEngine:
             raise ValueError(f"no usable prefill buckets in {buckets}")
         # The model declares what its layers keep; the engine picks the
         # cache from that when the caller sets nothing.
-        kinds = set(cache_kinds(model.config))
-        if len(kinds) > 1:
+        self._declared = cache_kinds(model.config)
+        kept = [k for k in self._declared if k is not None]
+        if {isinstance(k, KVKind) for k in kept} == {True, False}:
             raise ValueError(
                 "a model that keeps K/V in some layers and a retention "
                 "state in others is not servable yet: the engine holds "
                 "one kind of cache")
-        stateful = kinds == {"state"}
+        stateful = "state" in kept
+        windows = sorted({k.window for k in kept
+                          if isinstance(k, KVKind) and k.window})
+        if len(windows) > 1:
+            raise ValueError(
+                f"window layers of several widths ({windows}) are not "
+                f"servable yet: the engine holds one ring a slot")
+        # The window layers' window, 0 where the model has none.
+        self._window = windows[0] if windows else 0
         self.kv_mode = (kv_cache or ("state" if stateful
                                      else cfg.serve_kv)).lower()
         if self.kv_mode not in ("paged", "dense", "state"):
@@ -294,6 +312,11 @@ class InferenceEngine:
         if self.tp < 1:
             raise ValueError(f"tp must be >= 1, got {self.tp}")
         if self.tp > 1:
+            if self._window:
+                raise ValueError(
+                    "tensor-parallel serving of a model with window layers "
+                    "is not built yet: full and window layers have "
+                    "different KV heads to shard")
             if self.kv_mode == "state":
                 raise ValueError(
                     "tensor-parallel serving of a retention state is not "
@@ -348,6 +371,15 @@ class InferenceEngine:
             if self._tp_mesh is not None else _home(params))
         self._step_state = {"key": self._to_device(
             jax.random.PRNGKey(seed))}
+        # Expert layers (a sub-layer alone, or a block's feed-forward):
+        # a paged decode step hands back what they were sent beside its
+        # tokens, and ``_step`` adds it to two counters here (batcher
+        # thread; ``kv_stats`` only reads them).
+        mc = model.config
+        self.expert_layers = sum(
+            kind == "experts" or (kind == "block" and ffn == "experts")
+            for kind, ffn in zip(mc.layer_kinds, mc.ffns))
+        self.expert_pairs = self.experts_touched = 0
         self._device_slots = None
         self._upload_slots(self._slot_arrays(self._slot_snapshot()))
         self._slots_sent = 0
@@ -386,9 +418,9 @@ class InferenceEngine:
         # token, which dominates decode at real cache sizes.  CPU has
         # no donation support (it would only warn), so gate on backend.
         self._donate = (1,) if jax.default_backend() != "cpu" else ()
-        n_layer = model.config.n_layer
         kv_heads, head_dim = model.config.kv_heads, model.config.head_size
         self._states = None
+        self._ring = None
         self.state_resets = 0
         if self.kv_mode == "state":
             self.kv_block = 0
@@ -412,14 +444,21 @@ class InferenceEngine:
                          else cfg.serve_kv_blocks)
             if budget == 0:
                 # Auto: every slot fully servable plus an equal share
-                # of prefix-cache headroom.
-                budget = 1 + 2 * self.max_slots * self.blocks_per_slot
+                # of prefix-cache headroom — none where window layers
+                # rule prefix sharing out.
+                budget = 1 + ((1 if self._window else 2)
+                              * self.max_slots * self.blocks_per_slot)
             if budget < floor:
                 raise ValueError(
                     f"KV pool budget {budget} below the floor {floor} "
                     f"(1 trash + slots x blocks_per_slot) — active "
                     f"requests could deadlock on allocation")
             self.kv_blocks = budget
+            # A window layer's ring: the blocks a window can touch.
+            self.ring_blocks = min(-(-self._window // self.kv_block) + 1,
+                                   self.blocks_per_slot) if self._window \
+                else 0
+            ring_budget = 1 + self.max_slots * self.ring_blocks
             # A pool row holds a token's heads side by side, padded to
             # whole vectors of 128 lanes: the decode kernel copies
             # blocks out of the pool, and the chip copies only whole
@@ -427,14 +466,16 @@ class InferenceEngine:
             # layout pads a row so anyway, so the pad costs no memory
             # there.  A head-sharded pool keeps heads alone in its row:
             # a shard's part has to be whole heads, and the
-            # tensor-parallel step reads through the view.
+            # tensor-parallel step reads through the view.  Each layer
+            # has the rows it declared (``cache_kinds``): keys and
+            # values may differ in width, and layers in both.
             self._kv_row = kv_heads * head_dim
-            shape = (budget, self.kv_block,
-                     self._kv_row if self.tp > 1
-                     else round_up(self._kv_row, _LANES))
 
-            def _pool_zeros():
-                z = jnp.zeros(shape, model.config.dtype)
+            def _pool_zeros(blocks, row):
+                z = jnp.zeros(
+                    (blocks, self.kv_block,
+                     row if self.tp > 1 else round_up(row, _LANES)),
+                    model.config.dtype)
                 if self._tp_mesh is not None:
                     # Head-sharded pool: each shard device holds only
                     # its H/tp heads' part of every row; the block
@@ -444,22 +485,40 @@ class InferenceEngine:
                         PartitionSpec(None, None, "tensor")))
                 return _beside(params, z)
 
-            self._pools = [{"k": _pool_zeros(), "v": _pool_zeros()}
-                           for _ in range(n_layer)]
-            # Block table: one trailing trash column the jitted
-            # programs clamp invalid positions into (serve/kv/pool.py).
-            self._table = np.full(
+            self._pools = [
+                None if kind is None else {
+                    "k": _pool_zeros(ring_budget if kind.window else budget,
+                                     kind.k_row),
+                    "v": _pool_zeros(ring_budget if kind.window else budget,
+                                     kind.v_row)}
+                for kind in self._declared]
+            # Block tables, one for each kind of layer the model
+            # declares, each with a trailing trash column the jitted
+            # programs clamp invalid positions into (serve/kv/pool.py):
+            # a full layer's is as wide as the longest request, a window
+            # layer's a slot's ring.  A program is handed all of them
+            # and each layer reads its kind's (``_paged_caches``).
+            self._tables = {"full": np.full(
                 (self.max_slots, self.blocks_per_slot + 1),
-                TRASH_BLOCK, np.int32)
-            # The device's copy and the bytes it was made from: the
+                TRASH_BLOCK, np.int32)}
+            if self._window:
+                self._tables["window"] = np.full(
+                    (self.max_slots, self.ring_blocks + 1), TRASH_BLOCK,
+                    np.int32)
+            # The device's copies and the bytes they were made from: a
             # table goes up when it changed (a block boundary, an
             # admission, a release), not every step.
-            self._table_sent = None
-            self._table_device = None
+            self._table_sent = dict.fromkeys(self._tables)
+            self._table_device = dict.fromkeys(self._tables)
             self.table_uploads = 0
             self.paged_decode_steps = 0
             self.paged_live_blocks = 0
             self.paged_view_blocks = 0
+            # Positions a decode step's attention reads, by kind of
+            # layer, and blocks in use, each summed over the steps.
+            self.paged_live_rows = 0
+            self.paged_live_positions = dict.fromkeys(self._tables, 0)
+            self.kv_block_steps = dict.fromkeys(self._tables, 0)
             self._copy_fn = jax.jit(
                 self._copy_impl,
                 donate_argnums=(0,) if self._donate else ())
@@ -467,15 +526,27 @@ class InferenceEngine:
                 self._import_impl,
                 donate_argnums=(0,) if self._donate else ())
             dt_size = np.dtype(model.config.dtype).itemsize
-            self._kv = BlockPool(
-                budget, self.kv_block, self._table, self._copy_block,
-                heads=kv_heads // self.tp,
-                tp_degree=self.tp,
+
+            def _block_bytes(windowed: bool) -> int:
                 # Per-SHARD bytes of one block: K+V rows for the K/tp
-                # heads this shard holds, across every layer.
-                bytes_per_block=(2 * n_layer * self.kv_block
-                                 * (kv_heads // self.tp)
-                                 * head_dim * dt_size))
+                # heads this shard holds, across the kind's layers.
+                return sum((k.k_row + k.v_row) // self.tp
+                           for k in self._declared if k is not None
+                           and bool(k.window) == windowed
+                           ) * self.kv_block * dt_size
+
+            # One allocator a kind: chains that grow and share
+            # prefixes, and rings, which rule sharing out for the whole
+            # request.
+            self._kv = BlockPool(
+                budget, self.kv_block, self._tables["full"],
+                self._copy_block, heads=kv_heads // self.tp,
+                tp_degree=self.tp,
+                bytes_per_block=_block_bytes(False),
+                index_prefixes=not self._window)
+            self._ring = RingPool(
+                ring_budget, self._tables["window"],
+                _block_bytes(True)) if self._window else None
             self._caches = None
             self._decode_fn = jax.jit(self._decode_paged_impl,
                                       donate_argnums=self._donate)
@@ -499,6 +570,11 @@ class InferenceEngine:
         self.spec_verify_steps = 0
         self.spec_accepted_tokens = 0
         if drafter is not None:
+            if self._window:
+                raise ValueError(
+                    "speculative decoding over window layers is not built "
+                    "yet: a rejected draft's writes have already pushed "
+                    "live positions out of the ring")
             if self.kv_mode == "state":
                 raise ValueError(
                     "speculative decoding over a retention state is not "
@@ -656,14 +732,21 @@ class InferenceEngine:
 
     # --- compiled programs: paged tier --------------------------------------
 
-    def _paged_caches(self, pools, tables):
-        return [{"k_pool": pools[i]["k"], "v_pool": pools[i]["v"],
-                 "table": tables}
-                for i in range(self._model.config.n_layer)]
+    def _paged_caches(self, pools, tables, fresh: bool = False):
+        """The model's ``kv_caches`` over the pools: each layer with the
+        table of its kind (``tables``: ``{kind: table}``), a ring with
+        the position from which a row is invalid.  ``fresh``: the
+        chunk begins its rows, as every prefill over a ring does."""
+        return [None if p is None else
+                {"k_pool": p["k"], "v_pool": p["v"], "fresh": fresh,
+                 **({"table": tables["window"], "limit": self.max_seq_len}
+                    if kind.window else {"table": tables["full"]})}
+                for p, kind in zip(pools, self._declared)]
 
     def _copy_impl(self, pools, src, dst):
         self.trace_counts["kv_copy"] += 1  # trace-time only
-        return [{"k": p["k"].at[dst].set(p["k"][src]),
+        return [None if p is None else
+                {"k": p["k"].at[dst].set(p["k"][src]),
                  "v": p["v"].at[dst].set(p["v"][src])} for p in pools]
 
     def _copy_block(self, src: int, dst: int) -> None:
@@ -702,7 +785,9 @@ class InferenceEngine:
             # Invalid rows (padding, past the cache) take the view's
             # last position: the table's trash column.
             positions = jnp.where(valid, start + idx, SV - 1)
-            caches = self._paged_caches(pools, table_row[None])
+            caches = self._paged_caches(
+                pools, jax.tree.map(lambda t: t[None], table_row),
+                fresh=bool(self._window))
             logits, new = model.apply(
                 {"params": params}, tokens, kv_caches=caches,
                 positions=positions[None])
@@ -717,10 +802,25 @@ class InferenceEngine:
     def _decode_paged_impl(self, params, pools, tables, step):
         self.trace_counts["decode"] += 1  # trace-time only
         caches = self._paged_caches(pools, tables)
-        logits, new = self._model.apply(
+        if not self.expert_layers:
+            logits, new = self._model.apply(
+                {"params": params}, step["tokens"][:, None],
+                kv_caches=caches, positions=step["positions"][:, None])
+            return new, _advance(step, logits)
+        # A model with expert layers: what each sowed of the step's
+        # routing (``pairs_held``: the pairs each held expert was sent)
+        # goes back with the tokens as ``experts_sent`` — pairs held,
+        # held experts sent a pair — for the host to add up (``_step``).
+        (logits, new), sown = self._model.apply(
             {"params": params}, step["tokens"][:, None], kv_caches=caches,
-            positions=step["positions"][:, None])
-        return new, _advance(step, logits)
+            positions=step["positions"][:, None], mutable=["intermediates"])
+        sizes = jnp.stack([
+            leaf for path, leaf in jax.tree_util.tree_flatten_with_path(
+                sown)[0]
+            if any(getattr(k, "key", None) == "pairs_held" for k in path)])
+        return new, dict(
+            _advance(step, logits),
+            experts_sent=jnp.stack([sizes.sum(), (sizes > 0).sum()]))
 
     # --- compiled programs: state tier --------------------------------------
 
@@ -951,16 +1051,39 @@ class InferenceEngine:
             return int(self._positions[slot]) >= self.max_seq_len
 
     def _device_table(self):
-        """The block table on the device, sent again only when the
-        host's differs from the bytes last sent (``ensure_writable``,
-        ``begin_request`` and ``release`` change it: a row crosses a
-        block boundary one step in ``kv_block``)."""
-        if (self._table_sent is None
-                or not np.array_equal(self._table, self._table_sent)):
-            self._table_sent = self._table.copy()
-            self._table_device = self._to_device(self._table_sent)
-            self.table_uploads += 1
-        return self._table_device
+        """The block tables on the device, ``{kind: table}``, each sent
+        again only when the host's differs from the bytes last sent
+        (``_ensure_writable``, ``_begin_request`` and ``release``
+        change it: a row crosses a block boundary one step in
+        ``kv_block``; a full ring changes no more)."""
+        for kind, table in self._tables.items():
+            if (self._table_sent[kind] is None
+                    or not np.array_equal(table, self._table_sent[kind])):
+                self._table_sent[kind] = table.copy()
+                self._table_device[kind] = self._to_device(
+                    self._table_sent[kind])
+                self.table_uploads += 1
+        return dict(self._table_device)
+
+    def _table_rows(self, slot: int):
+        """``slot``'s row of each table, for a prefill."""
+        return {kind: jnp.asarray(t[slot])
+                for kind, t in self._tables.items()}
+
+    def _begin_request(self, slot: int, seq: List[int]) -> int:
+        """``slot``'s blocks for a new request: a chain over whatever
+        prefix of ``seq`` is resident (its length is returned) and,
+        beside window layers, an empty ring."""
+        hit = self._kv.begin_request(slot, seq)
+        if self._ring is not None:
+            self._ring.begin(slot)
+        return hit
+
+    def _ensure_writable(self, slot: int, start: int, n: int) -> None:
+        """Blocks of every kind for positions ``[start, start + n)``."""
+        self._kv.ensure_writable(slot, start, n)
+        if self._ring is not None:
+            self._ring.reach(slot, (start + n - 1) // self.kv_block)
 
     def _slot_snapshot(self):
         """Locked copy of the decode-relevant slot arrays: the step
@@ -1060,16 +1183,19 @@ class InferenceEngine:
             token = self._state_prefill(slot, prompt, sampling, span_args,
                                         stage=True)
         elif self.kv_mode == "paged":
-            hit = self._kv.begin_request(slot, prompt)
+            hit = self._begin_request(slot, prompt)
             ns = n - hit
             L = self.bucket_for(ns)
-            self._kv.ensure_writable(slot, hit, ns)
+            self._ensure_writable(slot, hit, ns)
             padded = np.zeros((1, L), np.int32)
             padded[0, :ns] = np.asarray(prompt[hit:], np.int32)
             fn = self._prefill_fns[L]
             span_args.update(bucket=L, prefix_hit=hit)
+            if self._window:
+                span_args["window_blocks"] = min(
+                    -(-n // self.kv_block), self.ring_blocks)
             args = (self._params, self._pools,
-                    jnp.asarray(self._table[slot]), jnp.asarray(padded),
+                    self._table_rows(slot), jnp.asarray(padded),
                     jnp.int32(hit), jnp.int32(ns), self._rng,
                     jnp.float32(sampling.temperature),
                     jnp.int32(sampling.top_k))
@@ -1162,7 +1288,7 @@ class InferenceEngine:
                 self._params, self._states, self._step_state)
         elif self.kv_mode == "paged":
             for s in active:
-                self._kv.ensure_writable(s, int(pos[s]), 1)
+                self._ensure_writable(s, int(pos[s]), 1)
             if self._tp_mesh is None:
                 # The decode step's attention walks each row's table to
                 # its length (ops/paged_attention.py); the gathered
@@ -1172,7 +1298,16 @@ class InferenceEngine:
                 span_args["live_blocks"] = live
                 self.paged_decode_steps += 1
                 self.paged_live_blocks += live
-                self.paged_view_blocks += self._table.size
+                self.paged_view_blocks += self._tables["full"].size
+                seen = np.where(act, pos + 1, 0)
+                self.paged_live_rows += len(active)
+                self.paged_live_positions["full"] += int(seen.sum())
+                self.kv_block_steps["full"] += self._kv.blocks_in_use()
+                if self._ring is not None:
+                    self.paged_live_positions["window"] += int(
+                        np.minimum(seen, self._window).sum())
+                    self.kv_block_steps["window"] += \
+                        self._ring.blocks_in_use()
             sent = self.table_uploads
             table = self._device_table()
             uploads += self.table_uploads - sent
@@ -1181,6 +1316,9 @@ class InferenceEngine:
         else:
             self._caches, advanced = self._dispatch_decode(
                 self._params, self._caches, self._step_state)
+        # What the expert layers were sent rides back with the tokens
+        # and is no part of the state the next step takes.
+        experts_sent = advanced.pop("experts_sent", None)
         self._step_state = dict(self._step_state, **advanced)
         self.decode_steps += 1
         # Whether the program's sampling branch ran, from the mirror.
@@ -1204,6 +1342,10 @@ class InferenceEngine:
                 dispatch_usual)
         else:
             nxt = np.asarray(advanced["tokens"])
+        if experts_sent is not None:
+            pairs, touched = np.asarray(experts_sent)
+            self.expert_pairs += int(pairs)
+            self.experts_touched += int(touched)
         held = self._device_slots
         self._device_slots = dict(
             held, tokens=nxt, positions=held["positions"] + held["active"])
@@ -1371,6 +1513,8 @@ class InferenceEngine:
         array."""
         if self._kv is not None:
             self._kv.release(slot)
+        if self._ring is not None:
+            self._ring.release(slot)
         self._clear_slot(slot)
 
     # --- deadline-aware preemption (serve/qos/; docs/qos.md) ----------------
@@ -1413,7 +1557,9 @@ class InferenceEngine:
         so on drafter engines only sequences fitting the largest
         bucket are preemptible (the scheduler skips other victims)."""
         n = n_prompt + max(0, n_emitted - 1)
-        if self._drafter is not None:
+        if self._drafter is not None or self._window:
+            # ... and a window layer's ring takes a prefill from
+            # position 0 only, in one chunk.
             return n <= self.prefill_buckets[-1]
         return 0 < n < self.max_seq_len
 
@@ -1465,7 +1611,7 @@ class InferenceEngine:
             span_args["prefix_hit"] = 0
             self._state_prefill(slot, seq, sampling, span_args)
         elif self.kv_mode == "paged":
-            hit = self._kv.begin_request(slot, seq)
+            hit = self._begin_request(slot, seq)
             span_args["prefix_hit"] = hit
             # Recompute the non-resident tail in bucket-sized chunks:
             # the paged prefill program takes a start offset, so a
@@ -1474,18 +1620,23 @@ class InferenceEngine:
             # rebuilds — an ordinary prompt never needs this, a resume
             # must not die on it.
             top = self.prefill_buckets[-1]
+            if self._window and n > top:
+                raise RuntimeError(
+                    f"a sequence of {n} tokens does not resume over window "
+                    f"layers: their ring is prefilled from position 0 in "
+                    f"one chunk, of at most {top}")
             pos = hit
             while pos < n:
                 ns = min(n - pos, top)
                 L = self.bucket_for(ns)
-                self._kv.ensure_writable(slot, pos, ns)
+                self._ensure_writable(slot, pos, ns)
                 padded = np.zeros((1, L), np.int32)
                 padded[0, :ns] = np.asarray(seq[pos:pos + ns], np.int32)
                 fn = self._prefill_fns[L]
                 span_args["bucket"] = L     # the last chunk's
                 _, self._pools, self._rng = fn(
                     self._params, self._pools,
-                    jnp.asarray(self._table[slot]),
+                    self._table_rows(slot),
                     jnp.asarray(padded), jnp.int32(pos),
                     jnp.int32(ns), self._rng,
                     jnp.float32(sampling.temperature),
@@ -1593,6 +1744,15 @@ class InferenceEngine:
     # the device pools the compiled programs donate), exactly like
     # start()/step() — the fleet layer routes both through the batcher.
 
+    def _refuse_frame(self, what: str) -> None:
+        rows = {row for k in self._declared if k is not None
+                for row in (k.k_row, k.v_row)}
+        if self._window or len(rows) > 1 or None in self._declared:
+            raise RuntimeError(
+                f"a model whose layers keep different rows (window layers, "
+                f"KV heads or widths by layer) has no migration frame yet: "
+                f"{what} ships one K/V shape for every layer")
+
     def export_slot_kv(self, slot: int):
         """Export ``slot``'s resident KV as ``(chain_len, k, v)`` numpy
         arrays of shape ``[n_layer, n_blocks, block, H, D]`` — the
@@ -1607,6 +1767,7 @@ class InferenceEngine:
         if self.kv_mode != "paged":
             raise RuntimeError("KV export requires the paged cache "
                                "(HVD_TPU_SERVE_KV=paged)")
+        self._refuse_frame("export_slot_kv")
         chain = self._kv.chain_blocks(slot)
         if not chain:
             raise RuntimeError(f"slot {slot} has no KV chain to export")
@@ -1655,6 +1816,7 @@ class InferenceEngine:
         if self.kv_mode != "paged":
             raise RuntimeError("KV import requires the paged cache "
                                "(HVD_TPU_SERVE_KV=paged)")
+        self._refuse_frame("import_slot_kv")
         with self._slot_lock:
             if self._active[slot]:
                 raise RuntimeError(f"slot {slot} is already active")
@@ -1772,6 +1934,32 @@ class InferenceEngine:
             out["paged_decode_steps"] = self.paged_decode_steps
             out["paged_live_blocks"] = self.paged_live_blocks
             out["paged_view_blocks"] = self.paged_view_blocks
+            out["paged_live_rows"] = self.paged_live_rows
+            # By kind of layer: positions a step's attention read and
+            # blocks it found in use, each summed over the steps, and
+            # bytes in use now and as a step's mean.
+            pools = {"full": self._kv}
+            if self._ring is not None:
+                out.update(self._ring.stats())
+                pools["window"] = self._ring
+            steps = max(1, self.paged_decode_steps)
+            for kind, pool in pools.items():
+                out[f"paged_live_positions_{kind}"] = \
+                    self.paged_live_positions[kind]
+                out[f"kv_{kind}_block_steps"] = self.kv_block_steps[kind]
+                out[f"kv_{kind}_layers"] = sum(
+                    k is not None and bool(k.window) == (kind == "window")
+                    for k in self._declared)
+                out[f"kv_{kind}_bytes_in_use"] = (
+                    pool.blocks_in_use() * pool.bytes_per_block)
+                out[f"kv_{kind}_bytes_in_use_a_step"] = (
+                    self.kv_block_steps[kind] / steps * pool.bytes_per_block)
+        if self.expert_layers:
+            out["expert_layers"] = self.expert_layers
+            out["experts_held"] = (self._model.config.expert_held
+                                   or (0, self._model.config.expert_count))[1]
+            out["expert_pairs_held"] = self.expert_pairs
+            out["experts_touched"] = self.experts_touched
         if self._states is not None:
             out["state_bytes"] = int(sum(
                 x.nbytes for x in jax.tree.leaves(self._states)))

@@ -23,3 +23,4 @@ Knobs: ``HVD_TPU_SERVE_KV`` (``paged``/``dense``),
 
 from .pool import BlockPool, KVPoolExhaustedError, TRASH_BLOCK  # noqa: F401
 from .prefix import PrefixIndex  # noqa: F401
+from .ring import RingPool  # noqa: F401

@@ -1,9 +1,13 @@
 """Refcounted block pool: the host-side allocator behind paged KV.
 
 One :class:`BlockPool` manages the block ids of one engine's
-preallocated per-layer device pools (``[num_blocks, block, H * D]``;
-the device arrays themselves live in the engine — this module never
-imports jax).  Responsibilities:
+preallocated per-layer device pools (``[num_blocks, block, row]``, a
+key pool and a value pool a layer, each as wide as the layer's KV heads
+times its key or value width; the device arrays themselves live in the
+engine — this module never imports jax).  It allocates for layers that
+keep every position of a request; window layers, which keep a ring a
+slot, have :class:`~horovod_tpu.serve.kv.ring.RingPool` beside it.
+Responsibilities:
 
 * **Allocation** — block ids come from a free list; block 0 is
   reserved as the *trash block*: unmapped block-table entries point at
@@ -67,7 +71,8 @@ class BlockPool:
     def __init__(self, num_blocks: int, block_tokens: int, table,
                  copy_block, *, heads: Optional[int] = None,
                  tp_degree: int = 1,
-                 bytes_per_block: Optional[int] = None) -> None:
+                 bytes_per_block: Optional[int] = None,
+                 index_prefixes: bool = True) -> None:
         if num_blocks < 2:
             raise ValueError(
                 f"block pool needs >= 2 blocks (one is the reserved "
@@ -98,6 +103,10 @@ class BlockPool:
         self._evictable: "collections.OrderedDict" = \
             collections.OrderedDict()             # guarded-by: _lock
         self._index = PrefixIndex(block_tokens)   # guarded-by: _lock
+        # False where the engine holds window layers beside these: a
+        # ring keeps no beginning another request could join, so nothing
+        # is indexed and no prompt ever matches.
+        self.index_prefixes = bool(index_prefixes)
         # Leading-block keys whose depth-0 block was evicted since the
         # last drain — piggybacked on response frames so the fleet's
         # global prefix directory can drop the entry (bounded: a missed
@@ -293,10 +302,11 @@ class BlockPool:
         """Register ``slot``'s prompt blocks in the prefix index (after
         prefill wrote them): full blocks as trie edges, the partial
         tail as a partial leaf.  Indexed blocks outlive the request —
-        release parks them in the LRU instead of freeing."""
+        release parks them in the LRU instead of freeing.  Nothing
+        where ``index_prefixes`` is off."""
         with self._lock:
             chain = self._chains.get(slot)
-            if chain:
+            if chain and self.index_prefixes:
                 self._index.insert(list(prompt), chain)
 
     def release(self, slot: int) -> None:

@@ -457,10 +457,9 @@ class TestWholeModel:
             _close(x, y, 1e-5)
 
     def test_serving_is_refused_in_one_sentence(self, model, params):
-        sentence = (r"serving a model with \['experts', 'ssm'\] layers is "
-                    r"not built: a state-space layer needs a state cache "
-                    r"beside the K/V cache in one cache manager, and the "
-                    r"engine has no expert layer \(serve/engine.py\)")
+        sentence = (r"serving a model with 'ssm' layers is not built: a "
+                    r"state-space layer needs a state cache beside the K/V "
+                    r"cache in one cache manager \(serve/engine.py\)")
         with pytest.raises(NotImplementedError, match=sentence):
             cache_kinds(model.config)
         tokens = jnp.zeros((1, 4), jnp.int32)

@@ -24,7 +24,7 @@ from hvdbench.models import brumby as family          # noqa: E402
 from hvdbench.reference import brumby as ref           # noqa: E402
 from horovod_tpu.models import GPT, GPTConfig          # noqa: E402
 from horovod_tpu.models.transformer import (           # noqa: E402
-    cache_kinds, init_kv_cache, init_state_cache)
+    KVKind, cache_kinds, init_kv_cache, init_state_cache)
 from horovod_tpu.ops import retention                  # noqa: E402
 from horovod_tpu.serve import (ContinuousBatcher,      # noqa: E402
                                InferenceEngine, SamplingParams)
@@ -255,7 +255,8 @@ def test_attention_with_the_same_vocabulary_decodes_through_its_cache():
     tokens = jnp.asarray([_tokens(31)], jnp.int32)
     p = m.init(jax.random.PRNGKey(0), tokens)["params"]
     assert p["block_0"]["attn"]["qkv"]["kernel"].shape == (48, (4 + 4) * 16)
-    assert cache_kinds(cfg) == ("kv", "kv")
+    # Two KV heads of 16: rows of 32 for keys and for values, no window.
+    assert cache_kinds(cfg) == (KVKind(32, 32, 0),) * 2
     want = m.apply({"params": p}, tokens)
     kv = init_kv_cache(cfg, 1, 64)
     assert kv[0]["k"].shape == (1, 64, 2, 16)
@@ -275,7 +276,7 @@ def test_attention_with_the_same_vocabulary_decodes_through_its_cache():
 
 def test_a_per_layer_mixer_is_checked():
     cfg = GPTConfig(n_layer=2, mixer=("attention", "retention"))
-    assert cache_kinds(cfg) == ("kv", "state")
+    assert cache_kinds(cfg) == (KVKind(768, 768, 0), "state")
     with pytest.raises(ValueError, match="mixer must be"):
         GPTConfig(n_layer=2, mixer=("attention",)).mixers
     with pytest.raises(ValueError, match="mixer must be"):
@@ -543,7 +544,7 @@ def test_gpt2_parameter_tree_is_the_parents(gpt2):
     got = sorted((jax.tree_util.keystr(k), v.shape) for k, v in
                  jax.tree_util.tree_flatten_with_path(p)[0])
     assert got == _GPT2_PATHS
-    assert cache_kinds(gpt2[0].config) == ("kv", "kv")
+    assert cache_kinds(gpt2[0].config) == (KVKind(64, 64, 0),) * 2
 
 
 def test_gpt2_logits_through_prefill_and_decode_are_the_parents(gpt2):

@@ -441,15 +441,17 @@ class TestPagedWriteThenRead:
         params, pools = eng.params, eng._pools
         if name == "decode":
             return eng._decode_paged_impl, (
-                params, pools, i32(n, cols), eng._step_state), 1
+                params, pools, {"full": i32(n, cols)}, eng._step_state), 1
         if name.startswith("prefill_"):
             L = int(name.split("_")[1])
             return eng._make_paged_prefill(L).__wrapped__, (
-                params, pools, i32(cols), i32(1, L), i32(), i32(), rng,
+                params, pools, {"full": i32(cols)}, i32(1, L), i32(), i32(),
+                rng,
                 f32(), i32()), 1
         if name == "spec_verify":
             return eng._spec_verify_impl, (
-                params, pools, i32(n, cols), i32(n), i32(n, eng.spec_k),
+                params, pools, {"full": i32(n, cols)}, i32(n),
+                i32(n, eng.spec_k),
                 i32(n), f32(n), i32(n), jnp.zeros(n, bool), rng), 1
         if name == "kv_copy":
             return eng._copy_impl, (pools, i32(), i32()), 0

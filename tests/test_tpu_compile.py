@@ -281,6 +281,32 @@ def test_paged_decode_kernel_compiles_for_v5e(topo):
     assert "hvd_tpu_paged_decode" in text
 
 
+@pytest.mark.parametrize("kind, kv_heads, blocks, cols, window", [
+    ("full", 4, 12289, 257, 0), ("window", 8, 433, 10, 128)])
+def test_paged_decode_kernel_compiles_at_mimo_v2_widths(topo, kind, kv_heads,
+                                                        blocks, cols, window):
+    """The kernel at MiMo-V2-Flash's published shapes, as the cell
+    ``mimov2flash-serve-reason`` runs it: 48 rows, 64 query heads, keys
+    192 and values 128 wide; a full layer's 4 KV heads (rows of 768 and
+    512) over a table of 256 + 1 columns, and a window layer's 8 (1,536
+    and 1,024) over a ring of 9 + 1 with a start, and a sink a head."""
+    from horovod_tpu.ops import paged_attention
+
+    one = SingleDeviceSharding(topo.devices[0])
+
+    def sds(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    text = _compile(
+        lambda q, k, v, table, pos, sink: paged_attention.paged_decode(
+            q, k, v, table, pos, kv_heads, v_head_dim=128, window=window,
+            sink=sink if window else None, interpret=False),
+        sds((48, 64, 192)), sds((blocks, 16, kv_heads * 192)),
+        sds((blocks, 16, kv_heads * 128)), sds((48, cols), jnp.int32),
+        sds((48,), jnp.int32), sds((64,), jnp.float32))
+    assert "hvd_tpu_paged_decode" in text
+
+
 def _described(topo, tree):
     one = SingleDeviceSharding(topo.devices[0])
     return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
@@ -310,7 +336,7 @@ def _paged_decode_program(topo, monkeypatch, n_layer):
     n, cols = eng.max_slots, eng.blocks_per_slot + 1
     text = jax.jit(eng._decode_paged_impl, donate_argnums=(1,)).lower(
         _described(topo, params), _described(topo, eng._pools),
-        _described(topo, jnp.zeros((n, cols), jnp.int32)),
+        _described(topo, {"full": jnp.zeros((n, cols), jnp.int32)}),
         _described(topo, eng._step_state)).compile().as_text()
     assert "hvd_tpu_paged_decode" in text
     return text, eng
