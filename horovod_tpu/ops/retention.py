@@ -40,9 +40,11 @@ that a feature row ``phi(k)[r]`` lies along the lanes of ``S[r]``) and
 (``hvd_tpu_retention_step``): XLA makes three passes over a layer's
 state (a fusion reads ``S`` and writes ``g S + v phi(k)^T``, a second
 reads the new ``S`` for the read-out), the kernel one: each ``S[b, k]``
-comes into VMEM once, is updated, read out against the group's
-``phi(q)`` on the MXU, and goes back in place.  Off the TPU the same
-arithmetic runs as plain ``jax.numpy``.
+of a row that holds a request comes into VMEM once, is updated, read
+out against the group's ``phi(q)`` on the MXU, and goes back in place;
+a row without one is not visited, and no block of its state moves.
+Off the TPU the same arithmetic runs as plain ``jax.numpy`` over every
+row.
 
 The degree (2: it is the feature map's) and ``EPS`` are the operator's
 definition as this repository runs it, not knobs: the benchmark's
@@ -212,40 +214,78 @@ def retention_chunked(q, k, v, log_g, state: Optional[tuple] = None,
     return o, (jnp.swapaxes(S, -1, -2), z)
 
 
-def _step_kernel(g_ref, v_ref, pk_ref, pq_ref, s_ref, s_out_ref, num_ref):
+def _step_kernel(rows_ref, n_ref, g_ref, v_ref, pk_ref, pq_ref, s_ref,
+                 s_out_ref, num_ref):
     """One ``(row, KV head)``: ``S[r] = g S[r] + v phi(k)[r]^T`` for
     every feature row ``r``, written back in place, and the group's
     read-out ``num[g] = sum_r phi(q)[r, g] . S[r]^T`` on the MXU with
     bfloat16 inputs (what XLA's default precision gives the same
-    contraction) and a float32 sum."""
+    contraction) and a float32 sum.  A grid step past the rows to visit
+    (``n_ref``) computes nothing; ``_step_pallas`` has why it copies
+    nothing either."""
+    del rows_ref                          # the index maps read it
     rows = s_ref.shape[2]
-    g_row = g_ref[0, 0]                   # [1, d]: g, the same in every lane
-    v_cols = v_ref[0, 0]                  # [d_v, d]: v along the sublanes
 
-    def body(r, acc):
-        s = (s_ref[0, 0, r] * g_row
-             + v_cols * pk_ref[0, 0, pl.ds(r, 1), :])
-        s_out_ref[0, 0, r] = s
-        return acc + jax.lax.dot_general(
-            pq_ref[0, 0, r].astype(jnp.bfloat16), s.astype(jnp.bfloat16),
-            (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
+    @pl.when(pl.program_id(0) < n_ref[0])
+    def _():
+        g_row = g_ref[0, 0]               # [1, d]: g, the same in every lane
+        v_cols = v_ref[0, 0]              # [d_v, d]: v along the sublanes
 
-    num_ref[0, 0] = jax.lax.fori_loop(
-        0, rows, body, jnp.zeros(num_ref.shape[2:], jnp.float32))
+        def body(r, acc):
+            s = (s_ref[0, 0, r] * g_row
+                 + v_cols * pk_ref[0, 0, pl.ds(r, 1), :])
+            s_out_ref[0, 0, r] = s
+            return acc + jax.lax.dot_general(
+                pq_ref[0, 0, r].astype(jnp.bfloat16), s.astype(jnp.bfloat16),
+                (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
+
+        num_ref[0, 0] = jax.lax.fori_loop(
+            0, rows, body, jnp.zeros(num_ref.shape[2:], jnp.float32))
 
 
 def _step_plain(g, v, phi_k, phi_q, S):
     """``(S_new, num)`` of one decode step in ``jax.numpy``: what runs
-    off the TPU, and what the tests hold the kernel to."""
+    off the TPU, and what the tests hold the kernel to.  Every row is
+    computed: ``_step`` has made a row without a request the
+    identity."""
     S = (g[..., None, None, None] * S
          + v[:, :, None, :, None] * phi_k[:, :, :, None, :])
     return S, jnp.einsum("bkgri,bkrvi->bkgv", phi_q, S)
 
 
-def _step_pallas(g, v, phi_k, phi_q, S, *, interpret: bool):
+def _visits(valid, batch: int):
+    """The rows a step visits: ``rows [B] int32``, the valid rows first
+    in slot order and the last of them again in every place after, and
+    ``n [1] int32``, how many places count.  A step with no valid row
+    visits row 0: a grid that visited nothing would still write its one
+    never-filled output buffer back over a block of the state, and
+    ``_step`` has made the visit of a row that is not valid the
+    identity."""
+    if valid is None:
+        return (jnp.arange(batch, dtype=jnp.int32),
+                jnp.full((1,), batch, jnp.int32))
+    slot = jnp.arange(batch, dtype=jnp.int32)
+    place = jnp.cumsum(valid, dtype=jnp.int32) - 1    # of a valid row
+    # No sort and no scatter: [B, B] compares (B is the slots).
+    rows = jnp.sum(jnp.where(valid[:, None] & (place[:, None] == slot),
+                             slot[:, None], 0), axis=0)
+    n = jnp.maximum(place[-1] + 1, 1)
+    last = jnp.max(jnp.where(valid, slot, 0))
+    return jnp.where(slot < n, rows, last), n[None]
+
+
+def _step_pallas(g, v, phi_k, phi_q, S, valid=None, *, interpret: bool):
     """``(S_new, num)`` of one decode step through the kernel.  ``g [B,
     K]``, ``v [B, K, d]``, ``phi_k [B, K, R, d]``, ``phi_q [B, K, G, R,
-    d]``, ``S [B, K, R, d, d]``, all float32."""
+    d]``, ``S [B, K, R, d, d]``, all float32; ``valid [B]`` (None: every
+    row).  Only the valid rows' blocks of ``S`` are copied in and out:
+    the grid is ``(B, K)`` whatever ``valid`` holds (one program), step
+    ``(b, k)`` works on block ``(rows[b], k)`` while ``b`` is under the
+    number of rows to visit, and every later step names the last live
+    step's block again — for every operand and result, so that the
+    pipeline sees a block index that did not change and issues no copy
+    — and computes nothing.  ``num`` of a row not visited is memory
+    nobody wrote."""
     B, K, R, d_v, d = S.shape
     G = phi_q.shape[2]
     Gp = -(-G // _SUBLANES) * _SUBLANES
@@ -257,23 +297,29 @@ def _step_pallas(g, v, phi_k, phi_q, S, *, interpret: bool):
     pq = jnp.pad(jnp.moveaxis(phi_q, 2, 3),
                  ((0, 0), (0, 0), (0, 0), (0, Gp - G), (0, 0)))
     per_head = lambda *tail: pl.BlockSpec(                  # noqa: E731
-        (1, 1) + tail, lambda b, k: (b, k) + (0,) * len(tail))
+        (1, 1) + tail,
+        lambda b, k, rows, n: (rows[b], jnp.where(b < n[0], k, K - 1))
+        + (0,) * len(tail))
     S_new, num = pl.pallas_call(
         _step_kernel,
-        grid=(B, K),
-        in_specs=[per_head(1, d), per_head(d_v, d), per_head(R, d),
-                  per_head(R, Gp, d), per_head(R, d_v, d)],
-        out_specs=[per_head(R, d_v, d), per_head(Gp, d_v)],
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(B, K),
+            in_specs=[per_head(1, d), per_head(d_v, d), per_head(R, d),
+                      per_head(R, Gp, d), per_head(R, d_v, d)],
+            out_specs=[per_head(R, d_v, d), per_head(Gp, d_v)]),
         out_shape=[jax.ShapeDtypeStruct(S.shape, S.dtype),
                    jax.ShapeDtypeStruct((B, K, Gp, d_v), jnp.float32)],
-        input_output_aliases={4: 0},
+        input_output_aliases={6: 0},
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel"),
+            # In order: the steps past the last row visited stay on its
+            # last block.
+            dimension_semantics=("arbitrary", "arbitrary"),
             # One head's S in and out, each double-buffered.
             vmem_limit_bytes=int(4.5 * R * d_v * d * 4) + (8 << 20)),
         name="hvd_tpu_retention_step",
         interpret=interpret,
-    )(g_rows, v_cols, phi_k, pq, S)
+    )(*_visits(valid, B), g_rows, v_cols, phi_k, pq, S)
     return S_new, num[:, :, :G]
 
 
@@ -282,7 +328,8 @@ def retention_step(q, k, v, log_g, state: tuple, valid=None, *,
     """Decode: one token a row, one read-modify-write of the state.
     ``q [B, H, d]``, ``k, v [B, K, d]``, ``log_g [B, K]``, ``state`` the
     ``(S, z)`` of :func:`state_shapes`; ``valid [B]`` marks rows that
-    hold a request (the others leave their state as it is).  On the TPU
+    hold a request (the others leave their state as it is, and their
+    output row is zero).  On the TPU
     the state goes through the Pallas kernel, elsewhere through the same
     arithmetic in ``jax.numpy``; ``interpret`` is the tree-wide escape
     hatch of a kernel (True: the kernel under the interpreter, which is
@@ -292,7 +339,8 @@ def retention_step(q, k, v, log_g, state: tuple, valid=None, *,
     if interpret is None and jax.default_backend() != "tpu":
         update = _step_plain
     else:
-        update = functools.partial(_step_pallas, interpret=bool(interpret))
+        update = functools.partial(_step_pallas, valid=valid,
+                                   interpret=bool(interpret))
     return _step(update, q, k, v, log_g, state, valid)
 
 
@@ -307,6 +355,9 @@ def _step(update, q, k, v, log_g, state, valid):
         phi_k = jnp.where(valid[:, None, None, None], phi_k, 0.0)
     phi_q = expand(q.reshape(B, K, H // K, d).astype(jnp.float32), 1.0 / d)
     S, num = update(g, v.astype(jnp.float32), phi_k, phi_q, S)
+    if valid is not None:
+        # The kernel wrote no read-out for a row it did not visit.
+        num = jnp.where(valid[:, None, None, None], num, 0.0)
     z = g[..., None, None] * z + phi_k
     # z is small: its read-out is exact float32 at no cost worth counting.
     den = jnp.einsum("bkgri,bkri->bkg", phi_q, z,

@@ -56,8 +56,9 @@ or a fixed-size retention **state**:
   init_state_cache``).  A prefill takes the slot's state in and gives
   it back — zeros when it starts at position 0, the carried state when
   it continues (a resume's later chunks) — and a bucket's padding never
-  enters it; decode updates every slot's state in place, in one
-  program, rows without a request left as they are.  There are no
+  enters it; decode updates the state in place, in one program
+  whatever the occupancy, and reads and writes only the rows that
+  hold a request.  There are no
   blocks, no table and no prefix to share; preemption keeps nothing and
   a resume recomputes.  ``max_seq_len`` is then the most positions a
   request may reach, and costs no memory.
@@ -422,6 +423,8 @@ class InferenceEngine:
         self._states = None
         self._ring = None
         self.state_resets = 0
+        # Rows the decode steps over the state visited, summed.
+        self.state_rows_visited = 0
         if self.kv_mode == "state":
             self.kv_block = 0
             self.kv_blocks = 0
@@ -1284,6 +1287,12 @@ class InferenceEngine:
             self._slots_sent = changed
             self.step_state_uploads += bool(uploads)
         if self.kv_mode == "state":
+            # The step's kernel reads and rewrites the state of the
+            # rows that hold a request and of no other
+            # (ops/retention.py): as many as ``valid`` marks, never
+            # none here.
+            span_args["rows"] = len(active)
+            self.state_rows_visited += len(active)
             self._states, advanced = self._dispatch_decode(
                 self._params, self._states, self._step_state)
         elif self.kv_mode == "paged":
@@ -1907,10 +1916,14 @@ class InferenceEngine:
         one) and ``paged_view_blocks`` (rows x table columns, what the
         view would have read); nothing for dense rows; for a retention
         state ``state_bytes`` (all slots and layers, whatever the context),
-        ``state_slots_touched`` (slots whose state one decode step
-        reads and writes: all of them, rows without a request ride
-        along) and ``state_resets`` (prefills that began a slot's state
-        from zeros)."""
+        ``state_slots_touched`` (slots whose state a decode step read
+        and wrote, the mean over the engine's decode steps: those that
+        held a request — the step's kernel copies no other row's
+        state in or out; 0.0 before the first step),
+        ``state_slots_skipped`` (the slots a step left alone,
+        the same mean: ``max_slots`` less the former) and
+        ``state_resets`` (prefills that began a slot's state from
+        zeros)."""
         out: Dict = {"decode_steps": self.decode_steps,
                      "sampling_steps": self.sampling_steps,
                      "step_state_uploads": self.step_state_uploads,
@@ -1963,7 +1976,12 @@ class InferenceEngine:
         if self._states is not None:
             out["state_bytes"] = int(sum(
                 x.nbytes for x in jax.tree.leaves(self._states)))
-            out["state_slots_touched"] = self.max_slots
+            # Every decode step of a state engine is a plain one.
+            steps = self.decode_steps
+            touched = self.state_rows_visited / steps if steps else 0.0
+            out["state_slots_touched"] = touched
+            out["state_slots_skipped"] = (
+                self.max_slots - touched if steps else 0.0)
             out["state_resets"] = self.state_resets
         if self._drafter is not None:
             steps = self.spec_verify_steps

@@ -8,6 +8,7 @@ benchmark holds the same comparison at the published widths on the
 chip.
 """
 
+import functools
 import os
 import sys
 
@@ -132,38 +133,68 @@ def test_recurrent_equals_chunked_and_quadratic(monkeypatch):
     np.testing.assert_allclose(got, want, atol=1e-4)
 
 
-def test_the_step_kernel_equals_the_plain_step():
+@pytest.mark.parametrize("valid", [
+    pytest.param([False] * 4, id="none"),
+    pytest.param([True, False, False, False], id="first_only"),
+    pytest.param([False, False, False, True], id="last_only"),
+    pytest.param([False, True, False, True], id="alternating"),
+    pytest.param([True] * 4, id="all"),
+    pytest.param(None, id="no_mask"),
+])
+def test_the_step_kernel_equals_the_plain_step(valid):
     """The Pallas kernel (interpreted here; compiled for the chip in
     ``test_tpu_compile.py``) against the same step in ``jax.numpy``:
-    the state to float32's precision, rows without a request left as
-    they were; the read-out equal to the contraction of the same
+    the state to float32's precision; a row without a request is not
+    visited — its state comes back bit for bit, its read-out is zero
+    and not whatever lay in memory nobody wrote — whichever rows those
+    are, and with no row valid the one row the grid still visits is the
+    identity; the read-out equal to the contraction of the same
     operands rounded to bfloat16, which is what the kernel's MXU
     contraction takes (and XLA's default precision on the chip)."""
-    q, k, v, log_g = _operands(T=6)
-    s_shape, z_shape = retention.state_shapes(2, 2, 16)
-    plain = kern = (jnp.zeros(s_shape), jnp.zeros(z_shape))
-    valid = jnp.asarray([True, False])
+    B = 4
+    q, k, v, log_g = _operands(T=6, B=B)
+    s_shape, z_shape = retention.state_shapes(B, 2, 16)
+    # Every row begins with a state, so that a row left alone shows.
+    began = tuple(jax.random.normal(key, shape) for key, shape in zip(
+        jax.random.split(jax.random.PRNGKey(1)), (s_shape, z_shape)))
+    plain = kern = began
+    mask = None if valid is None else jnp.asarray(valid)
+    step = jax.jit(functools.partial(retention.retention_step,
+                                     interpret=True))
     for t in range(6):
         args = (q[:, t], k[:, t], v[:, t], log_g[:, t])
-        _, plain = retention.retention_step(*args, plain, valid)
-        _, kern = retention.retention_step(*args, kern, valid,
-                                           interpret=True)
+        _, plain = retention.retention_step(*args, plain, mask)
+        out, kern = step(*args, kern, mask)
         np.testing.assert_allclose(kern[0], plain[0], atol=1e-6)
         np.testing.assert_allclose(kern[1], plain[1], atol=1e-6)
-    assert float(jnp.max(jnp.abs(kern[0][0]))) > 0.1
-    assert float(jnp.max(jnp.abs(kern[0][1]))) == 0.0    # row 1 held nothing
+        assert bool(jnp.isfinite(out).all())
+    # One program whatever the rows: ``valid`` is a traced value.
+    assert step._cache_size() == 1
+    visited = [True] * B if valid is None else valid
+    for row, seen in enumerate(visited):
+        if seen:
+            assert float(jnp.max(jnp.abs(kern[0][row] - began[0][row]))) > 0.1
+        else:
+            np.testing.assert_array_equal(kern[0][row], began[0][row])
+            np.testing.assert_array_equal(kern[1][row], began[1][row])
+            np.testing.assert_array_equal(out[row], 0.0)
     # The read-out, on a state that holds six tokens.
     g = jnp.exp(log_g[:, 5])
     phi_k = retention.expand(k[:, 5])
-    phi_q = retention.expand(q[:, 5].reshape(2, 2, 2, 16), 1.0 / 16)
+    phi_q = retention.expand(q[:, 5].reshape(B, 2, 2, 16), 1.0 / 16)
     S_new, num = retention._step_pallas(g, v[:, 5], phi_k, phi_q, plain[0],
-                                        interpret=True)
+                                        mask, interpret=True)
     want_S = (g[..., None, None, None] * plain[0]
               + v[:, 5][:, :, None, :, None] * phi_k[:, :, :, None, :])
-    np.testing.assert_allclose(S_new, want_S, atol=1e-6)
     rounded = lambda x: x.astype(jnp.bfloat16).astype(jnp.float32)  # noqa: E731
     want = jnp.einsum("bkgri,bkrvi->bkgv", rounded(phi_q), rounded(want_S))
-    np.testing.assert_allclose(num, want, rtol=1e-4, atol=1e-5)
+    if valid is not None and not any(valid):
+        visited = [True] + [False] * (B - 1)     # the one row it visits
+    rows = np.flatnonzero(visited)
+    np.testing.assert_allclose(S_new[rows], want_S[rows], atol=1e-6)
+    np.testing.assert_allclose(num[rows], want[rows], rtol=1e-4, atol=1e-5)
+    idle = np.flatnonzero(~np.asarray(visited))
+    np.testing.assert_array_equal(S_new[idle], plain[0][idle])
 
 
 def test_chunked_carries_a_state_and_skips_what_is_not_valid(monkeypatch):
@@ -330,6 +361,44 @@ def test_a_released_slot_reused_equals_a_fresh_engine(model, params):
     assert engine.kv_stats()["state_resets"] == 2
 
 
+def test_the_engine_counts_the_rows_a_step_visited(model, params,
+                                                   monkeypatch):
+    """Two of four slots busy for three steps, then one released and
+    five steps more, through the step's kernel (interpreted): the
+    tokens are the plain step's, ``state_slots_touched`` is the mean
+    number of rows a step's kernel visited — the rows that held a
+    request, never the slots there are — ``state_slots_skipped`` the
+    rest of the slots, every decode span names its rows, and the
+    occupancy built no second decode program."""
+    from horovod_tpu.obs import trace
+
+    a, b = _tokens(9), _tokens(12, 1)
+    want_a = _greedy(_engine(model, params, max_slots=4), 0, a, 8)
+    step = retention.retention_step
+    monkeypatch.setattr(retention, "retention_step",
+                        lambda *args: step(*args, interpret=True))
+    engine = _engine(model, params, max_slots=4)
+    assert engine.kv_stats()["state_slots_touched"] == 0.0
+    assert engine.kv_stats()["state_slots_skipped"] == 0.0
+    out_a = [engine.start(0, a, SamplingParams(max_new_tokens=99))]
+    engine.start(2, b, SamplingParams(max_new_tokens=99))
+    for _ in range(3):
+        out_a.append(engine.step()[0][0])
+    engine.release(2)
+    for _ in range(5):
+        out_a.append(engine.step()[0][0])
+    assert out_a == want_a
+    stats = engine.kv_stats()
+    assert stats["decode_steps"] == 8
+    assert stats["state_slots_touched"] == (3 * 2 + 5 * 1) / 8
+    assert stats["state_slots_skipped"] == 4 - (3 * 2 + 5 * 1) / 8
+    spans = [s for s in trace.snapshot()
+             if s["name"] == "hvd_tpu_engine_decode"][-8:]
+    assert [s["args"]["rows"] for s in spans] == [2, 2, 2, 1, 1, 1, 1, 1]
+    assert engine.trace_counts["decode"] == 1
+    assert engine._decode_fn._cache_size() == 1
+
+
 def test_each_program_is_built_once(model, params):
     """Every key a program is given is the engine's own kind (the
     first, each split of it, and the one a resume restores); a key of
@@ -409,7 +478,8 @@ def test_the_default_engine_of_a_retention_model_holds_a_state(model,
         "stalled_fence": 0, "stalled_prefills": 0, "dispatch_ms_p50": None,
         "dispatch_ms_p99": None, "fence_ms_p50": None, "fence_ms_p99": None,
         "state_bytes": 8 * L * K * (d // 2 + 1) * d * (d + 1) * 4,
-        "state_slots_touched": 8, "state_resets": 0}
+        "state_slots_touched": 0.0, "state_slots_skipped": 0.0,
+        "state_resets": 0}
     assert engine.prefix_probe(_tokens(5)) == 0
     assert engine.drain_evicted_prefixes() == []
     # max_seq_len is the most positions a request may reach, not a table.

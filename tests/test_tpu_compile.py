@@ -369,7 +369,9 @@ def test_paged_decode_holds_no_pool_copy_on_v5e(topo, monkeypatch):
 def test_retention_step_kernel_compiles_for_v5e(topo):
     """The decode step of power retention at the published widths of
     the benchmark's ``brumby-14b``: 8 slots, 40 query / 8 KV heads of
-    128, one layer's float32 state (272 MB) updated in place."""
+    128, one layer's float32 state (272 MB) updated in place, the rows
+    to visit a traced value (``valid``: one program whatever the
+    occupancy)."""
     from horovod_tpu.ops import retention
 
     one = SingleDeviceSharding(topo.devices[0])
@@ -379,14 +381,19 @@ def test_retention_step_kernel_compiles_for_v5e(topo):
 
     s_shape, z_shape = retention.state_shapes(8, 8, 128)
     compiled = jax.jit(
-        lambda q, k, v, g, S, z: retention.retention_step(
-            q, k, v, g, (S, z), interpret=False),
+        lambda q, k, v, g, S, z, valid: retention.retention_step(
+            q, k, v, g, (S, z), valid, interpret=False),
         donate_argnums=(4, 5)).lower(
             sds((8, 40, 128), jnp.bfloat16), sds((8, 8, 128), jnp.bfloat16),
             sds((8, 8, 128), jnp.bfloat16), sds((8, 8)), sds(s_shape),
-            sds(z_shape)).compile()
+            sds(z_shape), sds((8,), jnp.bool_)).compile()
     text = compiled.as_text()
-    assert "tpu_custom_call" in text and "hvd_tpu_retention_step" in text
+    calls = [line for line in text.splitlines()
+             if "custom_call_target=\"tpu_custom_call\"" in line]
+    assert len(calls) == 1 and "hvd_tpu_retention_step" in calls[0]
+    # The state is the kernel's seventh operand, behind the rows to
+    # visit and their number, and its first result.
+    assert "output_to_operand_aliasing={{0}: (6, {})}" in calls[0]
     # In place: no second copy of the state, no temporary of its size.
     memory = compiled.memory_analysis()
     state_bytes = 4 * (np.prod(s_shape) + np.prod(z_shape))
@@ -426,7 +433,15 @@ def _state_decode_program(topo, monkeypatch):
     compiled = jax.jit(eng._decode_state_impl, donate_argnums=(1,)).lower(
         _described(topo, params), _described(topo, states),
         _described(topo, slots)).compile()
-    assert compiled.as_text().count("hvd_tpu_retention_step") >= 2
+    # One kernel a layer, each with its layer's state (the operand
+    # behind the rows to visit and their number, taken from the step
+    # state's traced ``active``) aliased to its first result.
+    calls = [line for line in compiled.as_text().splitlines()
+             if "custom_call_target=\"tpu_custom_call\"" in line]
+    assert len(calls) == 2, calls
+    for call in calls:
+        assert "hvd_tpu_retention_step" in call
+        assert "output_to_operand_aliasing={{0}: (6, {})}" in call
     return compiled, states
 
 
