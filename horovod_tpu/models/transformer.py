@@ -108,6 +108,18 @@ class GPTConfig:
     expert_scale: float = 1.0
     expert_held: Optional[Tuple[int, int]] = None
     expert_mlp: str = "relu2"          # an expert: 'relu2' (squared ReLU, not gated) | 'swiglu' (gated SiLU)
+    expert_scoring: str = "sigmoid"    # the router's scores: 'sigmoid' of each logit | 'softmax' over all experts
+    # A model that generates by diffusion over blocks: attention is
+    # causal over blocks of ``block_length`` positions and full inside
+    # one (a query at ``i`` sees the key at ``j`` iff ``j //
+    # block_length <= i // block_length``; 0 = causal by position), in
+    # the no-cache forward, a prefill chunk and a decode step alike; a
+    # position still to be generated holds ``mask_token``, and the
+    # logit at a position predicts that position's token.  The serving
+    # engine decodes such a model a block a row (docs/serving.md "A
+    # model that generates by blocks").
+    block_length: int = 0
+    mask_token: int = 0
     # The kinds of layer (of ``layers``) that are recomputed in the
     # backward pass instead of keeping their activations (a
     # configuration states them where a step would not fit).
@@ -151,6 +163,10 @@ class GPTConfig:
         kinds = self._per_layer("attn", ATTENTIONS)
         if "window" in kinds and self.window < 1:
             raise ValueError("a 'window' attention layer needs window >= 1")
+        if "window" in kinds and self.block_length:
+            raise ValueError(
+                "a block mask (block_length) over 'window' layers is not "
+                "built: a window counts positions, a block mask blocks")
         return kinds
 
     @property
@@ -413,6 +429,14 @@ class Attention(nn.Module):
         # another width than the keys.  Those layers have one
         # arithmetic, ``ops/paged_attention.py::view_attention``.
         special = bool(window) or sink is not None or Dv != D
+        # A block mask (``block_length``) is that arithmetic's too.
+        blocks = cfg.block_length
+        if blocks and (cfg.attention != "full" or not cfg.causal):
+            raise ValueError(
+                f"a block mask (block_length={blocks}) is causal "
+                f"attention='full', not {cfg.attention!r}: the flash, ring "
+                f"and Ulysses paths mask by position")
+        special = special or bool(blocks)
         proj = _dense(cfg, cfg.d_model, "out")
 
         if cache is not None:
@@ -454,8 +478,17 @@ class Attention(nn.Module):
             # its rows (``"fresh"``: nothing of them is in the cache
             # yet, which is all a ring is asked for), the chunk's own
             # keys and values.
+            # A model that generates by blocks brings a block a row to
+            # a decode step (the cache says so: ``"block_step"``): its
+            # ``block_length`` queries see one range, everything up to
+            # their block's end, so once the block's K/V is written
+            # they ride the same kernel as ``block_length x H`` query
+            # heads over the ``K`` KV heads, the row's length at the
+            # block's end (``ops/paged_attention.py::fold_block``).
             paged = "k_pool" in cache
-            fresh = paged and cache.get("fresh", False) and T > 1
+            block_step = paged and cache.get("block_step", False)
+            fresh = (paged and cache.get("fresh", False) and T > 1
+                     and not block_step)
             if paged:
                 table = cache["table"]           # [B, n_cols] block ids
                 k_pool, v_pool = cache["k_pool"], cache["v_pool"]
@@ -490,22 +523,28 @@ class Attention(nn.Module):
                     k.astype(cache["k"].dtype))
                 v_new = cache["v"].at[at, positions].set(
                     v.astype(cache["v"].dtype))
-            if paged and T == 1 and cfg.tp_mesh is None:
+            if paged and (T == 1 or block_step) and cfg.tp_mesh is None:
                 # The scope holds the attention alone; the projections
                 # are the block's, outside it.  A model of one kind of
                 # layer keeps the one name; one of two says which.
                 scope = "hvd_tpu_paged_attention" + (
                     "" if len(set(cfg.attn_kinds)) == 1 else "_" + self.kind)
                 with jax.named_scope(scope):
+                    # One token a row goes in as it always did; only a
+                    # block step folds its queries.
                     out = paged_attention.paged_decode(
-                        q[:, 0], k_new, v_new, table, positions[:, 0], K,
-                        **(dict(v_head_dim=Dv, window=window, sink=sink)
-                           if special else {}))[:, None]
+                        paged_attention.fold_block(q, K) if block_step
+                        else q[:, 0], k_new, v_new, table, positions[:, -1],
+                        K, **(dict(v_head_dim=Dv, window=window, sink=sink)
+                              if window or sink is not None or Dv != D
+                              else {}))
+                    out = (paged_attention.unfold_block(out, T, K)
+                           if block_step else out[:, None])
             elif fresh:
                 out = paged_attention.view_attention(
                     q, k.astype(k_pool.dtype), v.astype(v_pool.dtype),
                     positions, key_positions=positions, window=window,
-                    sink=sink)
+                    sink=sink, block=blocks)
             else:
                 if paged and window:
                     raise NotImplementedError(
@@ -516,7 +555,8 @@ class Attention(nn.Module):
                     x, table, K, d) for x, d in ((k_new, D), (v_new, Dv)))
                     if paged else (k_new, v_new))
                 out = paged_attention.view_attention(
-                    q, k_all, v_all, positions, window=window, sink=sink)
+                    q, k_all, v_all, positions, window=window, sink=sink,
+                    block=blocks)
             # Gather-before-contract: the ``out`` kernel is replicated
             # under TP, so the head outputs all-gather here and every
             # shard computes the full projection — bitwise identical.
@@ -530,7 +570,7 @@ class Attention(nn.Module):
                     f"{cfg.attention!r}")
             at = jnp.broadcast_to(jnp.arange(T, dtype=jnp.int32), (B, T))
             out = paged_attention.view_attention(q, k, v, at, window=window,
-                                                 sink=sink)
+                                                 sink=sink, block=blocks)
             return proj(_tp_shard(cfg, out.reshape(B, T, C)))
         if K != H:
             # Grouped KV heads: each is read by H / K query heads.
@@ -749,6 +789,7 @@ def _experts(cfg: GPTConfig):
         n_experts=cfg.expert_count, top_k=cfg.expert_top_k,
         shared_d_ff=cfg.expert_shared_d_ff, scale=cfg.expert_scale,
         held=cfg.expert_held, gated=cfg.expert_mlp == "swiglu",
+        scoring=cfg.expert_scoring,
         dtype=cfg.dtype, param_dtype=cfg.param_dtype, name="experts")
 
 
@@ -877,10 +918,19 @@ class GPT(nn.Module):
         if return_hidden:
             # Pre-head activations for the chunked-vocab loss
             # (ops/xent.py) — the lm_head matmul happens inside the
-            # chunk loop there instead of materializing [B, T, V] here.
-            return x
-        logits = nn.Dense(cfg.vocab_size, use_bias=False, dtype=jnp.float32,
-                          param_dtype=cfg.param_dtype, name="lm_head")(x)
+            # chunk loop there instead of materializing [B, T, V] here
+            # — and, with ``kv_caches``, for a prefill that samples no
+            # token and so needs no logits.
+            return x if kv_caches is None else (x, new_caches)
+        head = nn.Dense(cfg.vocab_size, use_bias=False, dtype=jnp.float32,
+                        param_dtype=cfg.param_dtype, name="lm_head")
+        if cfg.block_length and kv_caches is not None:
+            # A block step's head belongs to what turns a block's
+            # logits into its next state (serve/engine.py::_transfer).
+            with jax.named_scope("hvd_tpu_block_transfer"):
+                logits = head(x)
+        else:
+            logits = head(x)
         if kv_caches is not None:
             return logits, new_caches
         return logits

@@ -46,6 +46,17 @@ Grouped KV heads (``K < H``) are covered by the same matrix: the
 bfloat16 inputs, float32 scores, softmax and sums, probabilities
 rounded to the pool's dtype before the product with V.
 
+**A block of queries a row.**  A model that generates by blocks
+(``GPTConfig.block_length``) brings ``B`` queries a row to a decode
+step, and all of them see one range: everything up to their block's
+end.  :func:`fold_block` lays them out as ``B x H`` query heads over
+the ``K`` KV heads — a grouped-query step with a group ``B`` times as
+large — and the same kernel serves them with the row's length at the
+block's end; :func:`unfold_block` takes the result apart again.
+:func:`view_attention` takes the block mask as ``block`` (a query at
+``i`` sees the key at ``j`` iff ``j // block <= i // block``) for the
+no-cache forward and prefill chunks.
+
 Off the TPU :func:`paged_decode` runs the view's arithmetic in
 ``jax.numpy``.  ``interpret`` is the tree-wide escape hatch of a kernel
 (True: the kernel under the interpreter, which is how the tests reach
@@ -85,14 +96,17 @@ _WAVE_BYTES = 1 << 20
 
 
 def view_attention(q, k_all, v_all, positions, *, key_positions=None,
-                   window: int = 0, sink=None):
+                   window: int = 0, sink=None, block: int = 0):
     """Attention of ``q [B, T, H, D_k]`` at absolute ``positions [B,
     T]`` over per-row keys ``[B, S, K, D_k]`` and values ``[B, S, K,
     D_v]`` (dense cache rows, a paged cache's gathered view, or the
     chunk's own): a query sees the keys at ``key_positions [B, S]``
     (None: key ``i`` is position ``i``) that are not after it, not
     negative and, with ``window``, fewer than ``window`` positions
-    back.  ``sink [H]`` (float32) joins each head's denominator.
+    back.  With ``block`` the mask is causal over blocks of ``block``
+    positions and full inside one: a query at ``i`` sees the key at
+    ``j`` iff ``j // block <= i // block``.  ``sink [H]`` (float32)
+    joins each head's denominator.
     Scores rounded to ``q``'s dtype by the product, softmax in float32,
     probabilities rounded to ``q``'s dtype.  Returns ``[B, T, H,
     D_v]``."""
@@ -107,6 +121,9 @@ def view_attention(q, k_all, v_all, positions, *, key_positions=None,
     if key_positions is None:
         key_positions = jnp.arange(S, dtype=positions.dtype)[None]
     back = positions[:, :, None] - key_positions[:, None, :]
+    if block:
+        back = (positions[:, :, None] // block
+                - key_positions[:, None, :] // block)
     visible = (back >= 0) & (key_positions[:, None, :] >= 0)
     if window:
         visible &= back < window
@@ -162,6 +179,26 @@ def _decode_view(q, k_pool, v_pool, table, positions, kv_heads,
         q[:, None], gathered_view(k_pool, table, kv_heads, D),
         gathered_view(v_pool, table, kv_heads, Dv), positions[:, None],
         key_positions=key_positions, window=window, sink=sink)[:, 0]
+
+
+def fold_block(q, kv_heads: int):
+    """A block's queries as query heads of one decode row: ``q [B, T,
+    H, D]`` to ``[B, T * H, D]``, the ``T * H / K`` heads that read KV
+    head ``k`` side by side (head ``k * T * G + t * G + g`` is position
+    ``t``'s head ``k * G + g``), so that the ``T`` queries of a row,
+    which all see the same range of keys, ride :func:`paged_decode` as
+    a grouped-query step with a group ``T`` times as large."""
+    B, T, H, D = q.shape
+    return q.reshape(B, T, kv_heads, H // kv_heads, D).swapaxes(
+        1, 2).reshape(B, T * H, D)
+
+
+def unfold_block(out, T: int, kv_heads: int):
+    """:func:`fold_block`'s inverse on the attention's result:
+    ``[B, T * H, D_v]`` to ``[B, T, H, D_v]``."""
+    B, heads, Dv = out.shape
+    return out.reshape(B, kv_heads, T, heads // (T * kv_heads), Dv).swapaxes(
+        1, 2).reshape(B, T, heads // T, Dv)
 
 
 def _sublane_tile(dtype) -> int:

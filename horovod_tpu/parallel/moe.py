@@ -16,7 +16,8 @@ dispatch fraction × E²) is sown under ``intermediates/moe_aux_loss``;
 
 Beside it, :class:`DroplessExperts`: the expert layer of the sigmoid-
 routed families (a selection bias, top-k normalised and scaled, a
-shared expert) as ONE chip of an expert-parallel job runs it.  The
+shared expert) and of those that take a softmax over all experts
+before the top-k, as ONE chip of an expert-parallel job runs it.  The
 router scores every expert; the token-expert pairs are sorted by
 expert; the pairs of experts this chip does not hold are discarded
 before any expert arithmetic; a grouped matrix product (the Pallas
@@ -175,7 +176,7 @@ def moe_aux_loss(intermediates, weight: float = 1e-2) -> jnp.ndarray:
     return weight * total / n
 
 
-# --- dropless, sigmoid-routed experts ----------------------------------------
+# --- dropless experts, sigmoid- or softmax-routed ----------------------------
 
 @jax.custom_vjp
 def _take(x, idx, inv):
@@ -211,9 +212,10 @@ _put.defvjp(lambda rows, idx, inv: (_put(rows, idx, inv), (idx, inv)),
 def route(scores, bias, top_k: int, scale: float):
     """Which experts a token goes to, and with what weight: the
     ``top_k`` largest of ``scores + bias`` (``scores [S, E]``: the
-    router's sigmoids; ``bias [E]`` only selects and carries no
-    gradient), weighted by their scores over the sum of the chosen,
-    times ``scale``.  Returns ``(experts [S, K] int32, weights [S, K])``."""
+    router's scores, each logit's sigmoid or the softmax over all
+    experts, as the layer's ``scoring`` says; ``bias [E]`` only selects
+    and carries no gradient), weighted by their scores over the sum of
+    the chosen, times ``scale``.  Returns ``(experts [S, K] int32, weights [S, K])``."""
     _, experts = jax.lax.top_k(scores + jax.lax.stop_gradient(bias), top_k)
     chosen = jnp.take_along_axis(scores, experts, axis=-1)
     weights = chosen / (chosen.sum(axis=-1, keepdims=True) + 1e-20) * scale
@@ -252,6 +254,13 @@ HEADROOM = 4
 # are full, as here), 19.4 at (128, 128, 128), and 11.5 through
 # ``jax.lax.ragged_dot``.
 GMM_TILES = (512, 1024, 1024)
+# Where the groups are many and hold few rows each — fewer than
+# SPARSE_ROWS a group: 128 experts all held under a block step's 1,536
+# pairs, twelve a group — the row tile is SPARSE_TILE.  A tile visits
+# every group it touches with all of its rows, so the products do about
+# ``rows + groups x tile`` rows' worth of work, and at 512 that is
+# forty times the pairs' own (PERF.md, PR 44, has the reading).
+SPARSE_ROWS, SPARSE_TILE = 32, 128
 
 
 def grouped_dot(rows, kernels, sizes, dtype, interpret: bool):
@@ -260,7 +269,8 @@ def grouped_dot(rows, kernels, sizes, dtype, interpret: bool):
     left as they fall.  ``rows [M, K]``, ``kernels [G, K, N]``."""
     from jax.experimental.pallas.ops.tpu.megablox import gmm
 
-    tm = math.gcd(rows.shape[0], GMM_TILES[0])
+    sparse = rows.shape[0] < SPARSE_ROWS * kernels.shape[0]
+    tm = math.gcd(rows.shape[0], SPARSE_TILE if sparse else GMM_TILES[0])
     if tm < 8 and not interpret:
         raise ValueError(f"{rows.shape[0]} rows (tokens x top_k) do not "
                          f"divide into tiles of 8 or more")
@@ -280,6 +290,10 @@ def usual_rows(pairs: int, count: int, n_experts: int) -> int:
 class DroplessExperts(nn.Module):
     """``[B, T, C] -> [B, T, C]``: ``sum_e w_e expert_e(x)`` over the
     held ones of a token's ``top_k`` experts, plus the shared expert.
+    The router is float32: logits over all ``n_experts``, scored by
+    ``scoring`` — ``'sigmoid'`` (each logit's own) or ``'softmax'``
+    (over all experts, before the top-k) — and the chosen scores
+    renormalised over their sum (:func:`route`).
     An expert is ``down(relu(up(x)) ** 2)`` (squared ReLU, not gated)
     or, ``gated``, ``down(silu(gate(x)) * up(x))``; the shared expert
     is of the same form.  ``held = (offset, count)``
@@ -307,6 +321,7 @@ class DroplessExperts(nn.Module):
     scale: float = 1.0
     held: Optional[Tuple[int, int]] = None
     gated: bool = False
+    scoring: str = "sigmoid"
     dtype: Any = jnp.bfloat16
     param_dtype: Any = jnp.float32
     interpret: Optional[bool] = None   # ops/pallas_common.resolve_interpret
@@ -338,8 +353,11 @@ class DroplessExperts(nn.Module):
                               precision=jax.lax.Precision.HIGHEST,
                               kernel_init=init, name="router")(
                 xf.astype(jnp.float32))
-            experts, weights = route(jax.nn.sigmoid(logits), bias, K,
-                                     self.scale)
+            if self.scoring not in ("sigmoid", "softmax"):
+                raise ValueError(f"Unknown scoring {self.scoring!r}")
+            scores = (jax.nn.sigmoid(logits) if self.scoring == "sigmoid"
+                      else jax.nn.softmax(logits, axis=-1))
+            experts, weights = route(scores, bias, K, self.scale)
             order, place, sizes = held_pairs(experts, (offset, count))
             # A counter for who asks (``mutable=["intermediates"]``):
             # the pairs each held expert was sent.

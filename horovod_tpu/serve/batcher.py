@@ -94,13 +94,19 @@ class ServeRequest:
     # submitted_at <= admitted_at (popped from the queue into a slot)
     # <= first_token_at == token_times[0] <= ... <= finished_at.  Queue
     # wait is admitted_at - submitted_at; token_times holds one stamp
-    # per emitted token (len(token_times) == len(tokens)).
+    # per emitted token (len(token_times) == len(tokens)).  A model
+    # that generates by blocks delivers a block's tokens together, when
+    # the block's last mask fell: they share a stamp, the first token
+    # is the first block's, and token_steps holds for each token the
+    # denoising step at which it was chosen (len(token_steps) ==
+    # len(tokens) there; empty for a model that decodes a token a step).
     submitted_at: float = 0.0
     admitted_at: Optional[float] = None
     first_token_at: Optional[float] = None
     finished_at: Optional[float] = None
     tokens: List[int] = dataclasses.field(default_factory=list)
     token_times: List[float] = dataclasses.field(default_factory=list)
+    token_steps: List[int] = dataclasses.field(default_factory=list)
     error: Optional[str] = None
     # Resident-prefix tokens (admission-time probe, refined to the
     # actual binding at prefill) — the cache-hit/miss signal the bench
@@ -393,6 +399,7 @@ class ContinuousBatcher:
         # PromptTooLongError / out-of-vocab ValueError early — a poison
         # prompt must never reach the shared KV pool (engine docstring).
         self.engine.check_prompt_tokens(prompt)
+        self.engine.check_sampling(sampling)
         # Admission-time prefix lookup: how much of this prompt's K/V
         # is already resident (serve/kv/).  Recorded before queueing so
         # backpressure decisions and the bench see the signal even for
@@ -606,13 +613,16 @@ class ContinuousBatcher:
         req.finish()
 
     def _emit(self, slot: int, req: ServeRequest, token: int,
-              now: float, check_full: bool = True) -> None:
+              now: float, check_full: bool = True,
+              step: Optional[int] = None) -> None:
         if req.done.is_set():
             return   # cancelled/expired concurrently: drop the token
         if req.first_token_at is None:
             req.first_token_at = now
         req.tokens.append(token)
         req.token_times.append(now)
+        if step is not None:    # the denoising step it was chosen at
+            req.token_steps.append(step)
         stop = req.sampling.stop_token
         # ``check_full`` is False for all but the last token of a
         # speculative burst: the engine advanced the slot position past
@@ -625,9 +635,10 @@ class ContinuousBatcher:
 
     def _prefill_into(self, slot: int, req: ServeRequest) -> int:
         """Bring ``req`` into ``slot`` — local prefill, migrated-KV
-        import, or preemption resume — and emit its first token(s);
-        returns the tokens emitted.  The caller already placed ``req``
-        in ``self._slots[slot]``."""
+        import, or preemption resume — and emit its first token(s),
+        none where the model generates by blocks and a prefill yields
+        nothing; returns the tokens emitted.  The caller already placed
+        ``req`` in ``self._slots[slot]``."""
         emitted = 0
         self._admitted += 1
         prefill_t0 = time.monotonic()
@@ -649,6 +660,7 @@ class ContinuousBatcher:
             req.resume_state = None
             req.tokens.clear()
             req.token_times.clear()
+            req.token_steps.clear()
             req.first_token_at = None
             resumed = False
         if self._lockstep is not None and not imported and not resumed:
@@ -692,8 +704,8 @@ class ContinuousBatcher:
                     slot, req.prompt, prev, req.sampling, rng=rng)
                 tokens = []
             else:
-                tokens = [self.engine.start(slot, req.prompt,
-                                            req.sampling)]
+                first = self.engine.start(slot, req.prompt, req.sampling)
+                tokens = [] if first is None else [first]
         except Exception as e:   # defensive: engine bug ≠ wedged slot
             with self._lock:
                 self._slots.pop(slot, None)
@@ -866,7 +878,9 @@ class ContinuousBatcher:
                 slot = free[0]
                 self._slots[slot] = req
             emitted += self._prefill_into(slot, req)
-        # Decode: one token for every active request.  The kill fault's
+        # Decode: one token for every active request — or, from a model
+        # that generates by blocks, none to a block's worth, as its
+        # block's last mask fell in this step or not.  The kill fault's
         # event coordinate is this dispatch — guarded so an unarmed
         # plan costs one attribute read.
         with self._lock:
@@ -891,11 +905,14 @@ class ContinuousBatcher:
                 # A speculative burst emits several tokens; a finish
                 # condition (stop token, max_new_tokens) mid-burst
                 # drops the remainder — exactly what plain greedy
-                # decode would never have produced.
+                # decode would never have produced.  A block is cut
+                # at ``max_new_tokens`` the same way.
+                steps = getattr(toks, "steps", None)
                 for j, token in enumerate(toks):
                     emitted += 1
                     self._emit(slot, req, token, now,
-                               check_full=(j == len(toks) - 1))
+                               check_full=(j == len(toks) - 1),
+                               step=None if steps is None else steps[j])
                     if req.done.is_set():
                         break
         with self._lock:

@@ -63,6 +63,19 @@ or a fixed-size retention **state**:
   a resume recomputes.  ``max_seq_len`` is then the most positions a
   request may reach, and costs no memory.
 
+**A model that generates by blocks** (``GPTConfig.block_length``:
+diffusion over blocks of ``B`` positions) rides the paged tier with a
+step state of its own: a block a row — its tokens, which of them are
+still masks, the step each was chosen at — in place of a token a row.
+Prefill writes the prompt's whole blocks and yields no token; ONE
+compiled *block step* forwards every active row's block (written into
+the pool, then read by the decode kernel as ``B x H`` query heads with
+the row's length at the block's end) and ``_transfer`` moves each row
+on: a denoising step that unmasks its most confident positions, or,
+for a block that came in all clean, the commit of its K/V and the
+opening of the next.  ``step()`` hands back none to ``B`` tokens a row
+(docs/serving.md "A model that generates by blocks").
+
 **Speculative decoding** (per-request opt-in via
 ``SamplingParams(spec=True)``; greedy requests only): a small drafter
 model proposes ``HVD_TPU_SERVE_SPEC_K`` tokens per step, the target
@@ -128,13 +141,26 @@ class SamplingParams:
     """Per-request sampling knobs (greedy when ``temperature == 0``).
     ``spec=True`` opts the request into speculative decoding (engines
     built with a drafter; greedy requests only — temperature rows in
-    the same batch keep plain single-token semantics)."""
+    the same batch keep plain single-token semantics).
+
+    A model that generates by blocks (``GPTConfig.block_length``) reads
+    three more, a request's own because they trade its quality against
+    its speed and rows of different schedules share a step: a block is
+    denoised in ``denoising_steps`` steps (0 = the block's length, a
+    position a step), and a step unmasks by ``transfer`` —
+    ``'static'``: the ``k_s = B // T`` (+1 in the first ``B mod T``
+    steps) masked positions of highest confidence; ``'dynamic'``: every
+    masked position whose confidence passes ``threshold`` when those
+    are at least ``k_s``, else the ``k_s`` highest."""
 
     max_new_tokens: int = 16
     temperature: float = 0.0
     top_k: int = 0                 # 0 = full vocab
     stop_token: Optional[int] = None
     spec: bool = False
+    denoising_steps: int = 0
+    transfer: str = "dynamic"
+    threshold: float = 0.9
 
 
 def _sample(logits, rng, temps, topks, rows=None):
@@ -185,6 +211,87 @@ def _advance(step, logits):
             "positions": step["positions"] + active.astype(jnp.int32)}
 
 
+def _transfer(step, logits, mask_token: int):
+    """``_advance`` for a model that generates by blocks (traced; the
+    tail of the block step): what one forward of every row's block
+    changes of the step state.  The state is a block a row: ``tokens
+    [S, B]``, ``masked [S, B]`` (a flag, never a comparison with the
+    mask's id), ``chosen [S, B]`` (the denoising step at which a
+    position was unmasked; -1: it came with the prompt), ``positions``
+    (the block's first), ``steps`` (denoising steps the block has had)
+    and the row's schedule (``denoise``, ``dynamic``, ``threshold``).
+
+    A row with masks left *transfers*: at every masked position the
+    greedy or sampled ``x0`` (the row's ``temps`` / ``topks``) and its
+    confidence ``p(x0)``, a float32 softmax over the vocabulary; at
+    step ``t`` of ``T`` the ``k = B // T + (t < B mod T)`` masked
+    positions of highest confidence take their ``x0`` (equal
+    confidences: the earlier position first) — or, under the dynamic
+    rule, every masked position over the threshold when those are at
+    least ``k``.  A row whose block came in all clean has just written
+    the K/V that the cache keeps: it advances ``B`` positions and opens
+    a block of masks.  Rows without a request keep theirs.
+
+    Returns the state's next arrays and ``report [S, 3 B + 3]`` int32
+    for the host — tokens, chosen, masked, steps, positions as they now
+    are, and whether the block's last mask fell in this step."""
+    logits = logits.astype(jnp.float32)
+    S, B, V = logits.shape
+    key, sub = jax.random.split(step["key"])
+    masked, active = step["masked"], step["active"]
+    denoising = active & masked.any(axis=-1)
+    commit = active & ~masked.any(axis=-1)
+    flat = logits.reshape(S * B, V)
+    x0 = _sample(flat, sub, jnp.repeat(step["temps"], B),
+                 jnp.repeat(step["topks"], B),
+                 rows=(denoising[:, None] & masked).reshape(-1))
+    conf = jnp.exp(jnp.take_along_axis(flat, x0[:, None], axis=-1)[:, 0]
+                   - jax.nn.logsumexp(flat, axis=-1))
+    x0 = x0.reshape(S, B)
+    conf = jnp.where(masked, conf.reshape(S, B), -1.0)
+    t, T = step["steps"], jnp.maximum(step["denoise"], 1)
+    k = B // T + (t < B % T)
+    over = conf > step["threshold"][:, None]
+    rank = jnp.argsort(jnp.argsort(-conf, axis=-1, stable=True), axis=-1)
+    dynamic = step["dynamic"] & (over.sum(axis=-1) >= k)
+    take = (jnp.where(dynamic[:, None], over, rank < k[:, None])
+            & masked & denoising[:, None])
+    tokens = jnp.where(take, x0, step["tokens"])
+    masked = masked & ~take
+    chosen = jnp.where(take, t[:, None], step["chosen"])
+    final = denoising & ~masked.any(axis=-1)
+    opened = commit[:, None]
+    out = {"key": key,
+           "tokens": jnp.where(opened, mask_token, tokens),
+           "masked": masked | opened,
+           "chosen": jnp.where(opened, -1, chosen),
+           "steps": jnp.where(commit, 0, t + denoising),
+           "positions": step["positions"] + B * commit}
+    report = jnp.concatenate(
+        [out["tokens"], out["chosen"], out["masked"].astype(jnp.int32),
+         out["steps"][:, None], out["positions"][:, None],
+         final.astype(jnp.int32)[:, None]], axis=1)
+    return dict(out, report=report)
+
+
+def _read_report(report, B: int) -> dict:
+    """A block step's report (``_transfer``; numpy) by its fields."""
+    return {"tokens": report[:, :B], "chosen": report[:, B:2 * B],
+            "masked": report[:, 2 * B:3 * B] != 0,
+            "steps": report[:, 3 * B], "positions": report[:, 3 * B + 1],
+            "final": report[:, 3 * B + 2] != 0}
+
+
+class FinalTokens(list):
+    """What a block step hands back for a slot: the tokens that became
+    final in it, in position order, and in ``steps`` the denoising step
+    (0 ... T - 1) at which each was chosen."""
+
+    def __init__(self, tokens=(), steps=()):
+        super().__init__(tokens)
+        self.steps = list(steps)
+
+
 # A phase of a decode step (``prepare``, ``dispatch``, ``fence``) or of
 # a prefill (``dispatch``, ``fence``) that took more than
 # ``_STALL_TIMES`` its usual length and ``_STALL_US`` microseconds over
@@ -229,7 +336,9 @@ class InferenceEngine:
     ``start(slot, prompt, sampling)`` prefixes a request into ``slot``
     and returns its first token; ``step()`` decodes for every active
     slot and returns ``{slot: [tokens]}`` — one token per slot on the
-    plain path, up to ``spec_k + 1`` under speculative decoding.
+    plain path, up to ``spec_k + 1`` under speculative decoding, and
+    from a model that generates by blocks none to a block's worth
+    (:class:`FinalTokens`; its ``start`` returns None).
     Each call is one span of ``obs/trace.py``
     (``hvd_tpu_engine_prefill`` / ``hvd_tpu_engine_decode``): in the
     span ring, on a live profiler's host plane, and mirrored onto the
@@ -283,6 +392,8 @@ class InferenceEngine:
                 f"servable yet: the engine holds one ring a slot")
         # The window layers' window, 0 where the model has none.
         self._window = windows[0] if windows else 0
+        # A model that generates by blocks: its block's length, else 0.
+        self._block = int(model.config.block_length)
         self.kv_mode = (kv_cache or ("state" if stateful
                                      else cfg.serve_kv)).lower()
         if self.kv_mode not in ("paged", "dense", "state"):
@@ -312,6 +423,22 @@ class InferenceEngine:
         self._tp_mesh = None
         if self.tp < 1:
             raise ValueError(f"tp must be >= 1, got {self.tp}")
+        if self._block:
+            if self.kv_mode != "paged":
+                raise ValueError(
+                    f"a model that generates by blocks is served from the "
+                    f"paged cache, not kv_cache={self.kv_mode!r}: a block "
+                    f"step reads each row's blocks through its table")
+            if self.tp > 1:
+                raise ValueError(
+                    "tensor-parallel serving of a model that generates by "
+                    "blocks is not built yet: the block step rides the "
+                    "decode kernel, which a head-sharded pool does not take")
+            if drafter is not None:
+                raise ValueError(
+                    "speculative decoding of a model that generates by "
+                    "blocks is not built: a block step already yields "
+                    "several tokens a row")
         if self.tp > 1:
             if self._window:
                 raise ValueError(
@@ -354,6 +481,11 @@ class InferenceEngine:
         self._last_tokens = np.zeros(self.max_slots, np.int32)  # guarded-by: _slot_lock
         self._spec = np.zeros(self.max_slots, bool)            # guarded-by: _slot_lock
         self._prefix_hits = np.zeros(self.max_slots, np.int32)  # guarded-by: _slot_lock
+        # A model that generates by blocks: the mirror of a block a row
+        # (``_transfer`` has the fields), and the rows whose block's
+        # last mask fell in the last step, which commit in the next.
+        self._blocks = self._empty_blocks() if self._block else None  # guarded-by: _slot_lock
+        self._block_final = np.zeros(self.max_slots, bool)     # guarded-by: _slot_lock
         # What a decode step reads of the slots lives on the device,
         # beside the weights, and the decode program advances it (the
         # token it sampled is the next input, the position the last
@@ -386,6 +518,14 @@ class InferenceEngine:
         self._slots_sent = 0
         self.decode_steps = 0
         self.sampling_steps = 0
+        # Block steps, and what their rows did: forwards of a block with
+        # masks left, forwards of one all clean (whose K/V the cache
+        # keeps), blocks so committed, tokens whose block became final.
+        self.block_steps = 0
+        self.denoise_forwards = 0
+        self.commit_forwards = 0
+        self.blocks_committed = 0
+        self.tokens_final = 0
         self.step_state_uploads = 0    # the constructor's is not counted
         self.staged_uploads = 0
         self.runtime_pokes = 0
@@ -441,6 +581,10 @@ class InferenceEngine:
             if self.kv_block < 1:
                 raise ValueError(f"kv_block must be >= 1, got "
                                  f"{self.kv_block}")
+            if self._block and self.kv_block % self._block:
+                raise ValueError(
+                    f"kv_block {self.kv_block} does not hold whole blocks "
+                    f"of the model's {self._block} positions")
             self.blocks_per_slot = -(-self.max_seq_len // self.kv_block)
             floor = 1 + self.max_slots * self.blocks_per_slot
             budget = int(kv_blocks if kv_blocks is not None
@@ -449,7 +593,7 @@ class InferenceEngine:
                 # Auto: every slot fully servable plus an equal share
                 # of prefix-cache headroom — none where window layers
                 # rule prefix sharing out.
-                budget = 1 + ((1 if self._window else 2)
+                budget = 1 + ((1 if self._window or self._block else 2)
                               * self.max_slots * self.blocks_per_slot)
             if budget < floor:
                 raise ValueError(
@@ -514,6 +658,7 @@ class InferenceEngine:
             self._table_sent = dict.fromkeys(self._tables)
             self._table_device = dict.fromkeys(self._tables)
             self.table_uploads = 0
+            self.table_uploads_ahead = 0   # of them, behind a block step
             self.paged_decode_steps = 0
             self.paged_live_blocks = 0
             self.paged_view_blocks = 0
@@ -546,15 +691,18 @@ class InferenceEngine:
                 self._copy_block, heads=kv_heads // self.tp,
                 tp_degree=self.tp,
                 bytes_per_block=_block_bytes(False),
-                index_prefixes=not self._window)
+                index_prefixes=not (self._window or self._block))
             self._ring = RingPool(
                 ring_budget, self._tables["window"],
                 _block_bytes(True)) if self._window else None
             self._caches = None
-            self._decode_fn = jax.jit(self._decode_paged_impl,
-                                      donate_argnums=self._donate)
-            self._prefill_fns = {L: self._make_paged_prefill(L)
-                                 for L in self.prefill_buckets}
+            self._decode_fn = jax.jit(
+                self._decode_block_impl if self._block
+                else self._decode_paged_impl, donate_argnums=self._donate)
+            self._prefill_fns = {
+                L: (self._make_block_prefill(L) if self._block
+                    else self._make_paged_prefill(L))
+                for L in self.prefill_buckets}
         else:
             self.kv_block = 0
             self.kv_blocks = 0
@@ -635,10 +783,15 @@ class InferenceEngine:
         """A slot snapshot as the step state's five arrays: copies
         nothing else holds, so a caller may write a row before it
         sends them."""
-        act, pos, temps, topks, last_tokens, _, _ = snap
-        return {"tokens": last_tokens,
-                "positions": np.where(act, pos, 0).astype(np.int32),
-                "active": act, "temps": temps, "topks": topks}
+        act, pos, temps, topks, last_tokens, _, _, blocks = snap
+        slots = {"positions": np.where(act, pos, 0).astype(np.int32),
+                 "active": act, "temps": temps, "topks": topks}
+        if blocks is None:
+            return dict(slots, tokens=last_tokens)
+        # A block a row in place of a token a row; which rows commit
+        # next the device knows from ``masked``.
+        return dict(slots, **{k: v for k, v in blocks.items()
+                              if k != "final"})
 
     def _upload_slots(self, slots: dict) -> int:
         """Into the device's step state those of ``slots`` that differ
@@ -657,17 +810,23 @@ class InferenceEngine:
         return len(send)
 
     def _stage_bind(self, slot: int, n_prompt: int,
-                    sampling: SamplingParams) -> None:
+                    sampling: SamplingParams,
+                    row: Optional[dict] = None) -> None:
         """Between a prefill's dispatch and its token fence: send what
         ``_bind_slot`` is about to change, but for the token still
         being sampled, and the block table the prefill wrote, while
         the device computes.  The first decode step then has one array
         left to send, not five and a table, with the device waiting.
         The next step compares again whatever happens until then (a
-        prefill that raises leaves the row to be sent back)."""
+        prefill that raises leaves the row to be sent back).  ``row``
+        (``_block_row``): the first block of a model that generates by
+        blocks, which awaits no token and is sent whole."""
         slots = self._slot_arrays(self._slot_snapshot())
-        del slots["tokens"]
-        slots["positions"][slot] = n_prompt
+        if row is None:
+            del slots["tokens"]
+            row = {"positions": n_prompt}
+        for field, value in row.items():
+            slots[field][slot] = value
         slots["active"][slot] = True
         slots["temps"][slot] = sampling.temperature
         slots["topks"][slot] = sampling.top_k
@@ -735,13 +894,17 @@ class InferenceEngine:
 
     # --- compiled programs: paged tier --------------------------------------
 
-    def _paged_caches(self, pools, tables, fresh: bool = False):
+    def _paged_caches(self, pools, tables, fresh: bool = False,
+                      block_step: bool = False):
         """The model's ``kv_caches`` over the pools: each layer with the
         table of its kind (``tables``: ``{kind: table}``), a ring with
         the position from which a row is invalid.  ``fresh``: the
-        chunk begins its rows, as every prefill over a ring does."""
+        chunk begins its rows, as every prefill over a ring does.
+        ``block_step``: the chunk is a block a row of a model that
+        generates by blocks, and reads as a decode step does."""
         return [None if p is None else
                 {"k_pool": p["k"], "v_pool": p["v"], "fresh": fresh,
+                 "block_step": block_step,
                  **({"table": tables["window"], "limit": self.max_seq_len}
                     if kind.window else {"table": tables["full"]})}
                 for p, kind in zip(pools, self._declared)]
@@ -804,26 +967,76 @@ class InferenceEngine:
 
     def _decode_paged_impl(self, params, pools, tables, step):
         self.trace_counts["decode"] += 1  # trace-time only
-        caches = self._paged_caches(pools, tables)
+        logits, new, sent = self._forward_step(
+            params, self._paged_caches(pools, tables),
+            step["tokens"][:, None], step["positions"][:, None])
+        return new, dict(_advance(step, logits), **sent)
+
+    def _forward_step(self, params, caches, tokens, positions):
+        """A decode step's forward: ``(logits, the caches it wrote,
+        what rides back beside the step state)``.  A model with expert
+        layers hands back what each sowed of the step's routing
+        (``pairs_held``: the pairs each held expert was sent) as
+        ``experts_sent`` — pairs held, held experts sent a pair — for
+        the host to add up (``_step``)."""
         if not self.expert_layers:
             logits, new = self._model.apply(
-                {"params": params}, step["tokens"][:, None],
-                kv_caches=caches, positions=step["positions"][:, None])
-            return new, _advance(step, logits)
-        # A model with expert layers: what each sowed of the step's
-        # routing (``pairs_held``: the pairs each held expert was sent)
-        # goes back with the tokens as ``experts_sent`` — pairs held,
-        # held experts sent a pair — for the host to add up (``_step``).
+                {"params": params}, tokens, kv_caches=caches,
+                positions=positions)
+            return logits, new, {}
         (logits, new), sown = self._model.apply(
-            {"params": params}, step["tokens"][:, None], kv_caches=caches,
-            positions=step["positions"][:, None], mutable=["intermediates"])
+            {"params": params}, tokens, kv_caches=caches,
+            positions=positions, mutable=["intermediates"])
         sizes = jnp.stack([
             leaf for path, leaf in jax.tree_util.tree_flatten_with_path(
                 sown)[0]
             if any(getattr(k, "key", None) == "pairs_held" for k in path)])
-        return new, dict(
-            _advance(step, logits),
-            experts_sent=jnp.stack([sizes.sum(), (sizes > 0).sum()]))
+        return logits, new, {
+            "experts_sent": jnp.stack([sizes.sum(), (sizes > 0).sum()])}
+
+    # --- compiled programs: a model that generates by blocks ----------------
+
+    def _make_block_prefill(self, L: int):
+        model, B = self._model, self._block
+        S, SV = self.max_seq_len, self._view_len
+
+        def prefill(params, pools, table_row, tokens, length):
+            # The prompt's whole blocks (``length`` tokens, a multiple
+            # of the block's length) go into the cache under the block
+            # mask, from position 0 — the chunk's own keys are all a
+            # query sees (``fresh``) — and no token comes out: the
+            # prompt's tail opens the first block (``_block_row``).
+            # What comes back beside the pools is a number to fence on.
+            self.trace_counts[f"prefill_{L}"] += 1  # trace-time only
+            idx = jnp.arange(L, dtype=jnp.int32)
+            valid = (idx < length) & (idx < S)
+            positions = jnp.where(valid, idx, SV - 1)
+            caches = self._paged_caches(
+                pools, jax.tree.map(lambda t: t[None], table_row),
+                fresh=True)
+            x, new = model.apply(
+                {"params": params}, tokens, kv_caches=caches,
+                positions=positions[None], return_hidden=True)
+            return x[0, 0, 0].astype(jnp.float32), new
+
+        return jax.jit(prefill, donate_argnums=self._donate)
+
+    def _decode_block_impl(self, params, pools, tables, step):
+        """The block step: every active row forwards its current block
+        — written at its positions, then read by the decode kernel with
+        the row's length at the block's end — and ``_transfer`` turns
+        the block's logits into the row's next state.  Rows in
+        different phases share it; the shapes are fixed."""
+        self.trace_counts["decode"] += 1  # trace-time only
+        positions = step["positions"][:, None] + jnp.arange(
+            self._block, dtype=jnp.int32)
+        logits, new, sent = self._forward_step(
+            params, self._paged_caches(pools, tables, block_step=True),
+            step["tokens"], positions)
+        with jax.named_scope("hvd_tpu_block_transfer"):
+            advanced = _transfer(step, logits,
+                                 self._model.config.mask_token)
+        return new, dict(advanced, **sent)
 
     # --- compiled programs: state tier --------------------------------------
 
@@ -1013,12 +1226,31 @@ class InferenceEngine:
         fit AND room to generate.  Returns the bucket."""
         if prompt_len < 1:
             raise ValueError("empty prompt")
-        if prompt_len >= self.max_seq_len:
+        if prompt_len >= self.max_seq_len or (
+                self._block and prompt_len - prompt_len % self._block
+                + self._block > self.max_seq_len):
             raise PromptTooLongError(
                 f"prompt of {prompt_len} tokens leaves no room to "
                 f"generate (a request may reach {self.max_seq_len} "
                 f"positions)")
         return self.bucket_for(prompt_len)
+
+    def check_sampling(self, sampling: SamplingParams) -> None:
+        """What a model that generates by blocks asks of a request's
+        schedule (the batcher calls this at admission): 1 to
+        ``block_length`` denoising steps a block (0 = the block's
+        length) and a transfer rule it knows."""
+        if not self._block:
+            return
+        steps = sampling.denoising_steps or self._block
+        if not 1 <= steps <= self._block:
+            raise ValueError(
+                f"denoising_steps {sampling.denoising_steps} is not 1 to "
+                f"the block's length ({self._block})")
+        if sampling.transfer not in ("static", "dynamic"):
+            raise ValueError(f"unknown transfer rule "
+                             f"{sampling.transfer!r}; expected 'static' or "
+                             f"'dynamic'")
 
     def check_prompt_tokens(self, prompt: Sequence[int]) -> int:
         """:meth:`check_prompt` plus token-ID range validation.  An
@@ -1051,6 +1283,10 @@ class InferenceEngine:
         is ``< max_seq_len``: the cache's last row, or with a retention
         state the last position a request may reach)."""
         with self._slot_lock:
+            if self._block:
+                # The next block: behind the one whose commit is due.
+                return (int(self._positions[slot]) + self._block * (
+                    1 + bool(self._block_final[slot]))) > self.max_seq_len
             return int(self._positions[slot]) >= self.max_seq_len
 
     def _device_table(self):
@@ -1094,10 +1330,14 @@ class InferenceEngine:
         release()/adopt() mutations field by field (hvdsan read-site
         catch — max_slots-sized copies, nanoseconds)."""
         with self._slot_lock:
+            blocks = None
+            if self._blocks is not None:
+                blocks = dict({k: v.copy() for k, v in self._blocks.items()},
+                              final=self._block_final.copy())
             return (self._active.copy(), self._positions.copy(),
                     self._temps.copy(), self._topks.copy(),
                     self._last_tokens.copy(), self._spec.copy(),
-                    self._slots_changed)
+                    self._slots_changed, blocks)
 
     # --- guarded slot-state mutation ----------------------------------------
     # The ONE place slot state changes (the hvdlint lock checker holds
@@ -1105,14 +1345,52 @@ class InferenceEngine:
     # prefill used to write these fields inline next to the cache-chunk
     # write, which left router-thread release() racing the batcher.
 
-    def _bind_slot(self, slot: int, n_prompt: int, token: int,
-                   sampling: SamplingParams, prefix_hit: int) -> None:
+    def _empty_blocks(self) -> Dict[str, np.ndarray]:
+        """The block mirror of an engine without a request (``_transfer``
+        has the fields); a cleared row goes back to this."""
+        rows, block = self.max_slots, (self.max_slots, self._block)
+        return {"tokens": np.zeros(block, np.int32),
+                "masked": np.zeros(block, bool),
+                "chosen": np.zeros(block, np.int32),
+                "steps": np.zeros(rows, np.int32),
+                "denoise": np.zeros(rows, np.int32),
+                "dynamic": np.zeros(rows, bool),
+                "threshold": np.zeros(rows, np.float32)}
+
+    def _block_row(self, prompt: List[int], sampling: SamplingParams) -> dict:
+        """The first block of a request, as a row of the step state:
+        blocks lie on absolute positions, so the prompt's tail (its
+        last ``len(prompt) mod B`` tokens) opens the block as positions
+        already clean, and the rest are masks."""
+        B = self._block
+        tail = prompt[len(prompt) - len(prompt) % B:]
+        return {"positions": len(prompt) - len(tail),
+                "tokens": np.asarray(
+                    tail + [self._model.config.mask_token] * (B - len(tail)),
+                    np.int32),
+                "masked": np.arange(B) >= len(tail),
+                "chosen": np.full(B, -1, np.int32), "steps": 0,
+                "denoise": sampling.denoising_steps or B,
+                "dynamic": sampling.transfer == "dynamic",
+                "threshold": sampling.threshold}
+
+    def _bind_slot(self, slot: int, n_prompt: int, token: Optional[int],
+                   sampling: SamplingParams, prefix_hit: int,
+                   row: Optional[dict] = None) -> None:
         with self._slot_lock:
             self._active[slot] = True
             self._positions[slot] = n_prompt   # first generated index
             self._temps[slot] = sampling.temperature
             self._topks[slot] = sampling.top_k
-            self._last_tokens[slot] = token    # first decode consumes it
+            if row is None:
+                self._last_tokens[slot] = token    # first decode consumes it
+            else:
+                # A block a row: the block's first position, and no
+                # token awaited.
+                self._positions[slot] = row["positions"]
+                self._block_final[slot] = False
+                for field in self._blocks:
+                    self._blocks[field][slot] = row[field]
             self._spec[slot] = bool(sampling.spec)
             self._prefix_hits[slot] = prefix_hit
             self._slots_changed += 1
@@ -1127,6 +1405,21 @@ class InferenceEngine:
             self._last_tokens[slot] = tokens[-1]
             self._positions[slot] += len(tokens)
 
+    def _advance_blocks(self, slots: List[int], now: dict) -> None:
+        """The mirror of ``slots`` after a block step, from the step's
+        report (``_read_report``): all rows under one lock."""
+        with self._slot_lock:
+            # A row released concurrently (cancel) is dropped.
+            rows = np.zeros(self.max_slots, bool)
+            rows[slots] = True
+            rows &= self._active
+            for field in ("tokens", "chosen", "masked"):
+                np.copyto(self._blocks[field], now[field],
+                          where=rows[:, None])
+            np.copyto(self._blocks["steps"], now["steps"], where=rows)
+            np.copyto(self._positions, now["positions"], where=rows)
+            np.copyto(self._block_final, now["final"], where=rows)
+
     def _clear_slot(self, slot: int) -> None:
         with self._slot_lock:
             self._active[slot] = False
@@ -1135,6 +1428,11 @@ class InferenceEngine:
             self._topks[slot] = 0
             self._spec[slot] = False
             self._prefix_hits[slot] = 0
+            if self._blocks is not None:
+                # No mask state is left behind for the next request.
+                self._block_final[slot] = False
+                for mirror in self._blocks.values():
+                    mirror[slot] = 0
             self._slots_changed += 1
 
     # --- prefix sharing -----------------------------------------------------
@@ -1156,9 +1454,11 @@ class InferenceEngine:
     # --- request lifecycle --------------------------------------------------
 
     def start(self, slot: int, prompt: Sequence[int],
-              sampling: SamplingParams) -> int:
+              sampling: SamplingParams) -> Optional[int]:
         """Prefill ``prompt`` into ``slot``; returns the first sampled
-        token.  One compiled program per (bucket, slot-batch) shape —
+        token — or None from a model that generates by blocks, whose
+        prefill writes the prompt's whole blocks and yields no token.
+        One compiled program per (bucket, slot-batch) shape —
         on the paged tier the bucket covers only the non-resident
         suffix.  The whole call is one ``hvd_tpu_engine_prefill``
         span: ``args.dispatch_us`` and ``args.fence_us`` say how long
@@ -1173,13 +1473,16 @@ class InferenceEngine:
             return token
 
     def _start(self, slot: int, prompt: Sequence[int],
-               sampling: SamplingParams, span_args: dict) -> int:
+               sampling: SamplingParams, span_args: dict) -> Optional[int]:
         with self._slot_lock:
             if self._active[slot]:
                 raise RuntimeError(f"slot {slot} is already active")
         prompt = [int(t) for t in prompt]
         n = len(prompt)
         self.check_prompt_tokens(prompt)
+        self.check_sampling(sampling)
+        if self._block:
+            return self._start_blocks(slot, prompt, sampling, span_args)
         if self.kv_mode == "state":
             hit = 0
             span_args["prefix_hit"] = 0
@@ -1238,6 +1541,31 @@ class InferenceEngine:
         self._bind_slot(slot, n, token, sampling, hit)
         return token
 
+    def _start_blocks(self, slot: int, prompt: List[int],
+                      sampling: SamplingParams, span_args: dict) -> None:
+        """``_start`` for a model that generates by blocks: the
+        prompt's whole blocks through the prefill program of their
+        bucket (a prompt shorter than a block still runs it, on no
+        token), the tail and the masks into the slot's first block."""
+        self._begin_request(slot, prompt)
+        row = self._block_row(prompt, sampling)
+        ns = row["positions"]
+        L = self.bucket_for(max(ns, 1))
+        if ns:
+            self._ensure_writable(slot, 0, ns)
+        padded = np.zeros((1, L), np.int32)
+        padded[0, :ns] = np.asarray(prompt[:ns], np.int32)
+        span_args.update(bucket=L, prefix_hit=0)
+        with trace_mod.annotate("hvd_tpu_prefill_dispatch"):
+            done, self._pools = self._timed(
+                span_args, "dispatch_us", self._prefill_fns[L],
+                self._params, self._pools, self._table_rows(slot),
+                jnp.asarray(padded), jnp.int32(ns))
+        self._stage_bind(slot, len(prompt), sampling, row)
+        with trace_mod.annotate("hvd_tpu_prefill_fence"):
+            self._timed(span_args, "fence_us", float, done)
+        self._bind_slot(slot, len(prompt), None, sampling, 0, row)
+
     @staticmethod
     def _timed(span_args: dict, key: str, fn, *args):
         """``fn(*args)``, the call's microseconds on the span clock
@@ -1253,7 +1581,13 @@ class InferenceEngine:
     def step(self) -> Dict[int, List[int]]:
         """One decode step for every active slot → ``{slot: [tokens]}``
         (one token per slot on the plain path; up to ``spec_k + 1``
-        under speculative decoding).  Inactive rows ride along masked
+        under speculative decoding; from a model that generates by
+        blocks the tokens whose block's last mask fell in this step, in
+        position order, none in most steps: :class:`FinalTokens`, whose
+        ``steps`` says at which denoising step each was chosen, and the
+        span's ``args.denoise_rows``, ``args.commit_rows`` and
+        ``args.tokens_final`` what the rows did).  Inactive rows ride
+        along masked
         and write into the trash block.  A step with an active slot is
         one ``hvd_tpu_engine_decode`` span: the host's table building,
         whatever it had to upload (``args.uploads``), the dispatch, the
@@ -1270,7 +1604,7 @@ class InferenceEngine:
 
     def _step(self, active: List[int], snap: tuple,
               span_args: dict) -> Dict[int, List[int]]:
-        act, pos, temps, topks, _, spec, changed = snap
+        act, pos, temps, topks, _, spec, changed, blocks = snap
         if self._drafter is not None and any(
                 spec[s] and temps[s] <= 0 for s in active):
             return self._step_spec(active, snap)
@@ -1296,19 +1630,31 @@ class InferenceEngine:
             self._states, advanced = self._dispatch_decode(
                 self._params, self._states, self._step_state)
         elif self.kv_mode == "paged":
+            # What a row brings to the step: a token, or its block.
+            width = self._block or 1
             for s in active:
-                self._ensure_writable(s, int(pos[s]), 1)
+                self._ensure_writable(s, int(pos[s]), width)
+            if blocks is not None:
+                # Rows whose block came in all clean commit it; the
+                # others denoise theirs.
+                commits = int((act & blocks["final"]).sum())
+                span_args.update(denoise_rows=len(active) - commits,
+                                 commit_rows=commits)
+                self.block_steps += 1
+                self.denoise_forwards += len(active) - commits
+                self.commit_forwards += commits
+                self.blocks_committed += commits
             if self._tp_mesh is None:
                 # The decode step's attention walks each row's table to
                 # its length (ops/paged_attention.py); the gathered
                 # view would have read every column of every row.
-                live = int((np.where(act, pos, 0) // self.kv_block
-                            + 1).sum())
+                live = int((np.where(act, pos + width - 1, 0)
+                            // self.kv_block + 1).sum())
                 span_args["live_blocks"] = live
                 self.paged_decode_steps += 1
                 self.paged_live_blocks += live
                 self.paged_view_blocks += self._tables["full"].size
-                seen = np.where(act, pos + 1, 0)
+                seen = np.where(act, pos + width, 0)
                 self.paged_live_rows += len(active)
                 self.paged_live_positions["full"] += int(seen.sum())
                 self.kv_block_steps["full"] += self._kv.blocks_in_use()
@@ -1328,7 +1674,22 @@ class InferenceEngine:
         # What the expert layers were sent rides back with the tokens
         # and is no part of the state the next step takes.
         experts_sent = advanced.pop("experts_sent", None)
+        # ... nor is a block step's report, which holds what it sampled.
+        report = advanced.pop("report", None)
         self._step_state = dict(self._step_state, **advanced)
+        # The fence: the state's tokens are what the step sampled.
+        fenced = advanced["tokens"] if report is None else report
+        if report is not None:
+            # What the host reads of a block step is asked for now, so
+            # that it leaves the device as the program ends and not
+            # when the host gets to it (the wait between the two is the
+            # runtime's: a millisecond or two of a step, and more on
+            # one host than on the next); and while the device
+            # computes, the table the next step needs.
+            report.copy_to_host_async()
+            if experts_sent is not None:
+                experts_sent.copy_to_host_async()
+            self._stage_next_table(active, snap, span_args)
         self.decode_steps += 1
         # Whether the program's sampling branch ran, from the mirror.
         sampling = int((act & (temps > 0)).any())
@@ -1339,23 +1700,24 @@ class InferenceEngine:
         dispatch_us = (returned - at) / 1e3
         dispatch_usual = self._follow("dispatch", dispatch_us)
         self._wake_runtime(span_args, dispatch_us, dispatch_usual)
-        # The fence: the state's tokens are what the step sampled.
         if traced:
             with trace_mod.annotate("hvd_tpu_decode_fence"):
                 t = time.monotonic_ns()
-                nxt = np.asarray(advanced["tokens"])
+                nxt = np.asarray(fenced)
                 fence_us = (time.monotonic_ns() - t) / 1e3
             self._note_phases(
                 span_args, {"prepare": (at - began) / 1e3,
                             "dispatch": dispatch_us, "fence": fence_us},
                 dispatch_usual)
         else:
-            nxt = np.asarray(advanced["tokens"])
+            nxt = np.asarray(fenced)
         if experts_sent is not None:
             pairs, touched = np.asarray(experts_sent)
             self.expert_pairs += int(pairs)
             self.experts_touched += int(touched)
         held = self._device_slots
+        if report is not None:
+            return self._blocks_out(active, nxt, span_args)
         self._device_slots = dict(
             held, tokens=nxt, positions=held["positions"] + held["active"])
         out = {}
@@ -1363,6 +1725,53 @@ class InferenceEngine:
             toks = [int(nxt[s])]
             out[s] = toks
             self._advance_slot(s, toks)
+        return out
+
+    def _stage_next_table(self, active: List[int], snap: tuple,
+                          span_args: dict) -> None:
+        """Between a block step's dispatch and its fence: the blocks
+        and the table the *next* step needs, sent while the device
+        computes.  Which rows commit in this step the snapshot knows
+        (``final``), so where every row's block lies in the next step
+        is known before this one's report: a committing row opens its
+        next block ``B`` positions on.  The next step compares again
+        (``_device_table``) and as a rule finds nothing to send; a bind
+        or a release until then changes the table like any other.  No
+        row is taken past the cache's end, so the pool's floor (every
+        slot servable whole) holds what is allotted here."""
+        pos, blocks = snap[1], snap[7]
+        B = self._block
+        for s in active:
+            if blocks["final"][s] and pos[s] + 2 * B <= self.max_seq_len:
+                self._ensure_writable(s, int(pos[s]) + B, B)
+        sent = self.table_uploads
+        self._device_table()
+        if self.table_uploads != sent:
+            self.table_uploads_ahead += self.table_uploads - sent
+            span_args["table_ahead"] = self.table_uploads - sent
+
+    def _blocks_out(self, active: List[int], report,
+                    span_args: dict) -> Dict[int, List[int]]:
+        """What a block step hands back, from its fenced report
+        (``_transfer``): the device's rows as they now are, the mirror
+        of every active row, and for each the tokens that became final
+        — its block's generated positions, in order, when the block's
+        last mask fell; none otherwise."""
+        now = _read_report(report, self._block)
+        self._device_slots = dict(
+            self._device_slots,
+            **{k: v for k, v in now.items() if k != "final"})
+        self._advance_blocks(active, now)
+        out = {s: FinalTokens() for s in active}
+        tokens, chosen = now["tokens"].tolist(), now["chosen"].tolist()
+        for s in np.nonzero(now["final"])[0].tolist():
+            if s in out:
+                made = [i for i, at in enumerate(chosen[s]) if at >= 0]
+                out[s] = FinalTokens([tokens[s][i] for i in made],
+                                     [chosen[s][i] for i in made])
+        final = sum(len(toks) for toks in out.values())
+        self.tokens_final += final
+        span_args["tokens_final"] = final
         return out
 
     def _dispatch_decode(self, *args):
@@ -1464,7 +1873,7 @@ class InferenceEngine:
         concurrent cancel between the two reads) and write into a
         just-released slot's chain."""
         K = self.spec_k
-        act, pos, temps, topks, last_tokens, spec, _ = snap
+        act, pos, temps, topks, last_tokens, spec = snap[:6]
         positions = np.where(act, pos, 0).astype(np.int32)
         for s in active:
             p = int(positions[s])
@@ -1545,6 +1954,7 @@ class InferenceEngine:
         the exact stream the uninterrupted run would be on — the
         temperature half of the token-identity oracle (same
         sole-active-slot contract as KV migration's rng carry)."""
+        self._refuse_blocks("preempt_slot")
         rng = np.asarray(self._rng)
         emitted = [int(t) for t in emitted]
         if self._kv is not None and emitted:
@@ -1566,6 +1976,8 @@ class InferenceEngine:
         so on drafter engines only sequences fitting the largest
         bucket are preemptible (the scheduler skips other victims)."""
         n = n_prompt + max(0, n_emitted - 1)
+        if self._block:
+            return False    # ``_refuse_blocks``
         if self._drafter is not None or self._window:
             # ... and a window layer's ring takes a prefill from
             # position 0 only, in one chunk.
@@ -1591,6 +2003,7 @@ class InferenceEngine:
         uninterrupted run; with concurrent traffic it stays
         distributionally correct (greedy is deterministic either
         way).  One ``hvd_tpu_engine_prefill`` span (``resumed``)."""
+        self._refuse_blocks("resume_slot")
         span_args = {"slot": int(slot), "resumed": True,
                      "cache": self.kv_mode}
         with trace_mod.span("hvd_tpu_engine_prefill", args=span_args):
@@ -1753,7 +2166,16 @@ class InferenceEngine:
     # the device pools the compiled programs donate), exactly like
     # start()/step() — the fleet layer routes both through the batcher.
 
+    def _refuse_blocks(self, what: str) -> None:
+        """Preemption-resume and migration frames carry a token a row."""
+        if self._block:
+            raise RuntimeError(
+                f"a model that generates by blocks is not preempted, "
+                f"resumed or migrated yet: {what} carries a request as its "
+                f"tokens so far, not a block in the middle of its denoising")
+
     def _refuse_frame(self, what: str) -> None:
+        self._refuse_blocks(what)
         rows = {row for k in self._declared if k is not None
                 for row in (k.k_row, k.v_row)}
         if self._window or len(rows) > 1 or None in self._declared:
@@ -1893,7 +2315,9 @@ class InferenceEngine:
         while the device computed), ``runtime_pokes`` (steps whose
         dispatch was slow enough to send the runtime small transfers
         behind the device's work) and, where there is a block table,
-        ``table_uploads`` (times it was sent: it had changed).  Where a
+        ``table_uploads`` (times it was sent: it had changed; of them
+        ``table_uploads_ahead`` behind a block step's dispatch, for the
+        step after it).  Where a
         step's host time went, with tracing on: ``dispatch_ms_p50`` /
         ``_p99`` and ``fence_ms_p50`` / ``_p99`` (the decode program's
         dispatch call; the wait for its tokens, which holds the
@@ -1923,7 +2347,12 @@ class InferenceEngine:
         ``state_slots_skipped`` (the slots a step left alone,
         the same mean: ``max_slots`` less the former) and
         ``state_resets`` (prefills that began a slot's state from
-        zeros)."""
+        zeros).  For a model that generates by blocks: ``block_steps``,
+        ``denoise_forwards`` and ``commit_forwards`` (a row's forwards
+        of a block with masks left, and of one all clean, whose K/V
+        the cache keeps), ``blocks_committed`` and ``tokens_final``
+        (tokens whose block's last mask fell); ``paged_live_positions_
+        full`` then counts every position up to each row's block end."""
         out: Dict = {"decode_steps": self.decode_steps,
                      "sampling_steps": self.sampling_steps,
                      "step_state_uploads": self.step_state_uploads,
@@ -1934,6 +2363,12 @@ class InferenceEngine:
                          self.stalled_prefills.values())}
         for phase in _DECODE_PHASES:
             out["stalled_" + phase] = self.stalled_steps[phase]
+        if self._block:
+            out.update(block_steps=self.block_steps,
+                       denoise_forwards=self.denoise_forwards,
+                       commit_forwards=self.commit_forwards,
+                       blocks_committed=self.blocks_committed,
+                       tokens_final=self.tokens_final)
         recent = [s["args"] for s in trace_mod.recent(
             "hvd_tpu_engine_decode", _RECENT_STEPS, self._born_us)
                   if "dispatch_us" in s["args"]]
@@ -1944,6 +2379,8 @@ class InferenceEngine:
         if self._kv is not None:
             out.update(self._kv.stats())
             out["table_uploads"] = self.table_uploads
+            if self._block:
+                out["table_uploads_ahead"] = self.table_uploads_ahead
             out["paged_decode_steps"] = self.paged_decode_steps
             out["paged_live_blocks"] = self.paged_live_blocks
             out["paged_view_blocks"] = self.paged_view_blocks

@@ -307,6 +307,53 @@ def test_paged_decode_kernel_compiles_at_mimo_v2_widths(topo, kind, kv_heads,
     assert "hvd_tpu_paged_decode" in text
 
 
+def test_a_folded_block_compiles_as_the_decode_kernel_at_sdar_widths(topo):
+    """A block step of the cell ``sdar30b-serve-reason``: 48 rows, each
+    a block of 4 positions x 32 query heads of 128 folded into 128
+    heads over the 4 KV heads (a group of 32), pool rows of 512, a
+    table of 256 + 1 columns — the kernel GPT-2 XL's decode step runs,
+    its group four times as large."""
+    from horovod_tpu.ops import paged_attention
+
+    one = SingleDeviceSharding(topo.devices[0])
+
+    def sds(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    def step(q, k, v, table, pos):
+        out = paged_attention.paged_decode(
+            paged_attention.fold_block(q, 4), k, v, table, pos, 4,
+            interpret=False)
+        return paged_attention.unfold_block(out, 4, 4)
+
+    text = _compile(step, sds((48, 4, 32, 128)), sds((12289, 16, 512)),
+                    sds((12289, 16, 512)), sds((48, 257), jnp.int32),
+                    sds((48,), jnp.int32))
+    assert "hvd_tpu_paged_decode" in text
+
+
+def test_all_128_experts_of_a_block_step_are_grouped_kernels_on_v5e(topo):
+    """Every expert of a layer held: 128 gated experts of 2048 x 768, a
+    softmax router, a block step's 48 rows x 4 positions x top 8 =
+    1,536 pairs, twelve a group: three Mosaic kernels under the
+    experts' scope and no conditional (every pair is a sorted row)."""
+    from horovod_tpu.parallel.moe import DroplessExperts
+
+    one = SingleDeviceSharding(topo.devices[0])
+    layer = DroplessExperts(d_model=2048, d_ff=768, n_experts=128, top_k=8,
+                            gated=True, scoring="softmax",
+                            param_dtype=jnp.bfloat16, interpret=False)
+    x = jax.ShapeDtypeStruct((48, 4, 2048), jnp.bfloat16, sharding=one)
+    params = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one),
+        jax.eval_shape(layer.init, jax.random.PRNGKey(0), x)["params"])
+    text = _compile(lambda p, x: layer.apply({"params": p}, x), params, x)
+    kernels = re.findall(r"= (\S+) custom-call\([^\n]*tpu_custom_call"
+                         r"[^\n]*hvd_tpu_moe_experts", text)
+    assert len(kernels) == 3, kernels
+    assert " conditional(" not in text
+
+
 def _described(topo, tree):
     one = SingleDeviceSharding(topo.devices[0])
     return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
